@@ -1,10 +1,11 @@
 """Shared benchmark helpers.
 
-CPU-container scaling note: the paper's experiments use SNAP graphs with up
-to 1e8 edges and eps=0.05 on a V100.  This container is a single CPU core,
-so every benchmark keeps the *methodology* (same machinery, same sweeps) at
-reduced n/eps, and records the configuration next to each number.  The
-TPU-target throughput story lives in EXPERIMENTS.md §Roofline instead.
+Scaling note: the paper's experiments use SNAP graphs with up to 1e8 edges
+and eps=0.05 on a V100.  These benchmarks replay its figures on the CPU at
+reduced n/eps, keeping the *methodology* (same machinery, same sweeps) and
+recording the configuration next to each number.  Their timings are CPU
+wall clocks, never device metrics; the TPU record is PERF.md, and the
+chip itself is exercised by chip_smoke.py at the repository root.
 """
 from __future__ import annotations
 

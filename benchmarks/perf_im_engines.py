@@ -637,6 +637,9 @@ def bench_streaming(n=2000, r=4, k=8, theta=4096, batch=256, rounds=3,
         import sys
         env = dict(os.environ)
         env["PYTHONPATH"] = "src"
+        # the 8 forced host devices are a CPU leg; pinned so the child
+        # never reaches for a chip this process may hold
+        env["JAX_PLATFORMS"] = "cpu"
         rp = subprocess.run([sys.executable, "-c", STREAM8_SCRIPT], env=env,
                             capture_output=True, text=True,
                             cwd=os.path.dirname(os.path.dirname(

@@ -45,6 +45,7 @@ import time
 import numpy as np
 
 from benchmarks.common import OUT_DIR, ba_graph
+from repro.graph import generators
 from repro.core.imm import IMMSolver
 from repro.core.problem import IMProblem
 from repro.serve import ServeConfig, ServeError, build_service
@@ -52,16 +53,17 @@ from repro.serve import ServeConfig, ServeError, build_service
 SOLVER_OPTS = {"batch": 64, "seed": 0}
 
 
-def make_workload(g, requests: int, theta: int, seed: int = 0):
+def make_workload(deg, requests: int, theta: int, seed: int = 0):
     """Mixed θ-pinned request stream: varying k, two candidate pools, and a
-    zipf-ish repeat pattern so the cache sees realistic re-asks."""
-    deg = np.diff(np.asarray(g.offsets))
-    top = np.argsort(-deg, kind="stable")
+    zipf-ish repeat pattern so the cache sees realistic re-asks.  ``deg``
+    is the (n,) out-degree vector (host numpy: building the workload
+    touches no device)."""
+    n = len(deg)
+    top = np.argsort(-np.asarray(deg), kind="stable")
     distinct = [IMProblem(k=k, theta=theta) for k in (1, 2, 5, 10)]
     distinct += [IMProblem(k=1, theta=theta, candidates=top[:m])
-                 for m in (g.n_nodes // 4, g.n_nodes // 2)]
-    distinct += [IMProblem(k=3, theta=theta,
-                           candidates=top[:g.n_nodes // 4])]
+                 for m in (n // 4, n // 2)]
+    distinct += [IMProblem(k=3, theta=theta, candidates=top[:n // 4])]
     rng = np.random.default_rng(seed)
     # zipf-like popularity: low indices re-asked often
     idx = np.minimum(rng.zipf(1.5, size=requests) - 1, len(distinct) - 1)
@@ -198,7 +200,8 @@ def chaos_main(args):
     requests = args.requests or 120
     theta = args.theta or 1024
     g = ba_graph(n, 4)
-    workload, distinct = make_workload(g, requests, theta)
+    workload, distinct = make_workload(np.diff(np.asarray(g.offsets)),
+                                       requests, theta)
     outcomes, results, dbounds, st, policy, wall = asyncio.run(run_chaos(
         g, workload, max_batch=args.max_batch, rate=args.fault_rate,
         deadline_probes=4, probe_theta=16 * theta))
@@ -392,8 +395,12 @@ def net_main(args):
             await asyncio.sleep(0.25)
         raise RuntimeError(f"server never ready\n{server_log()}")
 
-    g = ba_graph(n, 4)
-    workload, distinct = make_workload(g, requests, theta)
+    # one process per chip: while the server child runs, this process
+    # touches no device, so the workload is built from host edge lists and
+    # the graph object (device arrays) only after the child has exited
+    src, _dst = generators.barabasi_albert(n, 4, seed=0)
+    workload, distinct = make_workload(np.bincount(src, minlength=n),
+                                       requests, theta)
     # the approximate tier rides the same wire (satellite): sketch-mode
     # answers plus their pool-footprint ratio in /statsz
     approx = [IMProblem(k=3, theta=theta, mode="approximate"),
@@ -422,6 +429,7 @@ def net_main(args):
         if proc.poll() is None:
             proc.kill()
             proc.wait()
+    g = ba_graph(n, 4)
 
     # gates ------------------------------------------------------------
     total_5xx = sum(l["n_5xx"] for l in levels)
@@ -518,7 +526,8 @@ def main():
                               else [100.0, 500.0])
 
     g = ba_graph(n, 4)
-    workload, distinct = make_workload(g, requests, theta)
+    workload, distinct = make_workload(np.diff(np.asarray(g.offsets)),
+                                       requests, theta)
 
     levels = []
     results = {}

@@ -9,6 +9,7 @@ RIS estimate against forward Monte-Carlo.
 """
 import argparse
 import os
+import tempfile
 import time
 
 import numpy as np
@@ -19,6 +20,7 @@ from repro.core.imm import IMMSolver
 from repro.core.problem import IMProblem
 from repro.core import forward
 from repro.ckpt import checkpoint as ckpt
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def main():
@@ -29,8 +31,10 @@ def main():
     ap.add_argument("--model", choices=["ic", "lt"], default="ic")
     ap.add_argument("--engine", choices=["queue", "dense", "refill"],
                     default="queue")
-    ap.add_argument("--ckpt", default="/tmp/repro_im_ckpt")
+    ap.add_argument("--ckpt", default=os.path.join(tempfile.gettempdir(),
+                                                   "repro_im_ckpt"))
     args = ap.parse_args()
+    configure_compile_cache()
 
     n = int(75879 * args.scale)
     src, dst = generators.barabasi_albert(n, 4, seed=0)

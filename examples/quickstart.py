@@ -9,9 +9,11 @@ from repro.graph import csr, generators, weights
 from repro.core.imm import imm
 from repro.core import forward
 from repro.core.engine import list_engines, make_engine
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def main():
+    configure_compile_cache()
     # 1. build a scale-free social graph with weighted-cascade probabilities
     src, dst = generators.barabasi_albert(2000, 4, seed=0)
     g = weights.wc_weights(csr.from_edges(src, dst, 2000))

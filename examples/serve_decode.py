@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro.models import transformer as T
 from repro.models.transformer import LMConfig
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def main():
@@ -18,6 +19,7 @@ def main():
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--cache", type=int, default=128)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = LMConfig(name="gemma3-mini", n_layers=4, d_model=128, n_heads=8,
                    n_kv_heads=4, head_dim=16, d_ff=512, vocab=2048,

@@ -21,6 +21,7 @@ from repro.train.steps import init_train_state, build_lm_train_step
 from repro.data import tokens as tok
 from repro.ckpt import checkpoint as ckpt
 from repro.ft.straggler import StepTimer
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def small_cfg():
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--ckpt", default="/tmp/repro_lm_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.arch == "qwen2-mini":
         cfg = small_cfg()

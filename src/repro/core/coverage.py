@@ -36,12 +36,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map_unchecked, pvary
 from repro.core import sketch as sketch_mod
 from repro.core.packing import rank_positions
 from repro.ft.failures import PoolAllocError
 from repro.kernels import ops as kops
 from repro.kernels.bitset import _popcount
+
+# replication checking off: the pool programs run while_loops and Pallas
+# calls whose outputs carry no varying-axis annotation
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def _ceil_pow2(x: int) -> int:
@@ -316,7 +319,7 @@ def _mesh_store_fns(mesh: Mesh):
             out = local_fn(flat[0], ids[0], valid[0], t[0], nrr[0],
                            nodes[0], lens[0])
             return tuple(x[None] for x in out)
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh,
             in_specs=(buf, buf, buf, vec, vec, b3, buf),
             out_specs=(buf, buf, buf, vec, vec))
@@ -339,7 +342,7 @@ def _mesh_store_fns(mesh: Mesh):
                            nodes[0], lens[0], ew=ew[0], wsum=wsum[0],
                            roww=roww[0])
             return tuple(x[None] for x in out)
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh,
             in_specs=(buf, buf, buf, buf, vec, vec, vec, b3, buf, buf),
             out_specs=(buf, buf, buf, buf, vec, vec, vec))
@@ -364,7 +367,7 @@ def _mesh_store_fns(mesh: Mesh):
             pad = newcap - e.shape[1]
             return jnp.concatenate(
                 [e, jnp.zeros((1, pad), jnp.float32)], 1)
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf,), out_specs=buf)(ew)
 
     @functools.partial(jax.jit, static_argnames=("newcap", "n"))
@@ -376,7 +379,7 @@ def _mesh_store_fns(mesh: Mesh):
             return (jnp.concatenate([f, jnp.full((1, pad), n, jnp.int32)], 1),
                     jnp.concatenate([i, jnp.zeros((1, pad), jnp.int32)], 1),
                     jnp.concatenate([v, jnp.zeros((1, pad), bool)], 1))
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf, buf),
             out_specs=(buf, buf, buf))(flat, ids, valid)
 
@@ -389,7 +392,7 @@ def _mesh_store_fns(mesh: Mesh):
         def local(sk, nodes, lens, base):
             return sketch_mod.fold_batch_packed(
                 sk[0], nodes, lens, base, k=k, mode=mode)[None]
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(b3, P(), P(), P()),
             out_specs=b3)(sk, nodes, lens, base)
 
@@ -399,7 +402,7 @@ def _mesh_store_fns(mesh: Mesh):
             return _bitset_from_flat_local(
                 flat[0], ids[0], valid[0],
                 num_rows=num_rows, n_words=n_words)[None]
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf, buf),
             out_specs=b3)(flat, ids, valid)
 
@@ -418,7 +421,7 @@ def _mesh_store_fns(mesh: Mesh):
             g = jax.lax.all_gather(part, ax)
             return jax.lax.reduce(g, jnp.uint32(0),
                                   jax.lax.bitwise_or, (0,))[None]
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf, buf),
             out_specs=b3)(flat, ids, valid)
 
@@ -979,10 +982,13 @@ class ShardedDeviceRRStore:
         return max(32, _ceil_pow2(max(int(self._nrr_loc.max()), 1)))
 
     def bitset_matrix(self):
-        """(D, row_capacity, ceil(n/32)) packed membership matrix, one
-        block per shard (cached)."""
+        """(D, row_capacity, n_words) packed membership matrix, one block
+        per shard (cached).  ``n_words`` is ceil(n/32) rounded up to whole
+        128-lane tiles, so the matrix keeps a row-major device layout and
+        reaches the Occur kernel without a relayout copy; the extra words
+        are zero."""
         num_rows = self.row_capacity()
-        n_words = (self.n_nodes + 31) // 32
+        n_words = bitset_words(self.n_nodes)
         if self._bitset is None or \
                 self._bitset.shape[1:] != (num_rows, n_words):
             self._bitset = self._fns.bitset_build(
@@ -1062,10 +1068,9 @@ def _mesh_sketch_fns(mesh: Mesh):
     ax = mesh.axis_names[0]
     b3 = P(ax, None, None)
 
-    @functools.partial(jax.jit,
-                       static_argnames=("k", "mode", "rloc", "interpret"),
+    @functools.partial(jax.jit, static_argnames=("k", "mode", "rloc"),
                        donate_argnums=(0,))
-    def frontier_fold(sk, nodes, lens, base, *, k, mode, rloc, interpret):
+    def frontier_fold(sk, nodes, lens, base, *, k, mode, rloc):
         def local(sk, nodes, lens, base):
             w = nodes.shape[1]
             row_valid = lens.astype(jnp.int32) > 0
@@ -1074,17 +1079,13 @@ def _mesh_sketch_fns(mesh: Mesh):
             nb = jax.lax.dynamic_slice(nodes, (i * rloc, 0), (rloc, w))
             lb = jax.lax.dynamic_slice(lens, (i * rloc,), (rloc,))
             rb = jax.lax.dynamic_slice(rid, (i * rloc,), (rloc,))
-            # interpret resolved by the caller outside this trace: it picks
-            # the fold algorithm (kernel vs sort-based), so a stale baked-in
-            # resolution must not survive the jit cache
             part = sketch_mod.fold_frontier_rows(
-                jnp.zeros_like(sk[0]), nb, lb, rb, k=k, mode=mode,
-                interpret=interpret)
+                jnp.zeros_like(sk[0]), nb, lb, rb, k=k, mode=mode)
             g = jax.lax.all_gather(part, ax)
             delta = jax.lax.reduce(g, jnp.uint32(0),
                                    jax.lax.bitwise_or, (0,))
             return (sk[0] | delta)[None]
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(b3, P(), P(), P()),
             out_specs=b3)(sk, nodes, lens, base)
 
@@ -1101,8 +1102,8 @@ class SketchRRStore:
     state (DiFuseR mode, DESIGN.md §10).
 
     Every sampling micro-step's frontier folds straight into the packed
-    (D, sketch_rows, k/32) per-node occupancy words via the Pallas
-    scatter-OR kernel; the flat pool / ids / valid buffers of
+    (D, sketch_rows, k/32) per-node occupancy words via the sort-dedup
+    scatter-OR fold; the flat pool / ids / valid buffers of
     :class:`ShardedDeviceRRStore` are **never allocated** — O(n·k/8) bytes
     per device independent of θ, vs the exact pool's O(θ·E[|RR|]).  The
     only sampling state besides the words is the per-shard row counter
@@ -1167,7 +1168,6 @@ class SketchRRStore:
         entire "append".  Same calling convention as the exact store (the
         engines and the solver's fault-policy retry wrapper are shared);
         the one explicit host fetch is the (D, 2) shard-count scalar."""
-        from repro.kernels import ops as kops
         if row_w is not None:
             raise ValueError("pool-free sketch store is unweighted")
         nodes, lens = (batch.nodes, batch.lengths) \
@@ -1191,8 +1191,7 @@ class SketchRRStore:
         lens_rep = jax.device_put(lens, self._sh_rep)
         self._sk_words = self._fns.frontier_fold(
             self._sk_words, nodes_rep, lens_rep, base,
-            k=self.sketch_k, mode=self.sketch_mode, rloc=rloc,
-            interpret=kops.resolve_interpret(None))
+            k=self.sketch_k, mode=self.sketch_mode, rloc=rloc)
         self._t_loc += counts[:, 0]
         self._nrr_loc += counts[:, 1]
 
@@ -1390,13 +1389,13 @@ def _mesh_select_fns(mesh: Mesh):
                 occur = occur - jax.lax.psum(dec, ax)
                 return (occur, cov_words | new_words), (u, gain)
 
-            cov0 = pvary(jnp.zeros(num_rows // 32, jnp.uint32), ax)
+            cov0 = jnp.zeros(num_rows // 32, jnp.uint32)
             _, (seeds, gains) = jax.lax.scan(
                 step, (occur0, cov0), None, length=k)
             frac = gains.sum(dtype=jnp.int32) / jnp.maximum(nrr_tot, 1)
             return seeds, gains, frac.astype(jnp.float32)
 
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf, buf, vec),
             out_specs=(P(), P(), P()))(flat, ids, valid, nrr)
 
@@ -1426,13 +1425,13 @@ def _mesh_select_fns(mesh: Mesh):
                 gain = jax.lax.psum(newly.sum(dtype=jnp.int32), ax)
                 return (occur - dec, covered | hit), (u, gain)
 
-            covered0 = pvary(jnp.zeros(m.shape[0], bool), ax)
+            covered0 = jnp.zeros(m.shape[0], bool)
             _, (seeds, gains) = jax.lax.scan(
                 step, (occur0, covered0), None, length=k)
             frac = gains.sum(dtype=jnp.int32) / jnp.maximum(nrr_tot, 1)
             return seeds, gains, frac.astype(jnp.float32)
 
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(b3, vec),
             out_specs=(P(), P(), P()))(m_words, nrr)
 
@@ -1443,7 +1442,7 @@ def _mesh_select_fns(mesh: Mesh):
             h = jnp.zeros(n + 1, jnp.int32).at[flat[0]].add(
                 valid[0].astype(jnp.int32), mode="drop")[:n]
             return jax.lax.psum(h, ax)
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf),
             out_specs=P())(flat, valid)
 
@@ -1474,7 +1473,7 @@ def _mesh_select_fns(mesh: Mesh):
             gains = jax.lax.map(chunk, cs.reshape(-1, _EVAL_CHUNK))
             return jax.lax.psum(gains.reshape(-1)[:c], ax)
 
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf, buf, buf, P()),
             out_specs=P())(flat, ids, valid, cov_words, cands)
 
@@ -1488,7 +1487,7 @@ def _mesh_select_fns(mesh: Mesh):
             new_words = _pack_covered(newly)
             gain = jax.lax.psum(_popcount(new_words).sum(dtype=jnp.int32), ax)
             return (cov_words[0] | new_words)[None], gain
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf, buf, buf, P()),
             out_specs=(buf, P()))(flat, ids, valid, cov_words, u)
 
@@ -1608,7 +1607,7 @@ def _mesh_select_fns(mesh: Mesh):
                     return ((occur, cov_words | new_words, spent, gbud,
                              picked), (u, gain))
 
-                cov0 = pvary(jnp.zeros(num_rows // 32, jnp.uint32), ax)
+                cov0 = jnp.zeros(num_rows // 32, jnp.uint32)
                 carry0 = (occur0, cov0, jnp.float32(0.0),
                           jnp.full((n_groups,), group_quota, jnp.int32),
                           jnp.zeros(n, bool))
@@ -1620,7 +1619,7 @@ def _mesh_select_fns(mesh: Mesh):
                 return seeds, gains, frac, spent
 
             dummy = P()
-            return shard_map_unchecked(
+            return _shard_map(
                 local, mesh=mesh,
                 in_specs=(buf, buf, buf, vec,
                           vec if weighted else dummy,
@@ -1723,8 +1722,7 @@ def _mesh_select_fns(mesh: Mesh):
                 occur = occur - dec
                 return (occur, cov, spent, gbud, picked), (u, gain)
 
-            cov0 = pvary(jnp.zeros((r_count, num_rows // 32), jnp.uint32),
-                         ax)
+            cov0 = jnp.zeros((r_count, num_rows // 32), jnp.uint32)
             carry0 = (jnp.broadcast_to(occur0, (r_count, n)), cov0,
                       jnp.zeros(r_count, jnp.float32),
                       jnp.broadcast_to(quota[:, None],
@@ -1744,7 +1742,7 @@ def _mesh_select_fns(mesh: Mesh):
                                    jnp.float32(1e-30))).astype(jnp.float32)
             return seeds, gains, frac, spent
 
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh,
             in_specs=(buf, buf, buf, vec,
                       P(), P(), P(), P(), P(), P(), P()),
@@ -1760,7 +1758,7 @@ def _mesh_select_fns(mesh: Mesh):
             h = jnp.zeros(n + 1, jnp.float32).at[flat[0]].add(
                 jnp.where(valid[0], ew[0], 0.0), mode="drop")[:n]
             return jax.lax.psum(h, ax)
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf, buf),
             out_specs=P())(flat, valid, ew)
 
@@ -1775,7 +1773,7 @@ def _mesh_select_fns(mesh: Mesh):
             return jnp.maximum(jax.ops.segment_max(
                 ew_l, jnp.clip(ids[0], 0, num_rows - 1),
                 num_segments=num_rows), 0.0)[None]
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf, buf),
             out_specs=buf)(ids, valid, ew)
 
@@ -1802,7 +1800,7 @@ def _mesh_select_fns(mesh: Mesh):
             gains = jax.lax.map(chunk, cs.reshape(-1, _EVAL_CHUNK))
             return jax.lax.psum(gains.reshape(-1)[:c], ax)
 
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf, buf, buf, buf, P()),
             out_specs=P())(flat, ids, valid, roww, cov_words, cands)
 
@@ -1817,7 +1815,7 @@ def _mesh_select_fns(mesh: Mesh):
             gain = jax.lax.psum(
                 jnp.where(newly, roww[0], 0.0).sum(dtype=jnp.float32), ax)
             return (cov_words[0] | new_words)[None], gain
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, buf, buf, buf, buf, P()),
             out_specs=(buf, P()))(flat, ids, valid, roww, cov_words, u)
 
@@ -1827,7 +1825,7 @@ def _mesh_select_fns(mesh: Mesh):
         denominator), as a replicated device scalar."""
         def local(wvec):
             return jax.lax.psum(wvec[0], ax)
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(vec,), out_specs=P())(wvec)
 
     @functools.partial(jax.jit, static_argnames=("stripe", "interpret"))
@@ -1845,7 +1843,7 @@ def _mesh_select_fns(mesh: Mesh):
             full = jax.lax.dynamic_update_slice(
                 jnp.zeros(sk.shape[1], jnp.int32), g, (i * stripe,))
             return jax.lax.psum(full, ax)
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(b3, buf),
             out_specs=P())(sk, cov_sk)
 
@@ -1856,7 +1854,7 @@ def _mesh_select_fns(mesh: Mesh):
         identical, so each shard ORs its own copy)."""
         def local(cov, sk, u):
             return (cov[0] | sk[0, u])[None]
-        return shard_map_unchecked(
+        return _shard_map(
             local, mesh=mesh, in_specs=(buf, b3, P()),
             out_specs=buf)(cov_sk, sk, u)
 
@@ -1884,14 +1882,28 @@ def _mesh_select_fns(mesh: Mesh):
     return fns
 
 
+def bitset_words(n: int) -> int:
+    """Node-word width of the bitset matrix: ceil(n/32) rounded up to whole
+    128-word tiles."""
+    return -(-n // (32 * 128)) * 128
+
+
+def auto_method(store: "ShardedDeviceRRStore") -> str:
+    """The backend ``method="auto"`` picks for this pool: ``"bitset"`` iff
+    the per-shard bit matrix is no larger than the per-shard flat capacity
+    buffers it replaces (mean RR size ≳ n/32), else ``"flat"``."""
+    n_words = bitset_words(store.n_nodes)
+    return ("bitset" if store.row_capacity() * n_words <= store.capacity
+            else "flat")
+
+
 def select_seeds_device(store: "ShardedDeviceRRStore", k: int,
                         method: str = "auto") -> CoverageResult:
     """Fused greedy selection directly on a :class:`ShardedDeviceRRStore`.
 
     ``method``: ``"flat"`` (scatter decrement, optimal for sparse RR pools),
     ``"bitset"`` (Pallas bit-matrix path, optimal for dense pools), or
-    ``"auto"`` — bitset iff the per-shard bit matrix is no larger than the
-    per-shard flat capacity buffers it replaces (i.e. mean RR size ≳ n/32).
+    ``"auto"`` (:func:`auto_method`).
     Everything stays on the mesh; the returned ``frac`` uses the psum of
     the per-shard device row counts, so the call is legal under
     ``jax.transfer_guard("disallow")`` on a mesh of any size.
@@ -1899,8 +1911,7 @@ def select_seeds_device(store: "ShardedDeviceRRStore", k: int,
     fns = _mesh_select_fns(store.mesh)
     num_rows = store.row_capacity()
     if method == "auto":
-        n_words = (store.n_nodes + 31) // 32
-        method = "bitset" if num_rows * n_words <= store.capacity else "flat"
+        method = auto_method(store)
     if method == "flat":
         seeds, gains, frac = fns.fused(
             store._flat, store._ids, store._valid, store.n_rr_dev,
@@ -2571,8 +2582,6 @@ def select_seeds_sharded(mesh, store_shards, k: int, n: int, axis_names):
     equal to the mesh size (one row per device); rr_ids are *local* row ids.
     Per-seed collective cost: one psum over (n,) int32 — see DESIGN.md §5.
     """
-    from repro.compat import shard_map
-
     local_n_rr = store_shards.n_rr  # rows per shard (uniform)
 
     def local_fn(rr_flat, rr_ids, valid):
@@ -2598,15 +2607,15 @@ def select_seeds_sharded(mesh, store_shards, k: int, n: int, axis_names):
             gain = jax.lax.psum(newly.sum(dtype=jnp.int32), axis_names)
             return (occur, covered | row_has), (u, gain)
 
-        covered = pvary(jnp.zeros(local_n_rr, bool), axis_names)
+        covered = jnp.zeros(local_n_rr, bool)
         (_, covered), (seeds, gains) = jax.lax.scan(
             step, (occur, covered), None, length=k)
         return seeds[None], gains[None]
 
     specs = P(axis_names if isinstance(axis_names, str) else tuple(axis_names))
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(specs, specs, specs),
-                   out_specs=(specs, specs))
+    fn = _shard_map(local_fn, mesh=mesh,
+                    in_specs=(specs, specs, specs),
+                    out_specs=(specs, specs))
     seeds, gains = fn(store_shards.rr_flat, store_shards.rr_ids,
                       store_shards.valid)
     return seeds[0], gains[0]

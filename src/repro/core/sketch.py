@@ -20,9 +20,7 @@ and a bit already present must not carry), so the fold
 first occurrences, masks bits already set in the live words, and commits
 the survivors with one scatter-*add* — which at that point is exactly
 scatter-OR.  This is the ~8× sketch-memory cut over the historical bool
-occupancy (deleted); the equivalent TPU-bound Pallas kernel lives in
-``kernels/sketch.py`` (:func:`~repro.kernels.sketch.sketch_scatter_or`)
-and is property-tested bit-identical.
+occupancy (deleted), and the one fold on every backend.
 
 Properties the CELF selection path (``coverage.select_seeds_celf``) relies
 on:
@@ -114,40 +112,17 @@ def fold_batch_packed(words, nodes, lens, row_base, *, k, mode):
     jits it directly (single device) or per shard inside ``shard_map``
     (every device folds the identical replicated batch).
     """
-    r, w = nodes.shape
-    n_rows = words.shape[0]
-    lens = jnp.minimum(jnp.maximum(lens.astype(jnp.int32), 0), w)
-    mask = jnp.arange(w, dtype=jnp.int32)[None, :] < lens[:, None]
-    row_valid = lens > 0
+    row_valid = lens.astype(jnp.int32) > 0
     rid = row_base + jnp.cumsum(row_valid, dtype=jnp.int32) - 1
-    b = jnp.broadcast_to(bucket_of(rid, k, mode)[:, None], (r, w)).reshape(-1)
-    v = jnp.where(mask, nodes.astype(jnp.int32), n_rows).reshape(-1)
-    return scatter_or_bits(words, v, b)
+    return fold_frontier_rows(words, nodes, lens, rid, k=k, mode=mode)
 
 
-def fold_frontier_rows(words, nodes, lens, row_ids, *, k, mode,
-                       interpret=None):
-    """Fold a padded frontier batch into the packed words — the pool-free
-    hot path (``mode="approximate"``).
-
-    Unlike :func:`fold_batch_packed` (sized for occasional pool-side
-    folds), this commits the raw (row, bucket) pairs without dedup: OR is
-    idempotent, so duplicates are harmless.  On compiled backends the pairs
-    go straight through :func:`~repro.kernels.sketch.sketch_scatter_or` —
-    O(E) serial RMW, the moral ``atomicOr`` loop of gIM.  Under interpret
-    mode (CPU) that kernel's per-element load/store degrades to a full
-    (R, W) copy per pair, so the fold falls back to the vectorized
-    sort-based :func:`scatter_or_bits` — property-tested bit-identical to
-    the kernel, so the dispatch is invisible in results.  ``interpret``
-    resolves through the shared kernel policy; jitted callers must resolve
-    it *outside* their trace and pass the concrete bool (it picks the
-    algorithm, so a baked-in stale choice would survive jit caching).
-
-    ``row_ids`` are the per-row global RR ids (precomputed by the caller so
-    sharded callers can number over the full batch); rows with length 0 are
-    padding.
+def fold_frontier_rows(words, nodes, lens, row_ids, *, k, mode):
+    """:func:`fold_batch_packed` with caller-supplied per-row global RR ids
+    — the pool-free hot path (``mode="approximate"``), where sharded
+    callers number rows over the full batch before slicing their block.
+    Rows with length 0 are padding.
     """
-    from repro.kernels import ops as kops
     r, w = nodes.shape
     n_rows = words.shape[0]
     lens = jnp.minimum(jnp.maximum(lens.astype(jnp.int32), 0), w)
@@ -155,21 +130,7 @@ def fold_frontier_rows(words, nodes, lens, row_ids, *, k, mode,
     b = jnp.broadcast_to(
         bucket_of(row_ids, k, mode)[:, None], (r, w)).reshape(-1)
     v = jnp.where(mask, nodes.astype(jnp.int32), n_rows).reshape(-1)
-    if kops.resolve_interpret(interpret):
-        return scatter_or_bits(words, v, b)
-    return kops.sketch_scatter_or(words, v, b, interpret=False)
-
-
-def fold_frontier_packed(words, nodes, lens, row_base, *, k, mode,
-                         interpret=None):
-    """:func:`fold_frontier_rows` with canonical batch-order row numbering
-    (``row_base`` = global rows before this batch) — the single-device
-    convenience entry; bit-identical to :func:`fold_batch_packed` on the
-    same batch."""
-    row_valid = lens.astype(jnp.int32) > 0
-    rid = row_base + jnp.cumsum(row_valid, dtype=jnp.int32) - 1
-    return fold_frontier_rows(words, nodes, lens, rid, k=k, mode=mode,
-                              interpret=interpret)
+    return scatter_or_bits(words, v, b)
 
 
 def flat_to_packed_bits(flat, ids, valid, *, n_rows, k, mode):
@@ -248,8 +209,9 @@ def union_gains(sk_words, cov_words, *, interpret=None):
     Returns a device (R,) int32 vector (R = sketch rows; callers slice off
     the sentinel row).  Δocc is a certified lower bound on the exact
     marginal coverage (see module docstring).  ``interpret`` picks the
-    algorithm (kernel vs SWAR fallback) like :func:`fold_frontier_rows`;
-    jitted callers must resolve it outside their trace.
+    algorithm (kernel vs SWAR fallback); jitted callers must resolve it
+    outside their trace (it picks the algorithm, so a baked-in stale
+    choice would survive jit caching).
     """
     from repro.kernels import ops as kops
     if kops.resolve_interpret(interpret):

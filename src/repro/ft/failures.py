@@ -1,8 +1,8 @@
 """Fault injection + retry policy for the IM pipeline (DESIGN.md §8).
 
-On real hardware a device loss or allocator pressure surfaces as an
-``XlaRuntimeError`` (often ``RESOURCE_EXHAUSTED``) out of a jitted call in
-the solver hot loop.  The recovery control flow — detect → classify →
+On real hardware a device loss or allocator pressure surfaces as a
+``jax.errors.JaxRuntimeError`` (often ``RESOURCE_EXHAUSTED``) out of a
+jitted call in the solver hot loop.  The recovery control flow — detect → classify →
 backoff → retry from the last *committed* round watermark — is
 hardware-independent, so it is what this module implements and what the
 tests drive, with :class:`FaultInjector` standing in for the runtime error
@@ -31,12 +31,14 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+import jax
+
 # the injection boundaries, in hot-loop order
 SITES = ("sample", "append", "grow", "select", "executor")
 
 
 class InjectedFailure(RuntimeError):
-    """Transient stand-in for an ``XlaRuntimeError`` at a loop boundary."""
+    """Transient stand-in for a ``JaxRuntimeError`` at a loop boundary."""
 
 
 class PoolAllocError(RuntimeError):
@@ -51,12 +53,12 @@ class DeadlineExceeded(RuntimeError):
 
 def is_transient(e: BaseException) -> bool:
     """Retryable? Injected faults and alloc failures always are; real
-    ``XlaRuntimeError``s only when they look like allocator pressure
-    (``RESOURCE_EXHAUSTED``), where a retry after freeing memory can
-    succeed — anything else propagates."""
+    ``jax.errors.JaxRuntimeError``s only when they look like allocator
+    pressure (``RESOURCE_EXHAUSTED``), where a retry after freeing memory
+    can succeed — anything else propagates."""
     if isinstance(e, (InjectedFailure, PoolAllocError)):
         return True
-    return (type(e).__name__ == "XlaRuntimeError"
+    return (isinstance(e, jax.errors.JaxRuntimeError)
             and "RESOURCE_EXHAUSTED" in str(e))
 
 

@@ -26,32 +26,32 @@ def barabasi_albert(n: int, r: int, seed: int = 0):
     # initial clique of r0 = r+1 nodes
     r0 = r + 1
     init_src, init_dst = np.triu_indices(r0, k=1)
-    srcs = [init_src.astype(np.int64)]
-    dsts = [init_dst.astype(np.int64)]
-    # repeated-endpoint pool: node id appears once per incident edge end
-    pool = np.concatenate([init_src, init_dst]).astype(np.int64)
-    pool_list = [pool]
-    pool_size = pool.shape[0]
-    new_nodes = np.arange(r0, n, dtype=np.int64)
-    for start in range(r0, n, 65536):
-        stop = min(start + 65536, n)
-        block = np.arange(start, stop, dtype=np.int64)
-        # grow pool array lazily
-        pool = np.concatenate(pool_list)
-        pool_size = pool.shape[0]
-        blk_src = np.repeat(block, r)
-        picks = rng.integers(0, pool_size, size=blk_src.shape[0])
-        blk_dst = pool[picks]
-        # NOTE: sampling the pool "frozen" per 64k block is the standard
-        # batched-BA approximation; degree distribution stays power-law.
-        srcs.append(blk_src)
-        dsts.append(blk_dst)
-        pool_list.append(np.concatenate([blk_src, blk_dst]))
-    src = np.concatenate(srcs)
-    dst = np.concatenate(dsts)
-    # drop self loops (possible via pool picks), symmetrize
-    keep = src != dst
-    src, dst = src[keep], dst[keep]
+    # repeated-endpoint pool: node id appears once per incident edge end.
+    # Layout: the clique's ends, then per new node t its r own ends followed
+    # by its r picks.  Node t draws its r picks uniformly from the pool as
+    # it stands before t, i.e. from its first p0 + 2r·t entries, so the
+    # attachment law is exactly degree-proportional at every step.
+    pool0 = np.concatenate([init_src, init_dst]).astype(np.int64)
+    p0, m = pool0.shape[0], n - r0
+    t = np.arange(m, dtype=np.int64)
+    pos = (rng.random((m, r)) * (p0 + 2 * r * t)[:, None]).astype(np.int64)
+    pos = np.minimum(pos, (p0 + 2 * r * t - 1)[:, None]).ravel()
+    off = pos - p0
+    owner, j = off // (2 * r), off % (2 * r)
+    # a pick that lands on an earlier node's own end is that node; one that
+    # lands on an earlier pick is whatever that pick resolved to
+    dst = np.where(off < 0, pool0[np.minimum(pos, p0 - 1)], r0 + owner)
+    ref = np.where((off >= 0) & (j >= r), owner * r + j - r, -1)
+    todo = np.flatnonzero(ref >= 0)
+    while todo.size:                 # pointer jumping: chains halve per pass
+        nxt = ref[todo]
+        jump = ref[nxt]
+        done = jump < 0
+        dst[todo[done]] = dst[nxt[done]]
+        ref[todo] = jump
+        todo = todo[~done]
+    src = np.concatenate([init_src, np.repeat(r0 + t, r)]).astype(np.int64)
+    dst = np.concatenate([init_dst, dst]).astype(np.int64)
     return np.concatenate([src, dst]), np.concatenate([dst, src])
 
 

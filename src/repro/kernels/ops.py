@@ -95,12 +95,6 @@ def sketch_union_popcount(words, cov, *, interpret: bool | None = None):
         words, cov, interpret=resolve_interpret(interpret))
 
 
-def sketch_scatter_or(words, v, bucket, *, interpret: bool | None = None):
-    from repro.kernels import sketch as _sketch
-    return _sketch.sketch_scatter_or(
-        words, v, bucket, interpret=resolve_interpret(interpret))
-
-
 def flash_attention(q, k, v, *, causal=True, bq=128, bk=128,
                     interpret: bool | None = None):
     from repro.kernels import flashattn as _fa

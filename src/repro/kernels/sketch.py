@@ -1,26 +1,24 @@
-"""Pallas kernels for the bottom-k / one-permutation coverage sketches.
+"""Pallas kernel for the bottom-k / one-permutation coverage sketches.
 
 The sketch subsystem (``core/sketch.py``) summarises, for every node v, the
 set of RR rows containing v as a k-bit hashed occupancy bitmap packed into
 ``k/32`` uint32 words — the same packed-bitset layout the Visited structures
-use (``kernels/bitset.py``), so these kernels are thin recombinations of
-that plumbing:
+use (``kernels/bitset.py``).
 
-* :func:`sketch_union_popcount` — per-node ``popcount(sketch[v] | covered)``,
-  the inner product of the CELF sketch estimate: the union-cardinality proxy
-  for ``|rows(v) ∪ rows(S)|`` evaluated for *all* nodes in one cross-row
-  popcount sweep (grid over node blocks, SWAR popcount per word).
-* :func:`sketch_scatter_or` — scatter-OR of (row, bucket) bit pairs into
-  the packed (R, k/32) occupancy words, the sketch *fold*.  XLA has no
-  scatter-or, so the portable fold (``core/sketch.scatter_or_bits``)
-  lexsorts + dedups + scatter-adds; this kernel is the accelerator-native
-  alternative — a serial read-modify-write loop per block, the moral
-  equivalent of gIM's ``atomicOr`` — and is property-tested bit-identical
-  to the sort-based fold.
+:func:`sketch_union_popcount` computes per-node ``popcount(sketch[v] |
+covered)``, the inner product of the CELF sketch estimate: the
+union-cardinality proxy for ``|rows(v) ∪ rows(S)|`` evaluated for *all*
+nodes in one popcount sweep.  The sketch matrix is (R, k/32) with k/32
+narrow, which XLA lays out node-minor; the kernel reads it through the
+free transposed (k/32, R) view, so each block is a (k/32, BB) tile whose
+per-node sums are a sublane reduction landing lane-major in the output.
 
-The matching ``popcount(covered)`` baseline is one :func:`_popcount` call on
-a (W,) vector — not worth a kernel.  Estimation (linear counting) happens in
-``core/sketch.py``; the kernels only produce occupancy counts.
+The sketch *fold* (scatter-OR of (row, bucket) pairs) has no kernel: the
+sort-dedup fold ``core/sketch.scatter_or_bits`` is the one path on every
+backend.  The matching ``popcount(covered)`` baseline is one
+:func:`_popcount` call on a (W,) vector — not worth a kernel.  Estimation
+(linear counting) happens in ``core/sketch.py``; the kernel only produces
+occupancy counts.
 """
 from __future__ import annotations
 
@@ -34,9 +32,10 @@ from repro.kernels.bitset import _popcount
 
 
 def _union_popcount_kernel(words_ref, cov_ref, out_ref):
-    words = words_ref[...]                        # (BB, W) uint32
-    cov = cov_ref[...]                            # (1, W) uint32, replicated
-    out_ref[...] = _popcount(words | cov).sum(axis=1).astype(jnp.int32)
+    words = words_ref[...]                        # (W, BB) uint32, node-minor
+    cov = cov_ref[...]                            # (W, 1) uint32, replicated
+    out_ref[...] = _popcount(words | cov).astype(jnp.int32).sum(
+        axis=0, keepdims=True)
 
 
 def _resolve(interpret: bool | None) -> bool:
@@ -50,7 +49,7 @@ def _resolve(interpret: bool | None) -> bool:
     return resolve_interpret(interpret)
 
 
-def sketch_union_popcount(words, cov, *, block_b: int = 256,
+def sketch_union_popcount(words, cov, *, block_b: int = 2048,
                           interpret: bool | None = None):
     """``out[v] = popcount(words[v] | cov)`` for every sketch row.
 
@@ -77,55 +76,9 @@ def _union_popcount(words, cov, *, block_b: int, interpret: bool):
     return pl.pallas_call(
         _union_popcount_kernel,
         grid=(pl.cdiv(r, bb),),
-        in_specs=[pl.BlockSpec((bb, w), lambda i: (i, 0)),
-                  pl.BlockSpec((1, w), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((bb,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((r,), jnp.int32),
+        in_specs=[pl.BlockSpec((w, bb), lambda i: (0, i)),
+                  pl.BlockSpec((w, 1), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((1, bb), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, r), jnp.int32),
         interpret=interpret,
-    )(words, cov.reshape(1, w))
-
-
-def _scatter_or_kernel(words_ref, v_ref, w_ref, bit_ref, out_ref):
-    out_ref[...] = words_ref[...]
-
-    def body(e, carry):
-        vv = v_ref[e]
-        wi = w_ref[e]
-        cur = pl.load(out_ref, (vv, wi))
-        pl.store(out_ref, (vv, wi), cur | bit_ref[e])
-        return carry
-
-    jax.lax.fori_loop(0, v_ref.shape[0], body, 0)
-
-
-def sketch_scatter_or(words, v, bucket, *, interpret: bool | None = None):
-    """``out[v[e], bucket[e]//32] |= 1 << (bucket[e] % 32)`` for every pair.
-
-    ``words``: (R, W) uint32 packed occupancy; ``v``/``bucket``: (E,) int32.
-    Pairs with ``v`` out of ``[0, R)`` are dropped.  OR is idempotent, so
-    duplicates need no dedup — this is the scatter-OR the sort-based fold
-    (``core/sketch.scatter_or_bits``) emulates; a serial RMW loop stands in
-    for the GPU's ``atomicOr`` (one pallas block owns the whole matrix, so
-    the loop is race-free by construction).
-
-    ``interpret=None`` (default) defers to ``ops.resolve_interpret``; the
-    compiled Mosaic path is reachable without an explicit flag on
-    accelerator backends.
-    """
-    return _scatter_or(words, v, bucket, interpret=_resolve(interpret))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _scatter_or(words, v, bucket, *, interpret: bool):
-    r, w = words.shape
-    valid = (v >= 0) & (v < r)
-    v_safe = jnp.where(valid, v, 0).astype(jnp.int32)
-    wi = jnp.where(valid, bucket >> 5, 0).astype(jnp.int32)
-    bit = jnp.where(
-        valid, jnp.uint32(1) << (bucket & 31).astype(jnp.uint32),
-        jnp.uint32(0))
-    return pl.pallas_call(
-        _scatter_or_kernel,
-        out_shape=jax.ShapeDtypeStruct((r, w), jnp.uint32),
-        interpret=interpret,
-    )(words, v_safe, wi, bit)
+    )(words.T, cov.reshape(w, 1)).reshape(r)

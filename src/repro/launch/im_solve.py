@@ -22,13 +22,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map_unchecked
 from repro.graph import csr, generators, weights
 from repro.core import rrset
 from repro.core.engine import (RRBatch, build_alias_table, draw_roots,
                                register_engine, resolve_qcap)
 from repro.core.imm import IMMSolver
 from repro.core.problem import IMProblem
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_sample_mesh
 
 
@@ -107,8 +107,12 @@ class ShardedQueueEngine:
             jax.device_put(x, rep)
             for x in (g_rev.offsets, g_rev.indices, g_rev.weights))
         self._rep_sharding = rep
-        return jax.jit(shard_map_unchecked(
-            local, mesh=mesh,
+        # on-device all-gather to a replicated copy: a device_put of a
+        # sharded array onto one device would gather through the host,
+        # which the solver's transfer guard refuses on a TPU
+        self._replicate = jax.jit(lambda *xs: xs, out_shardings=rep)
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh, check_vma=False,
             in_specs=(P(), P(), P(), P()),
             out_specs=(P(axis), P(axis), P(axis), P(axis))))
 
@@ -121,15 +125,19 @@ class ShardedQueueEngine:
                                  self._rep_sharding)
         return self._fn(*self._replicated, keydata)
 
+    def _to_dev0(self, *xs):
+        """Mesh arrays onto device 0 with no host round trip: replicate on
+        the mesh, then take device 0's copy (explicit, guard-legal)."""
+        dev0 = self.mesh.devices.reshape(-1)[0]
+        return tuple(jax.device_put(x, dev0) for x in self._replicate(*xs))
+
     def sample(self, key) -> RRBatch:
         nodes, lengths, overflow, steps = self._sample_raw(key)
         n_dev = self.mesh.devices.size
-        dev0 = self.mesh.devices.reshape(-1)[0]
         # gather the per-device rows onto one device for a single-device
-        # consumer (explicit device_puts, guard-legal)
-        nodes, lengths, overflow, steps = (
-            jax.device_put(x, dev0)
-            for x in (nodes, lengths, overflow, steps))
+        # consumer
+        nodes, lengths, overflow, steps = self._to_dev0(
+            nodes, lengths, overflow, steps)
         # devices run concurrently: the batch's parallel-time cost is the
         # slowest device's lockstep count, not the sum
         return RRBatch.make(nodes.reshape(n_dev * self.config.batch, -1),
@@ -148,9 +156,7 @@ class ShardedQueueEngine:
         performs."""
         nodes, lengths, overflow, steps = self._sample_raw(key)
         n_dev = self.mesh.devices.size
-        dev0 = self.mesh.devices.reshape(-1)[0]
-        overflow, steps = (jax.device_put(x, dev0)
-                           for x in (overflow, steps))
+        overflow, steps = self._to_dev0(overflow, steps)
         return RRBatch.make(nodes.reshape(n_dev * self.config.batch, -1),
                             lengths.reshape(-1), overflow.reshape(-1),
                             steps.max())
@@ -463,6 +469,7 @@ def main():
                          "executor; default N=1) and recover via the retry "
                          "policy")
     args = ap.parse_args()
+    configure_compile_cache()
     if args.checkpoint_every < 1:
         raise SystemExit("--checkpoint-every: must be >= 1, got "
                          f"{args.checkpoint_every}")

@@ -7,8 +7,8 @@ part of the system:
   (sum/mean modes) over a flat multi-hot id list with offsets-style segments.
 * :func:`sharded_lookup` — mod/row-sharded tables: each device holds a
   contiguous row slice; lookup = masked local gather + ``psum`` over the
-  table axis (DLRM-style model-parallel embeddings).  Used inside shard_map
-  (import it from :mod:`repro.compat` — its home moved across jax releases).
+  table axis (DLRM-style model-parallel embeddings).  Used inside
+  ``jax.shard_map``.
 """
 from __future__ import annotations
 

@@ -376,6 +376,8 @@ def main(argv=None) -> None:
                     help="disable batched stacked selection (baseline)")
     ap.add_argument("--spill-dir", default=None)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     asyncio.run(serve_main(args))
 
 

@@ -75,10 +75,21 @@ def test_is_transient_classification():
     assert not is_transient(ValueError("x"))
     assert not is_transient(DeadlineExceeded("x"))
 
+    assert is_transient(jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: oom"))
+    assert not is_transient(
+        jax.errors.JaxRuntimeError("INTERNAL: device lost"))
+
+
+def test_is_transient_requires_the_runtime_error_type():
+    """Only the runtime's own error class counts as allocator pressure: a
+    look-alike carrying the same message (the old name-based match) does
+    not, and neither does a plain RuntimeError."""
     class XlaRuntimeError(RuntimeError):
         pass
-    assert is_transient(XlaRuntimeError("RESOURCE_EXHAUSTED: oom"))
-    assert not is_transient(XlaRuntimeError("INTERNAL: device lost"))
+    assert not is_transient(XlaRuntimeError("RESOURCE_EXHAUSTED: oom"))
+    assert not is_transient(RuntimeError("RESOURCE_EXHAUSTED: oom"))
+    assert is_transient(jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Out of memory allocating 17179869184 bytes"))
 
 
 def test_injector_validates_sites_and_counts():

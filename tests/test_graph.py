@@ -59,9 +59,39 @@ def test_barabasi_albert_properties():
     # symmetric edge set
     fwd = set(zip(src.tolist(), dst.tolist()))
     assert all((d, s) in fwd for s, d in list(fwd)[:500])
-    # power-law-ish: max degree much larger than mean
+    # power-law-ish: max degree much larger than mean, but of BA's order
+    # r·sqrt(n), not a handful of hubs holding every early node
     deg = np.bincount(src, minlength=2000)
     assert deg.max() > 5 * deg.mean()
+    assert deg.max() < 4 * 3 * np.sqrt(2000)
+
+
+def _barabasi_albert_sequential(n, r, seed):
+    """Node-by-node preferential attachment over a growing endpoint pool,
+    drawing the same uniforms as the vectorized generator."""
+    rng = np.random.default_rng(seed)
+    r0 = r + 1
+    a, b = np.triu_indices(r0, k=1)
+    pool = list(np.concatenate([a, b]))
+    u = rng.random((n - r0, r))
+    src, dst = list(a), list(b)
+    for t in range(n - r0):
+        size = len(pool)
+        picks = [pool[min(int(u[t, i] * size), size - 1)] for i in range(r)]
+        pool += [r0 + t] * r + picks
+        src += [r0 + t] * r
+        dst += picks
+    src, dst = np.asarray(src), np.asarray(dst)
+    return np.concatenate([src, dst]), np.concatenate([dst, src])
+
+
+@pytest.mark.parametrize("n,r,seed", [(40, 3, 7), (600, 4, 0),
+                                      (5000, 6, 1), (70_000, 2, 3)])
+def test_barabasi_albert_matches_sequential_attachment(n, r, seed):
+    got = generators.barabasi_albert(n, r, seed=seed)
+    want = _barabasi_albert_sequential(n, r, seed)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
 
 
 def test_icosahedral_multimesh_counts():

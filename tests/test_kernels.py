@@ -143,10 +143,11 @@ def test_flash_attention_noncausal():
 
 
 def test_sketch_kernels_defer_interpret_to_ops_policy(monkeypatch):
-    # regression: the sketch kernels used to hardcode interpret=True, so the
-    # compiled Mosaic path was unreachable on accelerator backends.  They
+    # regression: the sketch kernel used to hardcode interpret=True, so the
+    # compiled Mosaic path was unreachable on accelerator backends.  It
     # must resolve interpret=None through the shared ops policy (per-call >
     # module override > env > backend default) like every other kernel.
+    from repro.core import sketch as core_sk
     from repro.kernels import sketch as sk
     seen = []
     real = ops.resolve_interpret
@@ -156,17 +157,18 @@ def test_sketch_kernels_defer_interpret_to_ops_policy(monkeypatch):
         return real(flag)
 
     monkeypatch.setattr(ops, "resolve_interpret", recorder)
-    words = jnp.zeros((8, 2), jnp.uint32)
-    out = sk.sketch_scatter_or(words, jnp.asarray([1, 3, 99], jnp.int32),
-                               jnp.asarray([0, 33, 5], jnp.int32))
-    assert seen == [None]           # default defers to the shared policy
-    got = np.asarray(out)
+    words = core_sk.scatter_or_bits(
+        jnp.zeros((8, 2), jnp.uint32), jnp.asarray([1, 3, 99], jnp.int32),
+        jnp.asarray([0, 33, 5], jnp.int32))
+    got = np.asarray(words)
     assert got[1, 0] == 1 and got[3, 1] == 2   # bit 0 / bit 33
     assert got.sum() == 3                       # oob row 99 dropped
 
-    seen.clear()
     cov_words = jnp.asarray(np.asarray([1, 0], np.uint32))
-    cnt = sk.sketch_union_popcount(out, cov_words, interpret=True)
+    cnt = sk.sketch_union_popcount(words, cov_words)
+    assert seen == [None]           # default defers to the shared policy
+    seen.clear()
+    cnt = sk.sketch_union_popcount(words, cov_words, interpret=True)
     assert seen == [True]           # explicit flag still wins
     want = np.asarray([np.uint32(r[0] | 1).bit_count() + r[1].bit_count()
                        for r in got], np.int32)

@@ -262,7 +262,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.models.embedding import sharded_lookup
 table = jnp.asarray(np.random.default_rng(0).normal(size=(32, 4)),
                     jnp.float32)

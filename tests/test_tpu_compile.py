@@ -1,0 +1,134 @@
+"""Ahead-of-time compiles for a described TPU v5e: no chip is needed.
+
+Interpret mode (every other test) cannot see what the TPU compiler refuses:
+unaligned blocks, unsupported casts and reductions, layouts Mosaic cannot
+infer, VMEM overflows.  These tests lower every Pallas kernel on a
+user-reachable path with ``interpret=False`` at the node widths of the
+``im_paper`` stand-ins (epinions-like n=75,879 and pokec-like
+n=1,632,803), plus the fused and bitset selection ``shard_map`` programs
+on a 1-chip and a 4-chip mesh, and compile them for ``v5e:2x2``.
+
+The topology is described inside a module fixture only: the TPU library
+may be loaded by one process at a time, so nothing here touches it while
+modules are imported or tests are collected.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from repro.core.coverage import bitset_words
+from repro.kernels import bitset, ops
+from repro.kernels import sketch as ksketch
+
+WIDTHS = {"epinions": 75_879, "pokec": 1_632_803}   # configs/im_paper.py
+SKETCH_K = 1024                                       # store default
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:           # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def chip_compiles(topo):
+    """Compiled kernels, no persistent cache: a described chip's programs
+    cannot be read back from the cache, so writing them would only make
+    later compiles warn.  The backend here is the CPU, so the kernel
+    dispatch is steered to Mosaic explicitly."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was_cache = jax.config.jax_enable_compilation_cache
+    was_interpret = ops.INTERPRET
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    ops.INTERPRET = False
+    yield
+    ops.INTERPRET = was_interpret
+    jax.config.update("jax_enable_compilation_cache", was_cache)
+    cc.reset_cache()
+
+
+def _compile(fn, *args, **static):
+    compiled = jax.jit(fn, static_argnames=tuple(static)).lower(
+        *args, **static).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_occur_kernels_compile(width, one_chip, chip_compiles):
+    w = bitset_words(WIDTHS[width])
+    rows = 4096
+    words = _sds((rows, w), jnp.uint32, one_chip)
+    mask = _sds((rows,), jnp.bool_, one_chip)
+    c = _compile(lambda m, k: bitset.occur_from_bitset_masked(
+        m, k, interpret=False), words, mask)
+    # the word matrix reaches the kernel in place: no relayout copy of it
+    assert c.memory_analysis().temp_size_in_bytes < rows * w * 4 // 8
+    _compile(lambda m: bitset.occur_from_bitset(m, interpret=False), words)
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_union_popcount_kernel_compiles(width, one_chip, chip_compiles):
+    rows = WIDTHS[width] + 1
+    c = _compile(
+        lambda s, c: ksketch.sketch_union_popcount(s, c, interpret=False),
+        _sds((rows, SKETCH_K // 32), jnp.uint32, one_chip),
+        _sds((SKETCH_K // 32,), jnp.uint32, one_chip))
+    assert c.memory_analysis().temp_size_in_bytes < rows * SKETCH_K // 32
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_selection_programs_compile(width, chips, topo, chip_compiles):
+    """The fused and bitset Alg. 7 scans as the solver runs them: one
+    ``shard_map`` over a ``samples`` mesh, pool buffers sharded per chip,
+    at a pool that fills a realistic share of 16 GB."""
+    from repro.core.coverage import _mesh_select_fns
+    n = WIDTHS[width]
+    mesh = Mesh(np.asarray(topo.devices[:chips]), ("samples",))
+    buf = NamedSharding(mesh, P("samples", None))
+    vec = NamedSharding(mesh, P("samples"))
+    b3 = NamedSharding(mesh, P("samples", None, None))
+    fns = _mesh_select_fns(mesh)
+
+    rows, elems = 1 << 20, 1 << 22                  # per shard
+    flat = _sds((chips, elems), jnp.int32, buf)
+    valid = _sds((chips, elems), jnp.bool_, buf)
+    nrr = _sds((chips,), jnp.int32, vec)
+    c = fns.fused.lower(flat, flat, valid, nrr, num_rows=rows, n=n,
+                        k=50).compile()
+    assert c.memory_analysis().temp_size_in_bytes < 2 << 30
+
+    # the bitset backend's pool: auto picks it while the bit matrix is no
+    # larger than the flat buffers, so it holds few rows at pokec width
+    m_rows = 1 << 16 if n < 500_000 else 1 << 12
+    m = _sds((chips, m_rows, bitset_words(n)), jnp.uint32, b3)
+    c = fns.bitset.lower(m, nrr, k=50).compile()
+    assert "tpu_custom_call" in c.as_text()
+
+    sk = _sds((chips, -(-(n + 1) // chips) * chips, SKETCH_K // 32),
+              jnp.uint32, b3)
+    cov = _sds((chips, SKETCH_K // 32), jnp.uint32, buf)
+    c = fns.sweep.lower(sk, cov, stripe=sk.shape[1] // chips,
+                        interpret=False).compile()
+    assert "tpu_custom_call" in c.as_text()
