@@ -2,7 +2,7 @@
 
 ``PYTHONPATH=src python -m benchmarks.run [--only NAME]``
 Prints ``name,us_per_call,derived`` CSV lines; writes per-table CSVs to
-experiments/bench/ and, when dry-run artifacts exist, the roofline summary.
+experiments/bench/.
 """
 from __future__ import annotations
 

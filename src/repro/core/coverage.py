@@ -37,6 +37,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import sketch as sketch_mod
+from repro.core import spans
 from repro.core.packing import rank_positions
 from repro.ft.failures import PoolAllocError
 from repro.kernels import ops as kops
@@ -605,7 +606,7 @@ class ShardedDeviceRRStore:
                                           n=self.n_nodes)
             if self.row_weighted:
                 roww = _pad_row_weights(roww, pad=pad)
-        counts = np.asarray(jax.device_get(
+        counts = np.asarray(spans.fetch("shard_counts",
             _shard_counts(lens, d=d, width=w)), np.int64)
         elems_l, rows_l = counts[:, 0], counts[:, 1]
         # wide batches (device engine padding ≫ payload) go through the
@@ -1184,7 +1185,7 @@ class SketchRRStore:
         if pad:
             nodes, lens = _pad_batch_rows(nodes, lens, pad=pad,
                                           n=self.n_nodes)
-        counts = np.asarray(jax.device_get(
+        counts = np.asarray(spans.fetch("shard_counts",
             _shard_counts(lens, d=d, width=w)), np.int64)
         base = jax.device_put(np.int32(self.n_rr), self._sh_rep)
         nodes_rep = jax.device_put(nodes, self._sh_rep)
@@ -2154,7 +2155,7 @@ def select_seeds_celf(store: "ShardedDeviceRRStore", k: int, *,
     flat, ids, valid = store._flat, store._ids, store._valid
     c = max(1, min(eval_batch, n))
 
-    ub = np.asarray(jax.device_get(
+    ub = np.asarray(spans.fetch("select",
         fns.occur(flat, valid, n=n)), dtype=np.int64).copy()
     fresh = np.zeros(n, bool)
     # explicit placement: plain jnp.zeros is an implicit h2d transfer and
@@ -2176,7 +2177,7 @@ def select_seeds_celf(store: "ShardedDeviceRRStore", k: int, *,
         cands = np.asarray(cands, np.int32)
         pad = np.full(c, -1, np.int32)
         pad[:len(cands)] = cands
-        g = np.asarray(jax.device_get(fns.eval_batch(
+        g = np.asarray(spans.fetch("select", fns.eval_batch(
             flat, ids, valid, cov_words,
             jax.device_put(pad, store._sh_rep))))
         ub[cands] = g[:len(cands)]
@@ -2193,7 +2194,7 @@ def select_seeds_celf(store: "ShardedDeviceRRStore", k: int, *,
             # before entering the lazy loop (O(n) top-c selection —
             # eval-batch composition affects only the eval count, never
             # the accepted seed)
-            deltas = np.asarray(jax.device_get(
+            deltas = np.asarray(spans.fetch("select",
                 fns.sweep(sk_words, cov_sk, stripe=stripe, interpret=itp)))[:n]
             key = deltas.astype(np.int64) * (n + 1) - node_ids
             eval_exact(np.argpartition(-key, c - 1)[:c])
@@ -2215,7 +2216,7 @@ def select_seeds_celf(store: "ShardedDeviceRRStore", k: int, *,
                                              u_dev)
         if use_sketch:
             cov_sk = fns.union(cov_sk, sk_words, u_dev)
-        gain = int(jax.device_get(gain_dev))
+        gain = int(spans.fetch("select", gain_dev))
         ub[u] = 0                        # exact: u's rows are now covered
         seeds.append(u)
         gains.append(gain)
@@ -2279,7 +2280,7 @@ def select_seeds_sketch(store, k: int, *, cand=None,
     n_rr = store.n_rr
     seeds, gains = [], []
     for _ in range(k):
-        deltas = np.asarray(jax.device_get(
+        deltas = np.asarray(spans.fetch("select",
             fns.sweep(sk_words, cov_sk, stripe=stripe, interpret=itp)))[:n].astype(np.int64)
         score = np.where(mask, deltas, -1)
         u = int(np.argmax(score))        # first max == lowest id on ties
@@ -2367,12 +2368,12 @@ def _celf_variant(store: "ShardedDeviceRRStore", spec: SelectionSpec, *,
     spent32 = np.float32(0.0)
 
     if weighted:
-        ub = np.asarray(jax.device_get(fns.occur_weighted(
+        ub = np.asarray(spans.fetch("select", fns.occur_weighted(
             flat, valid, store._ew, n=n)), np.float64).copy()
-        denom = float(jax.device_get(fns.total_weight(store._w_dev)))
+        denom = float(spans.fetch("select", fns.total_weight(store._w_dev)))
         roww_dev = fns.row_weights(ids, valid, store._ew, num_rows=num_rows)
     else:
-        ub = np.asarray(jax.device_get(
+        ub = np.asarray(spans.fetch("select",
             fns.occur(flat, valid, n=n)), np.float64).copy()
         denom = float(max(store.n_rr, 1))
     fresh = np.zeros(n, bool)
@@ -2395,10 +2396,10 @@ def _celf_variant(store: "ShardedDeviceRRStore", spec: SelectionSpec, *,
         pad[:len(cands)] = cands
         pad_dev = jax.device_put(pad, store._sh_rep)
         if weighted:
-            g = np.asarray(jax.device_get(fns.eval_batch_w(
+            g = np.asarray(spans.fetch("select", fns.eval_batch_w(
                 flat, ids, valid, roww_dev, cov_words, pad_dev)))
         else:
-            g = np.asarray(jax.device_get(fns.eval_batch(
+            g = np.asarray(spans.fetch("select", fns.eval_batch(
                 flat, ids, valid, cov_words, pad_dev)))
         ub[cands] = g[:len(cands)]
         fresh[cands] = True
@@ -2434,7 +2435,7 @@ def _celf_variant(store: "ShardedDeviceRRStore", spec: SelectionSpec, *,
             break
         fresh[:] = False
         if use_sketch:
-            deltas = np.asarray(jax.device_get(
+            deltas = np.asarray(spans.fetch("select",
                 fns.sweep(sk_words, cov_sk, stripe=stripe, interpret=itp)))[:n]
             est = np.where(feas, deltas / costs if use_costs
                            else deltas.astype(np.float64), -np.inf)
@@ -2471,7 +2472,7 @@ def _celf_variant(store: "ShardedDeviceRRStore", spec: SelectionSpec, *,
                                                  u_dev)
         if use_sketch:
             cov_sk = fns.union(cov_sk, sk_words, u_dev)
-        gain = jax.device_get(gain_dev)
+        gain = spans.fetch("select", gain_dev)
         ub[u] = 0.0
         picked[u] = True
         gbud[group_of[u]] -= 1

@@ -66,6 +66,7 @@ from repro.graph.csr import CSRGraph, reverse
 from repro.ckpt import checkpoint as ckpt_mod
 from repro.core import coverage as cov
 from repro.core import sketch as sketch_mod
+from repro.core import spans
 from repro.core.oracle import imm_theta_params
 from repro.core.problem import (IMProblem, IMResult, ResolvedProblem,
                                 problem_from_state, problem_state)
@@ -106,6 +107,9 @@ class IMMStats:
     mesh_shape: tuple = (1,)
     pool_sharding: str = "samples:1"
     per_device_pool_bytes: int = 0
+    # explicit device-to-host fetches made inside solves (one per
+    # ``imm.fetch`` span, core/spans.py)
+    host_fetches: int = 0
     # resume watermark for the Alg. 2 LB loop: index of the last LB
     # iteration that finished *without* breaking.  A restored solve skips
     # iterations <= lb_completed instead of re-running them over the (now
@@ -195,6 +199,7 @@ class IMMSolver:
         self._ckpt_keep = int(checkpoint_keep)
         self._last_ckpt_round = 0
         self._active_solve: Optional[str] = None
+        self._solves = 0                   # the ``imm.solve`` span's id
         if isinstance(engine, str):
             self.g_rev = reverse(g)
         else:
@@ -288,153 +293,160 @@ class IMMSolver:
     def _prepare(self, problem: IMProblem,
                  _store: "cov.ShardedDeviceRRStore | None" = None
                  ) -> ResolvedProblem:
-        r = problem.resolve(self.n)
-        # the constructor's model= survives as the default for problems that
-        # don't set one (IMProblem.model=None); an explicit model on the
-        # problem — including "ic" — always wins
-        model = problem.model or self._default_model()
-        if problem.t_rounds is not None and model == "lt":
-            raise ValueError("MRIM sampling is IC-only (paper §4.8); the "
-                             "solver's default model is 'lt'")
-        w = r.node_weights
-        # the celf path estimates from the incremental coverage sketch, and
-        # the θ early-exit gate reads it (an incremental fold is required:
-        # its global row numbering keeps the occupancy==count identity on
-        # any mesh — the on-demand per-shard fold does not)
-        sketch_k = self._sketch_k_arg
-        if sketch_k is None and (self._sel_method == "celf"
-                                 or problem.early_exit):
-            sketch_k = cov.ShardedDeviceRRStore.DEFAULT_SKETCH_K
-        # approximate (pool-free) mode: the sketch IS the pool, so one
-        # always exists, auto-sized from (ε, n) so the certified estimator
-        # error stays within ε/2 at design load (core/sketch.auto_sketch_k)
-        approx = problem.mode == "approximate"
-        if approx and sketch_k is None:
-            sketch_k = sketch_mod.auto_sketch_k(problem.eps, self.n)
-        # engine/pool lifecycle is keyed on the problem's canonical pool
-        # signature (content hash of model/t_rounds/node_weights — see
-        # IMProblem.pool_digest): problems differing only in weight *values*
-        # can never alias one pool, unlike the old hash(tobytes) tuple key
-        if isinstance(self._engine_arg, str):
-            name = ("mrim" if problem.t_rounds is not None
-                    else resolve_engine_name(self._engine_arg, model))
-            sig = ("name", name, problem.pool_digest(model=model), sketch_k)
-        else:
-            sig = ("inst", id(self._engine_arg), problem.pool_digest(),
-                   sketch_k)
-        if sig == self._sig:
+        with spans.span("imm.prepare"):
+            r = problem.resolve(self.n)
+            # the constructor's model= survives as the default for problems
+            # that don't set one (IMProblem.model=None); an explicit model on
+            # the problem — including "ic" — always wins
+            model = problem.model or self._default_model()
+            if problem.t_rounds is not None and model == "lt":
+                raise ValueError("MRIM sampling is IC-only (paper §4.8); the "
+                                 "solver's default model is 'lt'")
+            w = r.node_weights
+            # the celf path estimates from the incremental coverage sketch,
+            # and the θ early-exit gate reads it (an incremental fold is
+            # required: its global row numbering keeps the occupancy==count
+            # identity on any mesh — the on-demand per-shard fold does not)
+            sketch_k = self._sketch_k_arg
+            if sketch_k is None and (self._sel_method == "celf"
+                                     or problem.early_exit):
+                sketch_k = cov.ShardedDeviceRRStore.DEFAULT_SKETCH_K
+            # approximate (pool-free) mode: the sketch IS the pool, so one
+            # always exists, auto-sized from (ε, n) so the certified
+            # estimator error stays within ε/2 at design load
+            # (core/sketch.auto_sketch_k)
+            approx = problem.mode == "approximate"
+            if approx and sketch_k is None:
+                sketch_k = sketch_mod.auto_sketch_k(problem.eps, self.n)
+            # engine/pool lifecycle is keyed on the problem's canonical pool
+            # signature (content hash of model/t_rounds/node_weights — see
+            # IMProblem.pool_digest): problems differing only in weight
+            # *values* can never alias one pool, unlike the old
+            # hash(tobytes) tuple key
+            if isinstance(self._engine_arg, str):
+                name = ("mrim" if problem.t_rounds is not None
+                        else resolve_engine_name(self._engine_arg, model))
+                sig = ("name", name, problem.pool_digest(model=model),
+                       sketch_k)
+            else:
+                sig = ("inst", id(self._engine_arg), problem.pool_digest(),
+                       sketch_k)
+            if sig == self._sig:
+                return r
+            # (re)build engine + pool for this problem signature
+            row_weight_mode = False
+            if isinstance(self._engine_arg, str):
+                opts = dict(self._engine_opts)
+                if problem.t_rounds is not None:
+                    opts["t_rounds"] = problem.t_rounds
+                engine = make_engine(name, self.g_rev, root_weights=w, **opts)
+            else:
+                engine = self._engine_arg
+                eng_w = getattr(engine, "root_weights", None)
+                if w is None and eng_w is not None:
+                    # converse mismatch: the engine samples roots ∝ its own
+                    # weights, so a plain solve on it would silently return
+                    # the weighted objective on the uniform scale — a wrong
+                    # number
+                    raise ValueError(
+                        "engine instance draws weighted roots (root_weights "
+                        "set) but the problem has no node_weights; set "
+                        "node_weights on the IMProblem (or use an unweighted "
+                        "engine)")
+                if w is not None and not (
+                        eng_w is not None
+                        and np.array_equal(np.asarray(eng_w, np.float32), w)):
+                    # instance without matching weighted-root sampling: fall
+                    # back to the importance-weighted row estimator (uniform
+                    # roots, rows weighted by node_weights[root])
+                    row_weight_mode = True
+            if engine.item_space != r.n_items:
+                raise ValueError(
+                    f"engine {getattr(engine, 'name', '?')!r} samples an "
+                    f"item space of {engine.item_space}, not the problem's "
+                    f"{r.n_items} items; tagged engines need a matching "
+                    f"t_rounds= on the IMProblem")
+            if approx:
+                # same sampler, same RNG stream — only the batch
+                # *destination* changes: appends fold into the pool-free
+                # sketch store below
+                engine = FusedSketchEngine(engine)
+            self._engine_obj = engine
+            self.engine_name = getattr(engine, "name", type(engine).__name__)
+            self._row_weight_mode = row_weight_mode
+            self._node_w_dev = (jax.device_put(w) if row_weight_mode else None)
+            # mesh placement is decided exactly once, here: the pool, the
+            # sketch, and every selection backend live on this mesh for the
+            # solver's lifetime (mesh=None -> the 1-device mesh special case)
+            if _store is not None:                   # adopt_pool() hand-off
+                want_k = (sketch_mod.resolve_sketch_k(sketch_k)
+                          if sketch_k is not None else None)
+                if getattr(_store, "pool_free", False) != approx:
+                    raise ValueError(
+                        "adopted pool kind does not match the problem mode: "
+                        "a pool-free sketch store can only back "
+                        "mode='approximate' solves, and an exact pool only "
+                        "exact ones")
+                if (_store.n_nodes != engine.item_space
+                        or _store.row_weighted != row_weight_mode
+                        or _store.sketch_k != want_k):
+                    raise ValueError(
+                        "adopted pool does not match the problem signature: "
+                        f"store (n={_store.n_nodes}, row_weighted="
+                        f"{_store.row_weighted}, sketch_k={_store.sketch_k}) "
+                        f"vs engine (n={engine.item_space}, row_weighted="
+                        f"{row_weight_mode}, sketch_k={want_k})")
+                if self._mesh is not None and _store.mesh != self._mesh:
+                    raise ValueError("adopted pool lives on a different mesh "
+                                     "than the solver's mesh= argument")
+                self._store_obj = _store
+            elif approx:
+                # pool-free: the flat pool / ids / valid buffers are never
+                # allocated — frontier batches fold straight into the packed
+                # sketch words (the DiFuseR-mode memory model, DESIGN.md §10)
+                self._store_obj = cov.SketchRRStore(
+                    engine.item_space, sketch_k=sketch_k, mesh=self._mesh)
+            else:
+                self._store_obj = cov.ShardedDeviceRRStore(
+                    engine.item_space, sketch_k=sketch_k, mesh=self._mesh,
+                    row_weighted=row_weight_mode)
+            if self.fault_policy is not None:
+                # gate pool growth through the policy's "grow" site, so an
+                # injected (or real) allocation failure surfaces *before* any
+                # buffer is re-allocated and the append stays retryable
+                pol = self.fault_policy
+                self._store_obj.alloc_check = (
+                    lambda store, newcap: pol.check(
+                        "grow", {"newcap": newcap,
+                                 "bytes": newcap * store.n_shards * 9}))
+            self._sig = sig
+            self._sig_problem = problem
+            store = self._store_obj
+            self._stats = IMMStats(
+                selection=self.selection,
+                variant=problem.variant,
+                mesh_shape=tuple(int(s) for s in store.mesh.devices.shape),
+                pool_sharding=f"{store.axis}:{store.n_shards}")
+            self._stats_dirty = False
+            # stats accumulate as device scalars; materialized once per
+            # sample_until / on `stats` access, not per round
+            self._steps_acc = jnp.zeros((), jnp.int32)
+            self._ovf_acc = jnp.zeros((), jnp.int32)
+            self._ovf_lanes = 0
+            # engines advertising full device residency let the solver hold a
+            # transfer guard over the whole hot loop; host-path engines (e.g.
+            # third-party adapters) fall back to unguarded execution
+            self._guard = ("disallow"
+                           if getattr(engine, "device_resident", False)
+                           else "allow")
+            self._sample = getattr(engine, "sample_device", engine.sample)
+            # a sharded engine on the *same* mesh hands the store rows that are
+            # already resident on their sampling device — no dev0 gather
+            if (store.n_shards > 1
+                    and getattr(engine, "mesh", None) == store.mesh
+                    and hasattr(engine, "sample_sharded")):
+                self._sample = engine.sample_sharded
             return r
-        # (re)build engine + pool for this problem signature
-        row_weight_mode = False
-        if isinstance(self._engine_arg, str):
-            opts = dict(self._engine_opts)
-            if problem.t_rounds is not None:
-                opts["t_rounds"] = problem.t_rounds
-            engine = make_engine(name, self.g_rev, root_weights=w, **opts)
-        else:
-            engine = self._engine_arg
-            eng_w = getattr(engine, "root_weights", None)
-            if w is None and eng_w is not None:
-                # converse mismatch: the engine samples roots ∝ its own
-                # weights, so a plain solve on it would silently return the
-                # weighted objective on the uniform scale — a wrong number
-                raise ValueError(
-                    "engine instance draws weighted roots (root_weights "
-                    "set) but the problem has no node_weights; set "
-                    "node_weights on the IMProblem (or use an unweighted "
-                    "engine)")
-            if w is not None and not (
-                    eng_w is not None
-                    and np.array_equal(np.asarray(eng_w, np.float32), w)):
-                # instance without matching weighted-root sampling: fall
-                # back to the importance-weighted row estimator (uniform
-                # roots, rows weighted by node_weights[root])
-                row_weight_mode = True
-        if engine.item_space != r.n_items:
-            raise ValueError(
-                f"engine {getattr(engine, 'name', '?')!r} samples an "
-                f"item space of {engine.item_space}, not the problem's "
-                f"{r.n_items} items; tagged engines need a matching "
-                f"t_rounds= on the IMProblem")
-        if approx:
-            # same sampler, same RNG stream — only the batch *destination*
-            # changes: appends fold into the pool-free sketch store below
-            engine = FusedSketchEngine(engine)
-        self._engine_obj = engine
-        self.engine_name = getattr(engine, "name", type(engine).__name__)
-        self._row_weight_mode = row_weight_mode
-        self._node_w_dev = (jax.device_put(w) if row_weight_mode else None)
-        # mesh placement is decided exactly once, here: the pool, the
-        # sketch, and every selection backend live on this mesh for the
-        # solver's lifetime (mesh=None -> the 1-device mesh special case)
-        if _store is not None:                   # adopt_pool() hand-off
-            want_k = (sketch_mod.resolve_sketch_k(sketch_k)
-                      if sketch_k is not None else None)
-            if getattr(_store, "pool_free", False) != approx:
-                raise ValueError(
-                    "adopted pool kind does not match the problem mode: a "
-                    "pool-free sketch store can only back mode='approximate'"
-                    " solves, and an exact pool only exact ones")
-            if (_store.n_nodes != engine.item_space
-                    or _store.row_weighted != row_weight_mode
-                    or _store.sketch_k != want_k):
-                raise ValueError(
-                    "adopted pool does not match the problem signature: "
-                    f"store (n={_store.n_nodes}, row_weighted="
-                    f"{_store.row_weighted}, sketch_k={_store.sketch_k}) "
-                    f"vs engine (n={engine.item_space}, row_weighted="
-                    f"{row_weight_mode}, sketch_k={want_k})")
-            if self._mesh is not None and _store.mesh != self._mesh:
-                raise ValueError("adopted pool lives on a different mesh "
-                                 "than the solver's mesh= argument")
-            self._store_obj = _store
-        elif approx:
-            # pool-free: the flat pool / ids / valid buffers are never
-            # allocated — frontier batches fold straight into the packed
-            # sketch words (the DiFuseR-mode memory model, DESIGN.md §10)
-            self._store_obj = cov.SketchRRStore(
-                engine.item_space, sketch_k=sketch_k, mesh=self._mesh)
-        else:
-            self._store_obj = cov.ShardedDeviceRRStore(
-                engine.item_space, sketch_k=sketch_k, mesh=self._mesh,
-                row_weighted=row_weight_mode)
-        if self.fault_policy is not None:
-            # gate pool growth through the policy's "grow" site, so an
-            # injected (or real) allocation failure surfaces *before* any
-            # buffer is re-allocated and the append stays retryable
-            pol = self.fault_policy
-            self._store_obj.alloc_check = (
-                lambda store, newcap: pol.check(
-                    "grow", {"newcap": newcap,
-                             "bytes": newcap * store.n_shards * 9}))
-        self._sig = sig
-        self._sig_problem = problem
-        store = self._store_obj
-        self._stats = IMMStats(
-            selection=self.selection,
-            variant=problem.variant,
-            mesh_shape=tuple(int(s) for s in store.mesh.devices.shape),
-            pool_sharding=f"{store.axis}:{store.n_shards}")
-        self._stats_dirty = False
-        # stats accumulate as device scalars; materialized once per
-        # sample_until / on `stats` access, not per round
-        self._steps_acc = jnp.zeros((), jnp.int32)
-        self._ovf_acc = jnp.zeros((), jnp.int32)
-        self._ovf_lanes = 0
-        # engines advertising full device residency let the solver hold a
-        # transfer guard over the whole hot loop; host-path engines (e.g.
-        # third-party adapters) fall back to unguarded execution
-        self._guard = ("disallow"
-                       if getattr(engine, "device_resident", False)
-                       else "allow")
-        self._sample = getattr(engine, "sample_device", engine.sample)
-        # a sharded engine on the *same* mesh hands the store rows that are
-        # already resident on their sampling device — no dev0 gather
-        if (store.n_shards > 1
-                and getattr(engine, "mesh", None) == store.mesh
-                and hasattr(engine, "sample_sharded")):
-            self._sample = engine.sample_sharded
-        return r
 
     # -- pool ownership (serving registry lifecycle) -----------------------
     def pool_bytes(self) -> int:
@@ -596,7 +608,7 @@ class IMMSolver:
 
     def _materialize_stats(self):
         if self._stats_dirty:
-            steps, ovf = (int(x) for x in jax.device_get(
+            steps, ovf = (int(x) for x in spans.fetch("stats",
                 (self._steps_acc, self._ovf_acc)))
             st = self._stats
             st.sampling_steps = steps
@@ -618,33 +630,38 @@ class IMMSolver:
         timer = pol.round_timer if pol is not None else None
         if timer is not None:
             timer.start()
-        new_key, sub = _split_key(self.key)
-        batch = (pol.run(lambda: self._sample(sub), "sample")
-                 if pol is not None else self._sample(sub))
+        with spans.span("imm.round", round=self._stats.rounds + 1):
+            with spans.span("imm.sample"):
+                new_key, sub = _split_key(self.key)
+                batch = (pol.run(lambda: self._sample(sub), "sample")
+                         if pol is not None else self._sample(sub))
 
-        def _append():
-            if self._row_weight_mode:
-                if batch.roots is None:
-                    raise ValueError(
-                        "weighted problem on an engine that neither supports "
-                        "root_weights nor reports batch roots — cannot form "
-                        "the importance-weighted estimator")
-                self.store.append_batch(
-                    batch, row_w=_gather_row_weights(self._node_w_dev,
-                                                     batch.roots))
-            else:
-                self.store.append_batch(batch)
+            def _append():
+                if self._row_weight_mode:
+                    if batch.roots is None:
+                        raise ValueError(
+                            "weighted problem on an engine that neither "
+                            "supports root_weights nor reports batch roots "
+                            "— cannot form the importance-weighted "
+                            "estimator")
+                    self.store.append_batch(
+                        batch, row_w=_gather_row_weights(self._node_w_dev,
+                                                         batch.roots))
+                else:
+                    self.store.append_batch(batch)
 
-        if pol is not None:
-            pol.run(_append, "append")
-        else:
-            _append()
-        self.key = new_key       # commit the cursor: the round is durable
-        self._steps_acc, self._ovf_acc = _accum_round_stats(
-            self._steps_acc, self._ovf_acc, batch.steps, batch.overflowed)
-        self._ovf_lanes += int(np.prod(batch.overflowed.shape))
-        self._stats.rounds += 1
-        self._stats_dirty = True
+            with spans.span("imm.append"):
+                if pol is not None:
+                    pol.run(_append, "append")
+                else:
+                    _append()
+            self.key = new_key   # commit the cursor: the round is durable
+            self._steps_acc, self._ovf_acc = _accum_round_stats(
+                self._steps_acc, self._ovf_acc, batch.steps,
+                batch.overflowed)
+            self._ovf_lanes += int(np.prod(batch.overflowed.shape))
+            self._stats.rounds += 1
+            self._stats_dirty = True
         if timer is not None:
             dt = timer.stop()
             if timer.is_straggler(dt):
@@ -655,13 +672,14 @@ class IMMSolver:
         # (explicit scalar fetch per append — gIM's Alg. 6 N_RR readback);
         # no pool data crosses to the host.  A restored solver re-enters
         # here with n_rr already at the saved watermark and simply tops up.
-        while self.store.n_rr < theta:
-            self._round()
-            if (self._ckpt_dir and self._ckpt_every > 0
-                    and self._stats.rounds - self._last_ckpt_round
-                    >= self._ckpt_every):
-                self.save_pool(self._ckpt_dir)
-                self._last_ckpt_round = self._stats.rounds
+        with spans.span("imm.sample_until", theta=int(theta)):
+            while self.store.n_rr < theta:
+                self._round()
+                if (self._ckpt_dir and self._ckpt_every > 0
+                        and self._stats.rounds - self._last_ckpt_round
+                        >= self._ckpt_every):
+                    self.save_pool(self._ckpt_dir)
+                    self._last_ckpt_round = self._stats.rounds
         self._materialize_stats()
 
     def _store(self) -> cov.RRStore:
@@ -720,7 +738,7 @@ class IMMSolver:
         empty = jax.device_put(
             np.zeros((st.n_shards, st.sketch_k // 32), np.uint32),
             st._sh_buf)
-        occ = np.asarray(jax.device_get(fns.sweep(
+        occ = np.asarray(spans.fetch("lb_bound", fns.sweep(
             st.sketch_words_mesh(), empty,
             stripe=st.sketch_rows // st.n_shards)))[:r.n_items]
         counts = sketch_mod.linear_count(occ, st.sketch_k)
@@ -767,7 +785,7 @@ class IMMSolver:
             res = cov.select_seeds_sketch(st, r.k_steps,
                                           cand=r.cand_mask_items,
                                           info_out=info)
-            seeds, gains, frac = jax.device_get(
+            seeds, gains, frac = spans.fetch("result",
                 (res.seeds, res.gains, res.frac))
             seeds, gains = np.asarray(seeds), np.asarray(gains)
             live = seeds < r.n_items
@@ -784,7 +802,7 @@ class IMMSolver:
         fns = cov._mesh_select_fns(st.mesh)
         # exact per-item row counts: the union upper bound + the
         # sketch-free fallback ranking (one mesh reduction, explicit fetch)
-        occ_exact = np.asarray(jax.device_get(fns.occur(
+        occ_exact = np.asarray(spans.fetch("occur", fns.occur(
             st._flat, st._valid, n=st.n_nodes)), np.int64)[:r.n_items]
         mask = (np.ones(r.n_items, bool) if r.cand_mask_items is None
                 else r.cand_mask_items.copy())
@@ -799,7 +817,7 @@ class IMMSolver:
                 np.zeros((st.n_shards, st.sketch_k // 32), np.uint32),
                 st._sh_buf)
             for _ in range(r.k_steps):
-                docc = np.asarray(jax.device_get(
+                docc = np.asarray(spans.fetch("sketch_gain",
                     fns.sweep(sk, cov_sk, stripe=stripe)))[:r.n_items]
                 docc = np.where(mask, docc, -1)
                 u = int(docc.argmax())
@@ -864,127 +882,147 @@ class IMMSolver:
         over the pool sampled so far instead of blowing the deadline —
         or raises :class:`~repro.ft.failures.DeadlineExceeded` when the
         objective has no certified sketch estimate (DESIGN.md §8)."""
-        r = self._prepare(problem)
-        spec = self._selection_spec(r)
-        scale = r.scale
-        p = problem
-        k_theta = p.k if p.k is not None else r.k_steps
-        deadline = (time.monotonic() + deadline_s
-                    if deadline_s is not None else None)
-        # resume: a restored pool carrying this very solve's digest picks
-        # the LB loop back up at stats.lb_completed + 1 — re-running
-        # completed iterations over the larger restored pool would shift
-        # their est/break points and fork from the uninterrupted stream
-        sig = p.signature_digest()
-        resume = (self._active_solve == sig)
-        self._active_solve = sig
+        self._solves += 1
+        with spans.solve(self._solves, self._count_fetch,
+                         variant=problem.variant):
+            r = self._prepare(problem)
+            spec = self._selection_spec(r)
+            scale = r.scale
+            p = problem
+            k_theta = p.k if p.k is not None else r.k_steps
+            deadline = (time.monotonic() + deadline_s
+                        if deadline_s is not None else None)
+            # resume: a restored pool carrying this very solve's digest
+            # picks the LB loop back up at stats.lb_completed + 1 —
+            # re-running completed iterations over the larger restored pool
+            # would shift their est/break points and fork from the
+            # uninterrupted stream
+            sig = p.signature_digest()
+            resume = (self._active_solve == sig)
+            self._active_solve = sig
 
-        def _expired() -> bool:
-            return deadline is not None and time.monotonic() >= deadline
+            def _expired() -> bool:
+                return (deadline is not None
+                        and time.monotonic() >= deadline)
 
-        self._sketch_info = None
+            self._sketch_info = None
+            pool_free = getattr(self.store, "pool_free", False)
+            method = "sketch" if pool_free else self._sel_method
 
-        def _select():
-            if getattr(self.store, "pool_free", False):
-                # approximate mode: no pool to verify against — selection
-                # runs purely on sketch estimates and leaves its error
-                # certificate in _sketch_info for the final spread_bounds
-                info = {}
-                self._sketch_info = info
-                fn = (lambda: cov.select_seeds_sketch(
-                    self.store, r.k_steps, cand=r.cand_mask_items,
-                    info_out=info))
-            else:
-                fn = (lambda: self.store.select(r.k_steps,
-                                                method=self._sel_method,
-                                                spec=spec,
-                                                eval_batch=self.eval_batch))
-            if self.fault_policy is not None:
-                # ctx identifies the request so a match-gated injector can
-                # poison one problem in a batch (serving isolation tests)
-                return self.fault_policy.run(fn, "select",
-                                             {"problem": p, "k": r.k_steps})
-            return fn()
+            def _select():
+                if pool_free:
+                    # approximate mode: no pool to verify against —
+                    # selection runs purely on sketch estimates and leaves
+                    # its error certificate in _sketch_info for the final
+                    # spread_bounds
+                    info = {}
+                    self._sketch_info = info
+                    fn = (lambda: cov.select_seeds_sketch(
+                        self.store, r.k_steps, cand=r.cand_mask_items,
+                        info_out=info))
+                else:
+                    fn = (lambda: self.store.select(
+                        r.k_steps, method=self._sel_method, spec=spec,
+                        eval_batch=self.eval_batch))
+                if self.fault_policy is not None:
+                    # ctx identifies the request so a match-gated injector
+                    # can poison one problem in a batch (serving isolation
+                    # tests)
+                    return self.fault_policy.run(
+                        fn, "select", {"problem": p, "k": r.k_steps})
+                return fn()
 
-        with jax.transfer_guard(self._guard):
-            if p.theta is not None:
-                # fixed-θ mode (benchmarks, MRIM's Table-3 experiment):
-                # sample to θ, one selection, no LB loop.  Re-entry after a
-                # restore needs no resume bookkeeping: sample_until tops up
-                # from the watermark and selection is pool-deterministic.
-                self._stats.theta = p.theta
-                self._stats.lb = 1.0
-                self.sample_until(p.theta)
-                if _expired():
-                    return self._degraded_result(r)
-                res = _select()
-            elif resume and self._stats.theta:
-                # the LB loop had already concluded when the checkpoint was
-                # written; only the final θ top-up remains
-                self.sample_until(self._stats.theta)
-                res = _select()
-            else:
-                lam_p, lam_star, eps_p, _ = imm_theta_params(
-                    self.n, k_theta, p.eps, p.ell)
-                lb = self._stats.lb if resume else 1.0
-                start_i = (self._stats.lb_completed + 1) if resume else 1
-                res = None
-                for i in range(start_i,
-                               max(int(math.log2(self.n)), 2)):  # Alg. 2
+            with jax.transfer_guard(self._guard):
+                if p.theta is not None:
+                    # fixed-θ mode (benchmarks, MRIM's Table-3 experiment):
+                    # sample to θ, one selection, no LB loop.  Re-entry
+                    # after a restore needs no resume bookkeeping:
+                    # sample_until tops up from the watermark and selection
+                    # is pool-deterministic.
+                    self._stats.theta = p.theta
+                    self._stats.lb = 1.0
+                    self.sample_until(p.theta)
                     if _expired():
                         return self._degraded_result(r)
-                    x = scale / (2.0 ** i)
-                    theta_i = int(math.ceil(lam_p / x))
-                    if p.max_theta:
-                        theta_i = min(theta_i, p.max_theta)
-                    self.sample_until(theta_i)
-                    threshold = (1.0 + eps_p) * x
-                    if self._early_exit_skip(r, threshold):
-                        self._stats.early_exit_skips += 1
+                    with spans.span("imm.select", method=method):
+                        res = _select()
+                elif resume and self._stats.theta:
+                    # the LB loop had already concluded when the checkpoint
+                    # was written; only the final θ top-up remains
+                    self.sample_until(self._stats.theta)
+                    with spans.span("imm.select", method=method):
+                        res = _select()
+                else:
+                    lam_p, lam_star, eps_p, _ = imm_theta_params(
+                        self.n, k_theta, p.eps, p.ell)
+                    lb = self._stats.lb if resume else 1.0
+                    start_i = (self._stats.lb_completed + 1) if resume else 1
+                    res = None
+                    for i in range(start_i,
+                                   max(int(math.log2(self.n)), 2)):  # Alg. 2
+                        if _expired():
+                            return self._degraded_result(r)
+                        x = scale / (2.0 ** i)
+                        theta_i = int(math.ceil(lam_p / x))
+                        if p.max_theta:
+                            theta_i = min(theta_i, p.max_theta)
+                        with spans.span("imm.lb_iter", i=i, theta=theta_i):
+                            self.sample_until(theta_i)
+                            threshold = (1.0 + eps_p) * x
+                            if self._early_exit_skip(r, threshold):
+                                self._stats.early_exit_skips += 1
+                                self._stats.history.append(
+                                    ("lb_skip", i, theta_i))
+                                self._stats.lb_completed = i
+                                continue
+                            with spans.span("imm.select", method=method):
+                                res = _select()
+                                # explicit scalar fetch: Alg. 2 L7 break is
+                                # host control
+                                est = scale * float(
+                                    spans.fetch("lb_frac", res.frac))
+                        self._stats.lb_iters = i
                         self._stats.history.append(
-                            ("lb_skip", i, theta_i))
+                            ("lb_iter", i, theta_i, est))
+                        if est >= threshold:                     # Alg. 2 L7
+                            lb = est / (1.0 + eps_p)             # Alg. 2 L8
+                            break
                         self._stats.lb_completed = i
-                        continue
-                    res = _select()
-                    # explicit scalar fetch: Alg. 2 L7 break is host control
-                    est = scale * float(jax.device_get(res.frac))
-                    self._stats.lb_iters = i
-                    self._stats.history.append(("lb_iter", i, theta_i, est))
-                    if est >= threshold:                         # Alg. 2 L7
-                        lb = est / (1.0 + eps_p)                 # Alg. 2 L8
-                        break
-                    self._stats.lb_completed = i
+                        self._stats.lb = lb
+                    theta = int(math.ceil(lam_star / lb))
+                    if p.max_theta:
+                        theta = min(theta, p.max_theta)
+                    self._stats.theta = theta
                     self._stats.lb = lb
-                theta = int(math.ceil(lam_star / lb))
-                if p.max_theta:
-                    theta = min(theta, p.max_theta)
-                self._stats.theta = theta
-                self._stats.lb = lb
-                if _expired():
-                    return self._degraded_result(r)
-                self.sample_until(theta)
-                res = _select()
-        self._active_solve = None
-        # final result materialization — the loop's only bulk transfer
-        spent_dev = getattr(res, "spent", None)
-        fetched = jax.device_get(
-            (res.seeds, res.gains, res.frac)
-            + ((spent_dev,) if spent_dev is not None else ()))
-        seeds, gains, frac = fetched[0], fetched[1], float(fetched[2])
-        spent = float(fetched[3]) if spent_dev is not None else 0.0
-        seeds = np.asarray(seeds)
-        gains = np.asarray(gains)
-        live = seeds < r.n_items          # budgeted scans pad with sentinels
-        seeds, gains = seeds[live], gains[live]
-        self._stats.frac_covered = frac
-        self._stats.variant = p.variant
-        self._stats.budget_spent = spent
-        spread = scale * frac                                    # Eq. (3)
-        bounds = (self._approx_bounds(r, self._sketch_info)
-                  if self._sketch_info else None)
-        return IMResult(seeds=seeds, spread=spread, gains=gains, frac=frac,
-                        stats=self.stats, problem=p, n_nodes=self.n,
-                        cost=spent, spread_bounds=bounds)
+                    if _expired():
+                        return self._degraded_result(r)
+                    self.sample_until(theta)
+                    with spans.span("imm.select", method=method):
+                        res = _select()
+            self._active_solve = None
+            # final result materialization — the loop's only bulk transfer
+            spent_dev = getattr(res, "spent", None)
+            fetched = spans.fetch(
+                "result", (res.seeds, res.gains, res.frac)
+                + ((spent_dev,) if spent_dev is not None else ()))
+            seeds, gains, frac = fetched[0], fetched[1], float(fetched[2])
+            spent = float(fetched[3]) if spent_dev is not None else 0.0
+            seeds = np.asarray(seeds)
+            gains = np.asarray(gains)
+            live = seeds < r.n_items      # budgeted scans pad with sentinels
+            seeds, gains = seeds[live], gains[live]
+            self._stats.frac_covered = frac
+            self._stats.variant = p.variant
+            self._stats.budget_spent = spent
+            spread = scale * frac                                # Eq. (3)
+            bounds = (self._approx_bounds(r, self._sketch_info)
+                      if self._sketch_info else None)
+            return IMResult(seeds=seeds, spread=spread, gains=gains,
+                            frac=frac, stats=self.stats, problem=p,
+                            n_nodes=self.n, cost=spent, spread_bounds=bounds)
+
+    def _count_fetch(self):
+        self._stats.host_fetches += 1
 
     def solve_stacked(self, problems: "list[IMProblem]") -> "list[IMResult]":
         """Fixed-θ micro-batch solve: one padded

@@ -202,25 +202,28 @@ def _enqueue_chunk(buf, visited, accept, nbr, tail, cap, ec, n, n_words,
 
     Returns (buf, visited, cnt, take).
     """
-    cnt = accept.sum(axis=1, dtype=jnp.int32)
-    take = jnp.minimum(cnt, jnp.maximum(cap - tail, 0))
     kacc = min(ACCEPT_CAP, ec)
-    packed = _pack_accepted(accept, nbr, n, ec, ec)
-    # buffer append is a cheap contiguous write — always full width, and
-    # crucially NOT routed through lax.cond: conditionals break XLA's
-    # in-place buffer aliasing and would copy the whole row buffer per step
-    buf = _rows_append(buf, packed, tail)
-    vis_src = jnp.where(arange_ec[None, :] < take[:, None], packed, n)
-    if kacc == ec:
-        visited = _bits_write(visited, vis_src, n, n_words)
-    else:
-        # the visited scatter cost is per update entry, so cap its width;
-        # only `visited` (small) crosses the cond boundary
-        visited = jax.lax.cond(
-            (cnt > kacc).any(),
-            lambda v: _bits_write(v, vis_src, n, n_words),
-            lambda v: _bits_write(v, vis_src[:, :kacc], n, n_words),
-            visited)
+    with jax.named_scope("rr.append"):
+        cnt = accept.sum(axis=1, dtype=jnp.int32)
+        take = jnp.minimum(cnt, jnp.maximum(cap - tail, 0))
+        packed = _pack_accepted(accept, nbr, n, ec, ec)
+        # buffer append is a cheap contiguous write — always full width,
+        # and crucially NOT routed through lax.cond: conditionals break
+        # XLA's in-place buffer aliasing and would copy the whole row
+        # buffer per step
+        buf = _rows_append(buf, packed, tail)
+    with jax.named_scope("rr.visited_write"):
+        vis_src = jnp.where(arange_ec[None, :] < take[:, None], packed, n)
+        if kacc == ec:
+            visited = _bits_write(visited, vis_src, n, n_words)
+        else:
+            # the visited scatter cost is per update entry, so cap its
+            # width; only `visited` (small) crosses the cond boundary
+            visited = jax.lax.cond(
+                (cnt > kacc).any(),
+                lambda v: _bits_write(v, vis_src, n, n_words),
+                lambda v: _bits_write(v, vis_src[:, :kacc], n, n_words),
+                visited)
     return buf, visited, cnt, take
 
 
@@ -245,39 +248,48 @@ def _sample_queue(key, offsets, indices, weights, roots, *,
     overflow = roots < 0
     arange_ec = jnp.arange(ec, dtype=jnp.int32)
 
+    # every op of a micro-step sits in one ``rr.*`` named scope: the
+    # scope lands in the op's metadata, which the device trace keeps
     def cond(st):
         _, _, qhead, qtail, _, _, _, _ = st
-        return (qhead < qtail).any()
+        with jax.named_scope("rr.advance"):
+            return (qhead < qtail).any()
 
     def body(st):
         queue, visited, qhead, qtail, ecur, overflow, key, step = st
-        active = qhead < qtail
-        u = queue[lane, jnp.clip(qhead, 0, qcap - 1)]            # current node
-        s = offsets[u]
-        deg = offsets[u + 1] - s
-        pos = ecur[:, None] + arange_ec[None, :]                 # (B, EC)
-        valid = (pos < deg[:, None]) & active[:, None]
-        eidx = jnp.clip(s[:, None] + pos, 0, m - 1)
-        nbr = indices[eidx]                                      # (B, EC)
-        pw = weights[eidx]
-        key, sub = jax.random.split(key)
-        urand = jax.random.uniform(sub, (batch, ec))
-        keep = (urand < pw) & valid                              # edge traversed
-        unseen = ~_bit_test(visited, nbr)
-        cand = keep & unseen
-        # first successful occurrence per destination within the chunk
-        accept = _first_occurrence(nbr, cand, arange_ec, mode=dedup)
+        with jax.named_scope("rr.edges"):
+            active = qhead < qtail
+            u = queue[lane, jnp.clip(qhead, 0, qcap - 1)]        # current node
+            s = offsets[u]
+            deg = offsets[u + 1] - s
+            pos = ecur[:, None] + arange_ec[None, :]             # (B, EC)
+            valid = (pos < deg[:, None]) & active[:, None]
+            eidx = jnp.clip(s[:, None] + pos, 0, m - 1)
+            nbr = indices[eidx]                                  # (B, EC)
+            pw = weights[eidx]
+        with jax.named_scope("rr.draw"):
+            key, sub = jax.random.split(key)
+            urand = jax.random.uniform(sub, (batch, ec))
+            keep = (urand < pw) & valid                          # traversed
+        with jax.named_scope("rr.visited_test"):
+            unseen = ~_bit_test(visited, nbr)
+            cand = keep & unseen
+        with jax.named_scope("rr.dedup"):
+            # first successful occurrence per destination within the chunk
+            accept = _first_occurrence(nbr, cand, arange_ec, mode=dedup)
         queue, visited, cnt, take = _enqueue_chunk(
             queue, visited, accept, nbr, qtail, qcap, ec, n, n_words,
             arange_ec)
-        overflow = overflow | (cnt > take)
-        qtail = qtail + take
-        # advance the edge cursor / pop the node (Alg. 3 L12)
-        ecur2 = ecur + ec
-        row_done = ecur2 >= deg
-        qhead = jnp.where(active & row_done, qhead + 1, qhead)
-        ecur = jnp.where(active & ~row_done, ecur2, 0)
-        return queue, visited, qhead, qtail, ecur, overflow, key, step + 1
+        with jax.named_scope("rr.advance"):
+            overflow = overflow | (cnt > take)
+            qtail = qtail + take
+            # advance the edge cursor / pop the node (Alg. 3 L12)
+            ecur2 = ecur + ec
+            row_done = ecur2 >= deg
+            qhead = jnp.where(active & row_done, qhead + 1, qhead)
+            ecur = jnp.where(active & ~row_done, ecur2, 0)
+            step = step + 1
+        return queue, visited, qhead, qtail, ecur, overflow, key, step
 
     queue, visited, qhead, qtail, ecur, overflow, key, steps = (
         jax.lax.while_loop(cond, body,
