@@ -38,8 +38,7 @@ def rank_positions(csum, width: int, size: int):
     Vectorized lower-bound binary search — log(size) gather steps, no
     scatter (XLA:CPU lowers scatter to a serial per-update loop).  Entries
     beyond the true count converge to ``size - 1``; callers mask by count.
-    Batched callers vmap over the leading axis.  Shared by the sampler
-    chunk pack (rrset) and the device store's packed append (coverage).
+    Used by the device store's packed append (coverage).
     """
     tgt = jnp.arange(1, width + 1, dtype=jnp.int32)
     lo = jnp.zeros((width,), jnp.int32)

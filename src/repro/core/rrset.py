@@ -13,11 +13,12 @@ Parallel decomposition (see DESIGN.md §2):
   lanes are reported so callers can resample at larger Qcap (0 on all
   benchmark workloads at the default Qcap).
 * Visited[n] byte array -> bit-packed (B, ceil(n/32)) uint32 (32x smaller).
-* atomic_enqueue -> in-chunk left-pack (prefix-sum rank + log-step binary
-  search gather) + one contiguous dynamic_update_slice per lane.  XLA:CPU
-  lowers scatter to a serial per-update loop, so the former (B, EC) masked
-  scatters dominated the micro-step; the packed append writes a contiguous
-  window into an EC-padded queue row instead, and the visited-bit update
+* atomic_enqueue -> in-chunk left-pack (prefix-sum rank + one fused
+  compare-reduce, no gather or scatter) + one contiguous
+  dynamic_update_slice per lane.  XLA:CPU lowers scatter to a serial
+  per-update loop and the TPU runs data-dependent gathers slowly, so the
+  pack uses neither, and the packed append writes a contiguous window
+  into an EC-padded queue row instead of scattering; the visited-bit update
   scatters only the first ACCEPT_CAP packed columns (full-width fallback
   via lax.cond when a chunk accepts more — e.g. p=1.0 stress graphs).
 * curand        -> threefry key folded per micro-step (replay-deterministic).
@@ -45,7 +46,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.graph.csr import CSRGraph
-from repro.core.packing import rank_positions
 from repro.core.roots import draw_roots, roots_from_uniform
 
 EC_DEFAULT = 128  # edge-chunk width (the paper's N_th=32, scaled to VPU lanes)
@@ -144,22 +144,23 @@ def _first_occurrence(nbr, cand, arange_ec, *, mode: str):
     return jnp.zeros_like(cand).at[rows, spos].set(accept_sorted)
 
 
-def _pack_accepted(accept, nbr, n, ec, width):
+def _pack_accepted(accept, nbr, n, width):
     """Left-pack each lane's first ``width`` accepted destinations, order
     preserved.
 
     Returns packed (B, width) int32 — sentinel ``n`` beyond each lane's
-    count.  The j-th accepted position is found by a vectorized binary
-    search over the accept prefix sum (log EC gather steps), so the pack
-    needs no scatter — XLA:CPU lowers scatter to a serial per-update loop,
-    which made the old per-chunk scatters the dominant micro-step cost.
+    count.  Slot j takes the one accepted ``nbr[i]`` whose inclusive
+    prefix count is j + 1: a compare, a select and a sum over ``i`` that
+    XLA fuses into one reduce, so the (B, width, EC) compare is never
+    built in memory and the pack needs no gather (slow on the TPU) and no
+    scatter (a serial per-update loop on XLA:CPU).
     """
     csum = jnp.cumsum(accept.astype(jnp.int32), axis=1)
     cnt = csum[:, -1]
-    pos = jax.vmap(lambda c: rank_positions(c, width, ec))(csum)
-    packed = jnp.take_along_axis(nbr, pos, axis=1)
-    tgt = jnp.arange(1, width + 1, dtype=jnp.int32)[None, :]
-    return jnp.where(tgt <= cnt[:, None], packed, n)
+    tgt = jnp.arange(1, width + 1, dtype=jnp.int32)
+    hit = accept[:, None, :] & (csum[:, None, :] == tgt[None, :, None])
+    packed = jnp.where(hit, nbr[:, None, :], 0).sum(axis=2, dtype=nbr.dtype)
+    return jnp.where(tgt[None, :] <= cnt[:, None], packed, n)
 
 
 def _bits_write(visited, packed, n, n_words):
@@ -206,7 +207,7 @@ def _enqueue_chunk(buf, visited, accept, nbr, tail, cap, ec, n, n_words,
     with jax.named_scope("rr.append"):
         cnt = accept.sum(axis=1, dtype=jnp.int32)
         take = jnp.minimum(cnt, jnp.maximum(cap - tail, 0))
-        packed = _pack_accepted(accept, nbr, n, ec, ec)
+        packed = _pack_accepted(accept, nbr, n, ec)
         # buffer append is a cheap contiguous write — always full width,
         # and crucially NOT routed through lax.cond: conditionals break
         # XLA's in-place buffer aliasing and would copy the whole row

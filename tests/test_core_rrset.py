@@ -7,7 +7,7 @@ import pytest
 
 from repro.graph import csr as csr_mod
 from repro.graph import generators, weights
-from repro.core import rrset, dense, coverage as cov
+from repro.core import rrset, dense, packing, coverage as cov
 from repro.core import oracle
 
 
@@ -164,3 +164,74 @@ def test_multi_edges_single_enqueue():
         row = np.asarray(nodes[b, :int(lengths[b])])
         assert sorted(row.tolist()) == [0, 1, 2]
     assert not bool(np.asarray(overflow).any())
+
+
+@pytest.mark.parametrize("ec", [8, 32, 128])
+@pytest.mark.parametrize("density", [0.0, 0.05, 0.5, 1.0])
+def test_pack_accepted_matches_numpy_left_pack(density, ec):
+    """The chunk pack is a left-pack in column order, sentinel ``n`` beyond
+    each lane's count; lane 0 accepts every slot and lane 1 none."""
+    n = 75_879
+    rng = np.random.default_rng(ec)
+    accept = rng.random((16, ec)) < density
+    accept[0], accept[1] = True, False
+    nbr = rng.integers(0, n, size=(16, ec)).astype(np.int32)
+    got = np.asarray(rrset._pack_accepted(jnp.asarray(accept),
+                                          jnp.asarray(nbr), n, ec))
+    rows, lens = packing.pack_rows(nbr, accept)
+    want = np.full((16, ec), n, np.int32)
+    for b in range(16):
+        want[b, :lens[b]] = rows[b, :lens[b]]
+    np.testing.assert_array_equal(got, want)
+
+
+def _binary_search_pack(accept, nbr, n, width):
+    """The former chunk pack: binary search over the accept prefix sum."""
+    csum = jnp.cumsum(accept.astype(jnp.int32), axis=1)
+    pos = jax.vmap(
+        lambda c: packing.rank_positions(c, width, accept.shape[1]))(csum)
+    packed = jnp.take_along_axis(nbr, pos, axis=1)
+    tgt = jnp.arange(1, width + 1, dtype=jnp.int32)[None, :]
+    return jnp.where(tgt <= csum[:, -1:], packed, n)
+
+
+@pytest.mark.parametrize("graph", ["wc", "p1_dense"])
+@pytest.mark.parametrize("sampler", ["queue", "refill"])
+def test_pack_keeps_the_draw_stream(sampler, graph, monkeypatch):
+    """Both samplers draw the same RR sets, in the same order and steps,
+    with the binary-search pack in place of the compare-reduce one.  The
+    p=1 graph's in-degrees exceed ACCEPT_CAP, so chunks take the
+    full-width visited write."""
+    if graph == "wc":
+        g = _wc_graph(n=120, m=600, seed=8)
+    else:
+        g = _det_graph(p=1.0, n=100, m=5000, seed=9)
+        assert np.diff(np.asarray(csr_mod.reverse(g).offsets)).max() > (
+            rrset.ACCEPT_CAP + 1)
+    g_rev = csr_mod.reverse(g)
+
+    def draw():
+        jax.clear_caches()
+        if sampler == "queue":
+            s = rrset.sample_rrsets_queue(jax.random.key(21), g_rev, 32,
+                                          qcap=g.n_nodes)
+            out = (s.nodes, s.lengths, s.steps)
+        else:
+            s = rrset.sample_rrsets_refill(jax.random.key(22), g_rev, 16,
+                                           quota=48, out_cap=8 * g.n_nodes)
+            out = (s.flat, s.lengths, s.n_done, s.steps)
+        return [np.asarray(x) for x in out]
+
+    packed_by_reduce = draw()
+    traced = []
+
+    def reference(*args):
+        traced.append(1)
+        return _binary_search_pack(*args)
+
+    monkeypatch.setattr(rrset, "_pack_accepted", reference)
+    packed_by_search = draw()
+    jax.clear_caches()
+    assert traced
+    for a, b in zip(packed_by_reduce, packed_by_search):
+        np.testing.assert_array_equal(a, b)

@@ -6,13 +6,16 @@ infer, VMEM overflows.  These tests lower every Pallas kernel on a
 user-reachable path with ``interpret=False`` at the node widths of the
 ``im_paper`` stand-ins (epinions-like n=75,879 and pokec-like
 n=1,632,803), plus the fused and bitset selection ``shard_map`` programs
-on a 1-chip and a 4-chip mesh, and compile them for ``v5e:2x2``.
+on a 1-chip and a 4-chip mesh, and compile them for ``v5e:2x2``.  The
+queue sampler's chunk pack is compiled at the benchmark's lane count to
+keep data-dependent gathers and scatters out of it.
 
 The topology is described inside a module fixture only: the TPU library
 may be loaded by one process at a time, so nothing here touches it while
 modules are imported or tests are collected.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -132,3 +135,28 @@ def test_selection_programs_compile(width, chips, topo, chip_compiles):
     c = fns.sweep.lower(sk, cov, stripe=sk.shape[1] // chips,
                         interpret=False).compile()
     assert "tpu_custom_call" in c.as_text()
+
+
+def test_sampler_pack_has_no_gather_or_scatter(one_chip, chip_compiles):
+    """The chunk left-pack at 512 lanes of 128-edge chunks: one fused
+    compare-reduce, no gather, no scatter, the (B, EC, EC) compare never
+    in memory; and no op of the sampler's ``rr.append`` scope gathers or
+    scatters."""
+    from repro.core import rrset
+    n, m = WIDTHS["epinions"], 607_012     # the benchmark's BA r=4 graph
+    b, ec = 512, rrset.EC_DEFAULT
+    c = jax.jit(lambda a, v: rrset._pack_accepted(a, v, n, ec)).lower(
+        _sds((b, ec), jnp.bool_, one_chip),
+        _sds((b, ec), jnp.int32, one_chip)).compile()
+    assert not re.search(r"\s(gather|scatter)\(", c.as_text())
+    assert c.memory_analysis().temp_size_in_bytes < b * ec * 4
+
+    hlo = rrset._queue_round.lower(
+        jax.random.key(0), _sds((n + 1,), jnp.int32, one_chip),
+        _sds((m,), jnp.int32, one_chip), _sds((m,), jnp.float32, one_chip),
+        None, batch=b, qcap=n, ec=ec, n=n, m=m, dedup="none"
+    ).compile().as_text()
+    ops = [line for line in hlo.splitlines()
+           if re.search(r"\s(gather|scatter)\(", line)]
+    assert any("rr.edges" in line for line in ops)
+    assert not any("rr.append" in line for line in ops)
