@@ -12,9 +12,10 @@ vectorized processing we carry ``rr_ids`` = the row id of every flat element
 The pool itself is *mesh-resident* (:class:`ShardedDeviceRRStore`): the flat
 buffers carry a leading shard dimension equal to the device-mesh size and
 stay sharded over the ``samples`` axis — each device keeps the rows it was
-dealt, rr_ids are **local**, and appends are per-shard jit'd rank-scatters
-into donated doubling buffers.  Every selection backend (fused scan, Pallas
-bitset, CELF-sketch) runs as a ``shard_map`` over the same sharded views:
+dealt, rr_ids are **local**, and appends are per-shard jit'd windowed
+writes, driven by the batch's row offsets, into donated doubling buffers.
+Every selection backend (fused scan, Pallas bitset, CELF-sketch) runs as
+a ``shard_map`` over the same sharded views:
 Occur is psum-reduced, argmax is replicated math, coverage updates stay
 local — per seed the only collective is one ``psum(n)`` (plus one scalar
 psum for the gain).  A single device is simply the mesh=1 special case of
@@ -38,8 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import sketch as sketch_mod
 from repro.core import spans
-from repro.core.packing import rank_positions
-from repro.ft.failures import PoolAllocError
+from repro.core.packing import PACK_WINDOW, row_window
 from repro.kernels import ops as kops
 from repro.kernels.bitset import _popcount
 
@@ -67,7 +67,7 @@ def _compact_padded(nodes, lens, base: int = 0):
     store (paper Alg. 6 lines 4-11, vectorized).
 
     Lengths are clamped to ``[0, W]`` exactly like the device append path
-    (:func:`_append_scatter_local`): an overflowed lane may report its true
+    (:func:`_append_rows_local`): an overflowed lane may report its true
     pre-truncation length while ``nodes`` only materializes ``W`` columns —
     without the clamp the element count (masked by width) and the row-id
     count (repeated by raw length) drift apart and the host mirror
@@ -171,8 +171,6 @@ class IncrementalRRStore:
 # Mesh-sharded device-resident RR pool (paper §3.5 layout × DiFuseR sharding).
 # ---------------------------------------------------------------------------
 
-_PACK = 1 << 15   # packed-append window (elements per DUS write)
-
 _EVAL_CHUNK = 8   # broadcast width of one exact-eval pass
 
 
@@ -208,85 +206,62 @@ def _shard_counts(lens, *, d, width):
                       (l > 0).sum(axis=1, dtype=jnp.int32)], axis=1)
 
 
-def _append_scatter_local(flat, ids, valid, t, n_rr, nodes, lens,
-                          ew=None, wsum=None, roww=None):
-    """Rank-scatter one padded batch into one shard's live buffers.
+def _append_rows_local(flat, ids, valid, t, n_rr, nodes, lens, *, pack, n,
+                       ew=None, wsum=None, roww=None):
+    """Append one padded batch into one shard's live buffers, driven by its
+    R row lengths: the device engines' rows are mostly padding, so work and
+    memory follow the elements drawn, never R·W.
 
-    Element ranks are a row-major prefix sum of the validity mask (rows stay
-    contiguous, matching the host compaction order exactly); rows with
-    length 0 are padding and receive no row id.  Row ids are shard-*local*.
+    The batch's elements are packed in row-major order (rows stay
+    contiguous, as in the host compaction) through the row offsets
+    (:func:`~repro.core.packing.row_window`: a search over the R row ends,
+    then one gather) into ``pack``-wide windows, each written with a
+    contiguous ``dynamic_update_slice``; a batch with more elements takes
+    consecutive windows in a loop.  Rows with length 0 are padding and
+    receive no row id; row ids are shard-*local*.  Slots past the batch's
+    element count get the virgin-buffer values (sentinel/0/False), which
+    the next append overwrites.  A window that would pass the buffer's end
+    starts earlier instead and keeps the slots before ``t`` as they are, so
+    any capacity that holds ``t`` plus the elements will do.
 
     Row-weighted stores pass ``ew``/``wsum``/``roww`` (all three or none):
     the row weight lands on every element of its row (weighted Occur is
     then one scatter-add of ``ew``) and the shard's total valid-row weight
-    accumulates into ``wsum`` (the weighted F_R denominator).  The
-    unweighted trace is unchanged by the extra parameters.
+    accumulates into ``wsum`` (the weighted F_R denominator).
     """
     cap = flat.shape[0]
-    r, w = nodes.shape
-    lens = jnp.minimum(jnp.maximum(lens.astype(jnp.int32), 0), w)
-    mask = jnp.arange(w, dtype=jnp.int32)[None, :] < lens[:, None]
-    fm = mask.reshape(-1)
-    dest = t + jnp.cumsum(fm, dtype=jnp.int32) - 1
-    dest = jnp.where(fm, dest, cap)                  # OOB -> dropped
-    flat = flat.at[dest].set(nodes.reshape(-1).astype(jnp.int32), mode="drop")
-    valid = valid.at[dest].set(True, mode="drop")
-    row_valid = lens > 0
-    rid = n_rr + jnp.cumsum(row_valid, dtype=jnp.int32) - 1
-    ids = ids.at[dest].set(
-        jnp.broadcast_to(rid[:, None], (r, w)).reshape(-1), mode="drop")
-    t_out = t + fm.sum(dtype=jnp.int32)
-    nrr_out = n_rr + row_valid.sum(dtype=jnp.int32)
-    if ew is None:
-        return flat, ids, valid, t_out, nrr_out
-    roww = roww.astype(jnp.float32)
-    ew = ew.at[dest].set(
-        jnp.broadcast_to(roww[:, None], (r, w)).reshape(-1), mode="drop")
-    wsum = wsum + jnp.where(row_valid, roww, 0.0).sum(dtype=jnp.float32)
-    return flat, ids, valid, ew, t_out, nrr_out, wsum
+    win = min(pack, cap)
+    with jax.named_scope("store.pack"):
+        lens = jnp.minimum(jnp.maximum(lens.astype(jnp.int32), 0),
+                           nodes.shape[1])
+        ends = jnp.cumsum(lens, dtype=jnp.int32)
+        total = ends[-1]
+        row_valid = lens > 0
+        rid = n_rr + jnp.cumsum(row_valid, dtype=jnp.int32) - 1
+        slot = jnp.arange(win, dtype=jnp.int32)
+        bufs = (flat, ids, valid) + (() if ew is None else (ew,))
+        virgin = (n, 0, False, 0.0)
 
+        def window(i, bufs):
+            at = jnp.minimum(t + i * win, cap - win)
+            j = at - t + slot                        # element rank per slot
+            node, row, is_elem = row_window(nodes, lens, ends, j)
+            new = (node.astype(jnp.int32), rid[row], is_elem) + (
+                () if ew is None else (roww[row].astype(jnp.float32),))
+            return tuple(
+                jax.lax.dynamic_update_slice(b, jnp.where(
+                    j < 0, jax.lax.dynamic_slice(b, (at,), (win,)),
+                    jnp.where(is_elem, x, jnp.asarray(v, b.dtype))), (at,))
+                for b, x, v in zip(bufs, new, virgin))
 
-def _append_packed_local(flat, ids, valid, t, n_rr, nodes, lens, *, pack, n,
-                         ew=None, wsum=None, roww=None):
-    """Rank-scatter append, packed variant for wide batches (one shard).
-
-    XLA:CPU lowers scatter to a serial per-update loop, so the plain
-    rank-scatter costs O(R·W) scatter updates even though only
-    ``sum(lens)`` elements are real.  Here the valid elements are gathered
-    into a ``pack``-wide window first (vectorized binary search over the
-    mask prefix sum — log(R·W) gather steps) and written with *contiguous*
-    ``dynamic_update_slice`` ops; positions past the batch's element count
-    get the virgin-buffer values (sentinel/0/False), which the next append
-    overwrites.  Host picks this path whenever R·W ≫ elements ≤ pack.
-
-    ``ew``/``wsum``/``roww`` (all three or none) are the row-weighted
-    extension — see :func:`_append_scatter_local`.
-    """
-    r, w = nodes.shape
-    lens = jnp.minimum(jnp.maximum(lens.astype(jnp.int32), 0), w)
-    mask = jnp.arange(w, dtype=jnp.int32)[None, :] < lens[:, None]
-    fm = mask.reshape(-1)
-    csum = jnp.cumsum(fm.astype(jnp.int32))
-    total = csum[-1]
-    size = r * w
-    src = rank_positions(csum, pack, size)
-    jvalid = jnp.arange(1, pack + 1, dtype=jnp.int32) <= total
-    fnodes = nodes.reshape(-1).astype(jnp.int32)[src]
-    row_valid = lens > 0
-    rid = n_rr + jnp.cumsum(row_valid.astype(jnp.int32)) - 1
-    upd_flat = jnp.where(jvalid, fnodes, n)
-    upd_ids = jnp.where(jvalid, rid[src // w], 0)
-    flat = jax.lax.dynamic_update_slice(flat, upd_flat, (t,))
-    ids = jax.lax.dynamic_update_slice(ids, upd_ids, (t,))
-    valid = jax.lax.dynamic_update_slice(valid, jvalid, (t,))
+        bufs = jax.lax.fori_loop(0, (total + win - 1) // win, window, bufs)
     t_out = t + total
     nrr_out = n_rr + row_valid.sum(dtype=jnp.int32)
     if ew is None:
-        return flat, ids, valid, t_out, nrr_out
-    roww = roww.astype(jnp.float32)
-    ew = jax.lax.dynamic_update_slice(
-        ew, jnp.where(jvalid, roww[src // w], 0.0), (t,))
-    wsum = wsum + jnp.where(row_valid, roww, 0.0).sum(dtype=jnp.float32)
+        return bufs + (t_out, nrr_out)
+    flat, ids, valid, ew = bufs
+    wsum = wsum + jnp.where(row_valid, roww.astype(jnp.float32),
+                            0.0).sum(dtype=jnp.float32)
     return flat, ids, valid, ew, t_out, nrr_out, wsum
 
 
@@ -315,52 +290,41 @@ def _mesh_store_fns(mesh: Mesh):
     ax = mesh.axis_names[0]
     buf, vec, b3 = P(ax, None), P(ax), P(ax, None, None)
 
-    def _wrap_append(local_fn):
+    def _by_shard(nodes, lens, *rest):
+        # (R, W) -> (D, R/D, W) inside the program: on one device a bitcast,
+        # where a reshape outside a jit would copy the whole batch
+        d = mesh.devices.size
+        return (nodes.reshape(d, -1, nodes.shape[1]), lens.reshape(d, -1),
+                *(x.reshape(d, -1) for x in rest))
+
+    @functools.partial(jax.jit, static_argnames=("pack", "n"),
+                       donate_argnums=(0, 1, 2, 3, 4))
+    def append_rows(flat, ids, valid, t, nrr, nodes, lens, *, pack, n):
         def local(flat, ids, valid, t, nrr, nodes, lens):
-            out = local_fn(flat[0], ids[0], valid[0], t[0], nrr[0],
-                           nodes[0], lens[0])
+            out = _append_rows_local(flat[0], ids[0], valid[0], t[0], nrr[0],
+                                     nodes[0], lens[0], pack=pack, n=n)
             return tuple(x[None] for x in out)
         return _shard_map(
             local, mesh=mesh,
             in_specs=(buf, buf, buf, vec, vec, b3, buf),
-            out_specs=(buf, buf, buf, vec, vec))
-
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
-    def append_scatter(flat, ids, valid, t, nrr, nodes, lens):
-        return _wrap_append(_append_scatter_local)(
-            flat, ids, valid, t, nrr, nodes, lens)
+            out_specs=(buf, buf, buf, vec, vec))(
+            flat, ids, valid, t, nrr, *_by_shard(nodes, lens))
 
     @functools.partial(jax.jit, static_argnames=("pack", "n"),
-                       donate_argnums=(0, 1, 2, 3, 4))
-    def append_packed(flat, ids, valid, t, nrr, nodes, lens, *, pack, n):
-        return _wrap_append(functools.partial(
-            _append_packed_local, pack=pack, n=n))(
-            flat, ids, valid, t, nrr, nodes, lens)
-
-    def _wrap_append_w(local_fn):
+                       donate_argnums=(0, 1, 2, 3, 4, 5, 6))
+    def append_rows_w(flat, ids, valid, ew, t, nrr, wsum, nodes, lens,
+                      roww, *, pack, n):
         def local(flat, ids, valid, ew, t, nrr, wsum, nodes, lens, roww):
-            out = local_fn(flat[0], ids[0], valid[0], t[0], nrr[0],
-                           nodes[0], lens[0], ew=ew[0], wsum=wsum[0],
-                           roww=roww[0])
+            out = _append_rows_local(flat[0], ids[0], valid[0], t[0], nrr[0],
+                                     nodes[0], lens[0], pack=pack, n=n,
+                                     ew=ew[0], wsum=wsum[0], roww=roww[0])
             return tuple(x[None] for x in out)
         return _shard_map(
             local, mesh=mesh,
             in_specs=(buf, buf, buf, buf, vec, vec, vec, b3, buf, buf),
-            out_specs=(buf, buf, buf, buf, vec, vec, vec))
-
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
-    def append_scatter_w(flat, ids, valid, ew, t, nrr, wsum, nodes, lens,
-                         roww):
-        return _wrap_append_w(_append_scatter_local)(
-            flat, ids, valid, ew, t, nrr, wsum, nodes, lens, roww)
-
-    @functools.partial(jax.jit, static_argnames=("pack", "n"),
-                       donate_argnums=(0, 1, 2, 3, 4, 5, 6))
-    def append_packed_w(flat, ids, valid, ew, t, nrr, wsum, nodes, lens,
-                        roww, *, pack, n):
-        return _wrap_append_w(functools.partial(
-            _append_packed_local, pack=pack, n=n))(
-            flat, ids, valid, ew, t, nrr, wsum, nodes, lens, roww)
+            out_specs=(buf, buf, buf, buf, vec, vec, vec))(
+            flat, ids, valid, ew, t, nrr, wsum,
+            *_by_shard(nodes, lens, roww))
 
     @functools.partial(jax.jit, static_argnames=("newcap",))
     def grow_ew(ew, *, newcap):
@@ -430,10 +394,8 @@ def _mesh_store_fns(mesh: Mesh):
         pass
 
     fns = Fns()
-    fns.append_scatter = append_scatter
-    fns.append_packed = append_packed
-    fns.append_scatter_w = append_scatter_w
-    fns.append_packed_w = append_packed_w
+    fns.append_rows = append_rows
+    fns.append_rows_w = append_rows_w
     fns.grow = grow
     fns.grow_ew = grow_ew
     fns.sketch_fold = sketch_fold
@@ -448,12 +410,13 @@ class ShardedDeviceRRStore:
     The flat pool (``flat``/``ids``/``valid``) carries a leading shard
     dimension equal to the mesh size and is sharded over the ``samples``
     axis: each device keeps the rows it was dealt, row ids are *local*, and
-    ``append_batch`` is one jit'd ``shard_map`` rank-scatter per shard into
-    donated doubling buffers (amortized O(1) growth, like the paper's
-    Alg. 6 pool but per device).  Batches are dealt to shards in contiguous
-    row blocks; a batch that is already sharded on the same mesh (a sharded
-    engine's ``sample_sharded``) is re-laid-out by one explicit
-    ``device_put`` with no host round-trip.
+    ``append_batch`` is one jit'd ``shard_map`` write per shard (windows
+    packed through the batch's row offsets) into donated doubling buffers
+    (amortized O(1) growth, like the paper's Alg. 6 pool but per device).
+    Batches are dealt to shards in contiguous row blocks; a batch that is
+    already sharded on the same mesh (a sharded engine's
+    ``sample_sharded``) is re-laid-out by one explicit ``device_put`` with
+    no host round-trip.
 
     The per-node coverage sketch is maintained **directly as packed uint32
     words** — (D, n_pad, k/32), a replica per shard, folded by every device
@@ -609,28 +572,13 @@ class ShardedDeviceRRStore:
         counts = np.asarray(spans.fetch("shard_counts",
             _shard_counts(lens, d=d, width=w)), np.int64)
         elems_l, rows_l = counts[:, 0], counts[:, 1]
-        # wide batches (device engine padding ≫ payload) go through the
-        # packed append: gather-pack + contiguous writes beat a serial
-        # R·W-update scatter by orders of magnitude on CPU
-        packed = rloc * w > _PACK and int(elems_l.max()) <= _PACK
-        need = int(((self._t_loc + _PACK) if packed
-                    else (self._t_loc + elems_l)).max())
-        # growth runs *before* the sketch fold so an allocation failure
+        # a row append fits any capacity that holds ``t`` plus its elements.
+        # Growth runs *before* the sketch fold so an allocation failure
         # leaves the store completely un-mutated — the whole append is then
         # safe to retry after the caller frees memory (DESIGN.md §8)
+        need = int((self._t_loc + elems_l).max())
         if need > self.capacity:
-            try:
-                self._grow_to(need)
-            except PoolAllocError:
-                # halve the growth step: the packed path reserves _PACK
-                # headroom per shard; retry at the exact scatter footprint
-                # before pushing the failure up to the fault policy
-                exact = int((self._t_loc + elems_l).max())
-                if not packed or exact >= need:
-                    raise
-                packed, need = False, exact
-                if need > self.capacity:
-                    self._grow_to(need)
+            self._grow_to(need)
         if self._sk_words is not None:
             # fold the batch into the packed coverage sketch *before* the
             # append advances the row counters: bucketing uses canonical
@@ -641,28 +589,23 @@ class ShardedDeviceRRStore:
             self._sk_words = self._fns.sketch_fold(
                 self._sk_words, nodes_rep, lens_rep, base,
                 k=self.sketch_k, mode=self.sketch_mode)
-        nodes_sh = jax.device_put(nodes.reshape(d, rloc, w), self._sh_b3)
-        lens_sh = jax.device_put(lens.reshape(d, rloc), self._sh_buf)
+        # rows dealt to shards in contiguous blocks; on one device the
+        # batch as it is (a device_put to its own sharding does not copy)
+        nodes_sh = jax.device_put(nodes, self._sh_buf)
+        lens_sh = jax.device_put(lens, self._sh_vec)
         if self.row_weighted:
-            roww_sh = jax.device_put(roww.reshape(d, rloc), self._sh_buf)
-            fn = (functools.partial(self._fns.append_packed_w, pack=_PACK,
-                                    n=self.n_nodes)
-                  if packed else self._fns.append_scatter_w)
             (self._flat, self._ids, self._valid, self._ew, self._t_dev,
-             self._nrr_dev, self._w_dev) = fn(
+             self._nrr_dev, self._w_dev) = self._fns.append_rows_w(
                 self._flat, self._ids, self._valid, self._ew, self._t_dev,
-                self._nrr_dev, self._w_dev, nodes_sh, lens_sh, roww_sh)
-        elif packed:
-            (self._flat, self._ids, self._valid, self._t_dev,
-             self._nrr_dev) = self._fns.append_packed(
-                self._flat, self._ids, self._valid, self._t_dev,
-                self._nrr_dev, nodes_sh, lens_sh,
-                pack=_PACK, n=self.n_nodes)
+                self._nrr_dev, self._w_dev, nodes_sh, lens_sh,
+                jax.device_put(roww, self._sh_vec), pack=PACK_WINDOW,
+                n=self.n_nodes)
         else:
             (self._flat, self._ids, self._valid, self._t_dev,
-             self._nrr_dev) = self._fns.append_scatter(
+             self._nrr_dev) = self._fns.append_rows(
                 self._flat, self._ids, self._valid, self._t_dev,
-                self._nrr_dev, nodes_sh, lens_sh)
+                self._nrr_dev, nodes_sh, lens_sh, pack=PACK_WINDOW,
+                n=self.n_nodes)
         self._t_loc += elems_l
         self._nrr_loc += rows_l
         if rows_l.sum():

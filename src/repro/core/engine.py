@@ -438,11 +438,12 @@ def _mrim_round(key, offsets, indices, weights, root_table, *, batch, t, qcap,
         ksample, offsets, indices, weights, tiled_roots,
         batch=batch * t, qcap=qcap, ec=ec, n=n, m=m, dedup=dedup)
     rounds = jnp.tile(jnp.arange(t, dtype=jnp.int32), batch)
-    enc = (nodes + (rounds * n)[:, None]).reshape(batch, t * qcap)
+    q = nodes.shape[1]                       # qcap plus the queue's pad tail
+    enc = (nodes + (rounds * n)[:, None]).reshape(batch, t * q)
     lane_len = lengths.reshape(batch, t)
     # valid positions: within each lane's segment, first lane_len entries
-    seg = jnp.arange(t * qcap, dtype=jnp.int32) // qcap
-    pos = jnp.arange(t * qcap, dtype=jnp.int32) % qcap
+    seg = jnp.arange(t * q, dtype=jnp.int32) // q
+    pos = jnp.arange(t * q, dtype=jnp.int32) % q
     mask = pos[None, :] < lane_len[:, seg]
     out_nodes, out_lens = pack_rows_device(enc, mask)
     overflow = overflowed.reshape(batch, t).any(axis=1)

@@ -110,6 +110,9 @@ class IMMStats:
     # explicit device-to-host fetches made inside solves (one per
     # ``imm.fetch`` span, core/spans.py)
     host_fetches: int = 0
+    # RR-set elements appended to the pool (the ``imm.append`` spans'
+    # ``elements``, summed): the bytes an append must move follow it
+    pool_elements: int = 0
     # resume watermark for the Alg. 2 LB loop: index of the last LB
     # iteration that finished *without* breaking.  A restored solve skips
     # iterations <= lb_completed instead of re-running them over the (now
@@ -650,11 +653,15 @@ class IMMSolver:
                 else:
                     self.store.append_batch(batch)
 
-            with spans.span("imm.append"):
+            with spans.span("imm.append") as sp:
+                before = self.store.n_elems
                 if pol is not None:
                     pol.run(_append, "append")
                 else:
                     _append()
+                elems = self.store.n_elems - before
+                sp.set_metadata(elements=elems)
+            self._stats.pool_elements += elems
             self.key = new_key   # commit the cursor: the round is durable
             self._steps_acc, self._ovf_acc = _accum_round_stats(
                 self._steps_acc, self._ovf_acc, batch.steps,

@@ -5,7 +5,9 @@ width; ``pack_rows_device`` is its jit-safe twin with a *static* width (the
 mask's column count), used on the device-resident engine paths where shape
 stability matters more than trailing padding.  Low-level (imports nothing
 from core) so both the samplers and the engine layer can use it without
-cycles.
+cycles.  ``row_window`` is the device pool's append and the sketch fold's
+element pack: a window of a batch's packed elements, read through the
+batch's row offsets.
 """
 from __future__ import annotations
 
@@ -31,24 +33,27 @@ def pack_rows(values: np.ndarray, mask: np.ndarray):
     return out, lens
 
 
-def rank_positions(csum, width: int, size: int):
-    """Positions of the 1st..``width``-th set elements of a flat mask, given
-    its inclusive prefix sum ``csum`` (length ``size``).
+PACK_WINDOW = 1 << 15   # elements per window of a row-offset pack
 
-    Vectorized lower-bound binary search — log(size) gather steps, no
-    scatter (XLA:CPU lowers scatter to a serial per-update loop).  Entries
-    beyond the true count converge to ``size - 1``; callers mask by count.
-    Used by the device store's packed append (coverage).
+
+def row_window(nodes, lens, ends, j):
+    """Slots ``j`` of the row-major left-pack of a padded batch, found from
+    its row offsets alone: no (R, W) mask, prefix sum or scatter.
+
+    ``nodes`` (R, W), ``lens`` (R,) clamped to ``[0, W]``, ``ends`` their
+    inclusive prefix sum (row r's elements are ranks ``ends[r] - lens[r]``
+    to ``ends[r] - 1``), ``j`` element ranks.  Slot j's row is the first
+    whose end passes j (a binary search over the R ends), and its node is
+    ``nodes[row, j - start[row]]`` (one gather).  Returns (values, row,
+    is_elem), ``is_elem`` false where j is not a rank of the batch; values
+    and rows there are in range and meaningless.  Work and memory are
+    O(len(j) · log R), whatever W is.
     """
-    tgt = jnp.arange(1, width + 1, dtype=jnp.int32)
-    lo = jnp.zeros((width,), jnp.int32)
-    hi = jnp.full((width,), size - 1, jnp.int32)
-    for _ in range(max(int(np.ceil(np.log2(max(size, 2)))), 1)):
-        mid = (lo + hi) >> 1
-        go_right = csum[mid] < tgt
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right, hi, mid)
-    return jnp.clip(lo, 0, size - 1)
+    r, w = nodes.shape
+    row = jnp.clip(jnp.searchsorted(ends, j, side="right"), 0, r - 1)
+    col = jnp.clip(j - (ends[row] - lens[row]), 0, w - 1)
+    is_elem = (j >= 0) & (j < ends[-1])
+    return nodes[row, col], row, is_elem
 
 
 def pack_rows_device(values, mask):

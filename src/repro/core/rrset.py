@@ -52,7 +52,8 @@ EC_DEFAULT = 128  # edge-chunk width (the paper's N_th=32, scaled to VPU lanes)
 
 
 class QueueSample(NamedTuple):
-    nodes: jnp.ndarray       # (B, Qcap) int32 — visit-order node ids per lane
+    nodes: jnp.ndarray       # (B, Qcap+EC) int32 — visit-order node ids per
+                             # lane; only nodes[b, :lengths[b]] is defined
     lengths: jnp.ndarray     # (B,) int32 — RR-set sizes
     roots: jnp.ndarray       # (B,) int32
     overflowed: jnp.ndarray  # (B,) bool — lane hit Qcap (RR set truncated)
@@ -296,7 +297,10 @@ def _sample_queue(key, offsets, indices, weights, roots, *,
         jax.lax.while_loop(cond, body,
                            (queue, visited, qhead, qtail, ecur, overflow, key,
                             jnp.int32(0))))
-    return queue[:, :qcap], qtail, overflow, steps
+    # the queue buffer itself, pad tail included: a ``[:, :qcap]`` slice
+    # would be a second (B, qcap) copy of the round's largest array.
+    # Consumers read nodes[b, :lengths[b]] only.
+    return queue, qtail, overflow, steps
 
 
 @functools.partial(jax.jit,

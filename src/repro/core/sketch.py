@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.packing import PACK_WINDOW, row_window
 from repro.kernels.bitset import _popcount
 
 
@@ -122,15 +123,29 @@ def fold_frontier_rows(words, nodes, lens, row_ids, *, k, mode):
     — the pool-free hot path (``mode="approximate"``), where sharded
     callers number rows over the full batch before slicing their block.
     Rows with length 0 are padding.
+
+    The batch's elements are read window by window through its row offsets
+    (:func:`~repro.core.packing.row_window`), so work and memory follow the
+    elements, not R·W.  A pair that a later window folds again finds its
+    bit already set, so the windows give the one-shot fold's words bit for
+    bit.
     """
     r, w = nodes.shape
+    if r * w == 0:
+        return words
     n_rows = words.shape[0]
+    win = min(PACK_WINDOW, r * w)
     lens = jnp.minimum(jnp.maximum(lens.astype(jnp.int32), 0), w)
-    mask = jnp.arange(w, dtype=jnp.int32)[None, :] < lens[:, None]
-    b = jnp.broadcast_to(
-        bucket_of(row_ids, k, mode)[:, None], (r, w)).reshape(-1)
-    v = jnp.where(mask, nodes.astype(jnp.int32), n_rows).reshape(-1)
-    return scatter_or_bits(words, v, b)
+    ends = jnp.cumsum(lens, dtype=jnp.int32)
+    bucket = bucket_of(row_ids, k, mode)
+    slot = jnp.arange(win, dtype=jnp.int32)
+
+    def window(i, words):
+        node, row, is_elem = row_window(nodes, lens, ends, i * win + slot)
+        v = jnp.where(is_elem, node.astype(jnp.int32), n_rows)
+        return scatter_or_bits(words, v, bucket[row])
+
+    return jax.lax.fori_loop(0, (ends[-1] + win - 1) // win, window, words)
 
 
 def flat_to_packed_bits(flat, ids, valid, *, n_rows, k, mode):

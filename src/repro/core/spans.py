@@ -11,7 +11,8 @@ Names (``chipbench/program_trace.py`` reads them): ``imm.prepare``,
 ``imm.solve``, ``imm.lb_iter``, ``imm.sample_until``, ``imm.round``,
 ``imm.sample``, ``imm.append``, ``imm.select`` and ``imm.fetch`` — the last
 around each explicit device-to-host fetch (:func:`fetch`), which also
-counts it in the open solve's ``IMMStats.host_fetches``.
+counts it in the open solve's ``IMMStats.host_fetches``.  ``imm.append``
+ends with the round's ``elements`` (summed in ``IMMStats.pool_elements``).
 """
 from __future__ import annotations
 

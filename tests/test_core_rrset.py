@@ -185,11 +185,26 @@ def test_pack_accepted_matches_numpy_left_pack(density, ec):
     np.testing.assert_array_equal(got, want)
 
 
+def _rank_positions(csum, width, size):
+    """Positions of the 1st..``width``-th set elements of a mask, from its
+    inclusive prefix sum: a lower-bound binary search (the chunk pack's
+    former search)."""
+    tgt = jnp.arange(1, width + 1, dtype=jnp.int32)
+    lo = jnp.zeros((width,), jnp.int32)
+    hi = jnp.full((width,), size - 1, jnp.int32)
+    for _ in range(max(int(np.ceil(np.log2(max(size, 2)))), 1)):
+        mid = (lo + hi) >> 1
+        go_right = csum[mid] < tgt
+        lo = jnp.where(go_right, mid + 1, lo)
+        hi = jnp.where(go_right, hi, mid)
+    return jnp.clip(lo, 0, size - 1)
+
+
 def _binary_search_pack(accept, nbr, n, width):
     """The former chunk pack: binary search over the accept prefix sum."""
     csum = jnp.cumsum(accept.astype(jnp.int32), axis=1)
     pos = jax.vmap(
-        lambda c: packing.rank_positions(c, width, accept.shape[1]))(csum)
+        lambda c: _rank_positions(c, width, accept.shape[1]))(csum)
     packed = jnp.take_along_axis(nbr, pos, axis=1)
     tgt = jnp.arange(1, width + 1, dtype=jnp.int32)[None, :]
     return jnp.where(tgt <= csum[:, -1:], packed, n)

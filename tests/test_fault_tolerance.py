@@ -9,8 +9,8 @@ Contracts under test (ISSUE acceptance criteria):
   boundaries are retried by ``FaultPolicy`` and the result stream stays
   bit-identical (transactional RNG cursor: a retried round replays the
   same subkey against unchanged buffers);
-* growth-allocation failure recovery: ``on_oom`` hooks run, the packed
-  append falls back to the exact-need allocation, and the solve completes
+* growth-allocation failure recovery: ``on_oom`` hooks run, the append
+  retries its exact-need allocation, and the solve completes
   bit-identically;
 * ε-driven LB-loop crash/resume: the checkpoint's ``lb_completed``
   watermark + ``active_solve`` digest let a restarted process skip
@@ -128,11 +128,11 @@ def test_injected_faults_retry_bit_identical(g, ref):
 
 
 def test_growth_fault_recovers_bit_identical(g):
-    """Allocation failures during capacity doubling first fall back to the
-    exact (un-padded) footprint inside the store, then escalate to the
-    policy, whose on_oom hooks run before the append retries — and the
-    solve still matches the fault-free stream.  θ is set well past the
-    store's initial element capacity so growth genuinely happens."""
+    """Allocation failures during capacity doubling (the store grows to the
+    exact footprint of the batch) escalate to the policy, whose on_oom
+    hooks run before the append retries — and the solve still matches the
+    fault-free stream.  θ is set well past the store's initial element
+    capacity so growth genuinely happens."""
     p = IMProblem(k=3, theta=8192)
     clean = IMMSolver(g, batch=256, seed=7).solve(p)
     freed = []

@@ -119,6 +119,15 @@ def test_host_fetches_counts_every_fetch_span(traced_solve):
     assert res.stats.host_fetches >= res.stats.rounds + 1
 
 
+def test_append_spans_carry_the_round_elements(traced_solve):
+    res, got = traced_solve
+    appends = [s for s in got if s[0] == "imm.append"]
+    assert len(appends) == res.stats.rounds
+    elems = [int(s[3]["elements"]) for s in appends]
+    assert all(e > 0 for e in elems)
+    assert sum(elems) == res.stats.pool_elements
+
+
 def test_host_fetches_without_a_trace():
     g = _graph(seed=1)
     problem = IMProblem(k=2, theta=256)

@@ -8,7 +8,9 @@ user-reachable path with ``interpret=False`` at the node widths of the
 n=1,632,803), plus the fused and bitset selection ``shard_map`` programs
 on a 1-chip and a 4-chip mesh, and compile them for ``v5e:2x2``.  The
 queue sampler's chunk pack is compiled at the benchmark's lane count to
-keep data-dependent gathers and scatters out of it.
+keep data-dependent gathers and scatters out of it, and one sampling
+round and the pool append of its output at pokec width, to bound their
+memory.
 
 The topology is described inside a module fixture only: the TPU library
 may be loaded by one process at a time, so nothing here touches it while
@@ -160,3 +162,65 @@ def test_sampler_pack_has_no_gather_or_scatter(one_chip, chip_compiles):
            if re.search(r"\s(gather|scatter)\(", line)]
     assert any("rr.edges" in line for line in ops)
     assert not any("rr.append" in line for line in ops)
+
+
+# one sampling round at the benchmark's lanes and EC, and the pool append
+# of its output, at pokec width: memory follows the queue rows held once
+# and the elements drawn, not a copy per (B, n) array
+ROUND_B, POKEC_M = 512, 32_655_950         # BA r=10 graph, both directions
+
+
+def test_sampling_round_holds_its_queue_once(one_chip, chip_compiles):
+    """The round returns its queue buffer, pad tail included, so its
+    temporaries hold the (B, n + EC) queue about once (three copies when
+    it returned a ``[:, :qcap]`` slice)."""
+    from repro.core import rrset
+    n, m, ec = WIDTHS["pokec"], POKEC_M, rrset.EC_DEFAULT
+    c = rrset._queue_round.lower(
+        jax.random.key(0), _sds((n + 1,), jnp.int32, one_chip),
+        _sds((m,), jnp.int32, one_chip), _sds((m,), jnp.float32, one_chip),
+        None, batch=ROUND_B, qcap=n, ec=ec, n=n, m=m, dedup="none"
+    ).compile()
+    queue_bytes = ROUND_B * (n + ec) * 4
+    assert c.memory_analysis().temp_size_in_bytes <= 1.1 * queue_bytes
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_pool_append_follows_the_elements(weighted, topo, chip_compiles):
+    """The row-offset append of a (512, n + EC) round at pokec width:
+    temporaries of at most 64 MB whatever the width, and no op that makes
+    an array of the batch's R·W slots (only the batch itself, as the
+    program's parameter, a bitcast of it, or the loop carrying it)."""
+    from repro.core import rrset
+    from repro.core.coverage import _mesh_store_fns
+    from repro.core.packing import PACK_WINDOW
+    n = WIDTHS["pokec"]
+    r, w = ROUND_B, n + rrset.EC_DEFAULT
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("samples",))
+    buf = NamedSharding(mesh, P("samples", None))
+    vec = NamedSharding(mesh, P("samples"))
+    fns = _mesh_store_fns(mesh)
+    cap = 1 << 22
+    pool = (_sds((1, cap), jnp.int32, buf), _sds((1, cap), jnp.int32, buf),
+            _sds((1, cap), jnp.bool_, buf))
+    counters = (_sds((1,), jnp.int32, vec), _sds((1,), jnp.int32, vec))
+    batch = (_sds((r, w), jnp.int32, buf), _sds((r,), jnp.int32, vec))
+    if weighted:
+        c = fns.append_rows_w.lower(
+            *pool, _sds((1, cap), jnp.float32, buf), counters[0],
+            counters[1], _sds((1,), jnp.float32, vec), *batch,
+            _sds((r,), jnp.float32, vec), pack=PACK_WINDOW, n=n).compile()
+    else:
+        c = fns.append_rows.lower(*pool, *counters, *batch,
+                                  pack=PACK_WINDOW, n=n).compile()
+    assert c.memory_analysis().temp_size_in_bytes <= 64 << 20
+    wide = []
+    for line in c.as_text().splitlines():
+        shape = re.match(r"\s*(?:ROOT\s+)?%\S+\s*=\s*\w+\[([\d,]*)\]\S*\s+"
+                         r"([\w-]+)\(", line)
+        if shape and shape.group(1):
+            size = int(np.prod([int(x) for x in shape.group(1).split(",")]))
+            if size == r * w and shape.group(2) not in (
+                    "parameter", "bitcast", "get-tuple-element", "tuple"):
+                wide.append(line.strip()[:120])
+    assert not wide, wide
