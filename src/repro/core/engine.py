@@ -275,6 +275,8 @@ class QueueEngine:
         self.config = config if config is not None else self.Config()
         self.qcap = resolve_qcap(self.config.qcap, self.g_rev)
         self._dedup = rr_queue.detect_dedup_mode(self.g_rev)
+        self._edge_rows = rr_queue.edge_row_table(
+            self.g_rev.indices, self.g_rev.weights, ec=self.config.ec)
         self.root_weights, self._root_table = _resolve_root_table(root_weights)
 
     @property
@@ -283,9 +285,9 @@ class QueueEngine:
 
     def sample(self, key) -> RRBatch:
         s = rr_queue.sample_rrsets_queue(key, self.g_rev, self.config.batch,
-                                         self.qcap, self.config.ec,
-                                         dedup=self._dedup,
-                                         root_table=self._root_table)
+                                         self.qcap, dedup=self._dedup,
+                                         root_table=self._root_table,
+                                         edge_rows=self._edge_rows)
         return RRBatch.make(s.nodes, s.lengths, s.overflowed, s.steps,
                             roots=s.roots)
 
@@ -349,6 +351,8 @@ class RefillEngine:
         self.out_cap = (cfg.out_cap if cfg.out_cap is not None
                         else min(8 * cfg.batch // self.lanes, 64) * 64)
         self._dedup = rr_queue.detect_dedup_mode(self.g_rev)
+        self._edge_rows = rr_queue.edge_row_table(
+            self.g_rev.indices, self.g_rev.weights, ec=cfg.ec)
         self.root_weights, self._root_table = _resolve_root_table(root_weights)
         if (self._root_table is not None
                 and self.g_rev.n_nodes > ONE_UNIFORM_MAX_N):
@@ -365,9 +369,9 @@ class RefillEngine:
         return rr_queue.sample_rrsets_refill(key, self.g_rev, self.lanes,
                                              quota=self.config.batch,
                                              out_cap=self.out_cap,
-                                             ec=self.config.ec,
                                              dedup=self._dedup,
-                                             root_table=self._root_table)
+                                             root_table=self._root_table,
+                                             edge_rows=self._edge_rows)
 
     def sample(self, key) -> RRBatch:
         s = self._sample_raw(key)
@@ -424,10 +428,9 @@ class LTEngine:
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("batch", "t", "qcap", "ec", "n", "m",
-                                    "dedup"))
-def _mrim_round(key, offsets, indices, weights, root_table, *, batch, t, qcap,
-                ec, n, m, dedup="sort"):
+                   static_argnames=("batch", "t", "qcap", "n", "m", "dedup"))
+def _mrim_round(key, offsets, edge_rows, root_table, *, batch, t, qcap, n, m,
+                dedup="sort"):
     """Root draw + T tagged BFS + segment merge as ONE jit (device path).
     Key-split structure matches the historical host implementation, keeping
     sample streams bit-identical."""
@@ -435,8 +438,8 @@ def _mrim_round(key, offsets, indices, weights, root_table, *, batch, t, qcap,
     roots = draw_roots(kroot, batch, n, root_table)
     tiled_roots = jnp.repeat(roots, t)                # lane b*T+r -> root b
     nodes, lengths, overflowed, steps = rr_queue._sample_queue(
-        ksample, offsets, indices, weights, tiled_roots,
-        batch=batch * t, qcap=qcap, ec=ec, n=n, m=m, dedup=dedup)
+        ksample, offsets, edge_rows, tiled_roots,
+        batch=batch * t, qcap=qcap, n=n, m=m, dedup=dedup)
     rounds = jnp.tile(jnp.arange(t, dtype=jnp.int32), batch)
     q = nodes.shape[1]                       # qcap plus the queue's pad tail
     enc = (nodes + (rounds * n)[:, None]).reshape(batch, t * q)
@@ -473,6 +476,8 @@ class MRIMEngine:
         self.config = config if config is not None else self.Config()
         self.qcap = resolve_qcap(self.config.qcap, self.g_rev)
         self._dedup = rr_queue.detect_dedup_mode(self.g_rev)
+        self._edge_rows = rr_queue.edge_row_table(
+            self.g_rev.indices, self.g_rev.weights, ec=self.config.ec)
         self.root_weights, self._root_table = _resolve_root_table(root_weights)
         if self.item_space >= np.iinfo(np.int32).max:
             raise ValueError("n_nodes * t_rounds must fit int32")
@@ -484,7 +489,7 @@ class MRIMEngine:
     def sample(self, key) -> RRBatch:
         g, cfg = self.g_rev, self.config
         out_nodes, out_lens, roots, overflow, steps = _mrim_round(
-            key, g.offsets, g.indices, g.weights, self._root_table,
-            batch=cfg.batch, t=cfg.t_rounds, qcap=self.qcap, ec=cfg.ec,
+            key, g.offsets, self._edge_rows, self._root_table,
+            batch=cfg.batch, t=cfg.t_rounds, qcap=self.qcap,
             n=g.n_nodes, m=g.n_edges, dedup=self._dedup)
         return RRBatch.make(out_nodes, out_lens, overflow, steps, roots=roots)

@@ -12,6 +12,15 @@ Parallel decomposition (see DESIGN.md §2):
   (paper Alg. 4's reservoir trigger) is counted, not spilled: `overflowed`
   lanes are reported so callers can resample at larger Qcap (0 on all
   benchmark workloads at the default Qcap).
+* edge reads -> whole CSR rows: the reverse CSR is laid out once per engine
+  as an (R, 2, EC) int32 *edge-row table* (:func:`edge_row_table`: EC
+  consecutive destinations and their weight bits per row).  A lane's chunk
+  ``start .. start+EC-1`` spans at most two rows, so a micro-step fetches
+  two rows per lane (two row gathers of 2·EC words) and aligns them by
+  ``start % EC`` in log2(EC) selects (:func:`_fetch_edges`), instead of
+  2·B·EC scalar gathers from the m-element arrays, which the TPU runs one
+  element at a time.  A vmapped ``dynamic_slice`` of the window is not
+  used: it compiles to a B-trip loop of one-lane slices.
 * Visited[n] byte array -> bit-packed (B, ceil(n/32)) uint32 (32x smaller).
 * atomic_enqueue -> in-chunk left-pack (prefix-sum rank + one fused
   compare-reduce, no gather or scatter) + one contiguous
@@ -58,6 +67,58 @@ class QueueSample(NamedTuple):
     roots: jnp.ndarray       # (B,) int32
     overflowed: jnp.ndarray  # (B,) bool — lane hit Qcap (RR set truncated)
     steps: jnp.ndarray       # () int32 — micro-steps executed
+
+
+@functools.partial(jax.jit, static_argnames=("ec",))
+def edge_row_table(indices, weights, *, ec: int = EC_DEFAULT):
+    """The CSR edge arrays as whole rows for the samplers' chunk fetch.
+
+    Returns (R, 2, ec) int32 with R = ceil(m / ec) + 2: ``[r, 0, c]`` is
+    ``indices[r*ec + c]`` and ``[r, 1, c]`` the bits of ``weights[r*ec +
+    c]``.  The tail repeats edge m-1, so every read of the table equals
+    the clipped read ``indices[clip(i, 0, m-1)]`` it replaces, and the two
+    rows a chunk starting at any ``start <= m`` spans both exist.  Built on
+    the device from the arrays already there (engines build it once).
+    """
+    m = indices.shape[0]
+    rows = -(-m // ec) + 2
+
+    def as_rows(x):
+        tail = x[m - 1] if m else jnp.zeros((), x.dtype)
+        x = jnp.concatenate([x, jnp.broadcast_to(tail, (rows * ec - m,))])
+        return x.reshape(rows, ec)
+    # stacked as rows, not transposed from (2, rows, ec): the TPU
+    # compiler takes about a minute over that transpose at m = 32.7M
+    return jnp.stack([as_rows(indices.astype(jnp.int32)),
+                      as_rows(jax.lax.bitcast_convert_type(
+                          weights.astype(jnp.float32), jnp.int32))], axis=1)
+
+
+def _fetch_edges(edge_rows, start, m):
+    """Each lane's EC edges from ``start``: (nbr (B, EC) int32, pw (B, EC)
+    float32), bit-identical to ``indices[clip(start + c, 0, m-1)]`` and
+    ``weights[...]`` for c < EC.
+
+    Two row gathers (rows ``start // EC`` and the next) give each lane a
+    (2, 2·EC) block holding its window at offset ``start % EC``; a
+    log-step shift on the offset's bits, narrowing as it goes, moves the
+    window to column 0.
+    """
+    ec = edge_rows.shape[2]
+    st = jnp.clip(start, 0, m)
+    row = st // ec
+    off = st % ec
+    x = jnp.concatenate([edge_rows[row], edge_rows[row + 1]], axis=2)
+    nbits = (ec - 1).bit_length()
+    need = ec + (1 << nbits) - 1            # widest window before the shifts
+    if need > 2 * ec:                       # only when ec is no power of 2
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, need - 2 * ec)))
+    for k in reversed(range(nbits)):
+        d, width = 1 << k, ec + (1 << k) - 1
+        take = ((off >> k) & 1).astype(bool)[:, None, None]
+        x = jnp.where(take, x[:, :, d:d + width], x[:, :, :width])
+    x = x[:, :, :ec]
+    return x[:, 0], jax.lax.bitcast_convert_type(x[:, 1], jnp.float32)
 
 
 def _bit_test(words, nodes):
@@ -230,10 +291,12 @@ def _enqueue_chunk(buf, visited, accept, nbr, tail, cap, ec, n, n_words,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("batch", "qcap", "ec", "n", "m",
-                                    "dedup"))
-def _sample_queue(key, offsets, indices, weights, roots, *,
-                  batch, qcap, ec, n, m, dedup="sort"):
+                   static_argnames=("batch", "qcap", "n", "m", "dedup"))
+def _sample_queue(key, offsets, edge_rows, roots, *,
+                  batch, qcap, n, m, dedup="sort"):
+    """The queue BFS of one round; ``edge_rows`` is the graph's
+    :func:`edge_row_table`, whose row width is the chunk width EC."""
+    ec = edge_rows.shape[2]
     n_words = (n + 31) // 32
     lane = jnp.arange(batch, dtype=jnp.int32)
     # EC-wide pad tail absorbs the contiguous-append slice windows
@@ -266,9 +329,7 @@ def _sample_queue(key, offsets, indices, weights, roots, *,
             deg = offsets[u + 1] - s
             pos = ecur[:, None] + arange_ec[None, :]             # (B, EC)
             valid = (pos < deg[:, None]) & active[:, None]
-            eidx = jnp.clip(s[:, None] + pos, 0, m - 1)
-            nbr = indices[eidx]                                  # (B, EC)
-            pw = weights[eidx]
+            nbr, pw = _fetch_edges(edge_rows, s + ecur, m)       # (B, EC)
         with jax.named_scope("rr.draw"):
             key, sub = jax.random.split(key)
             urand = jax.random.uniform(sub, (batch, ec))
@@ -304,10 +365,9 @@ def _sample_queue(key, offsets, indices, weights, roots, *,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("batch", "qcap", "ec", "n", "m",
-                                    "dedup"))
-def _queue_round(key, offsets, indices, weights, root_table, *, batch, qcap,
-                 ec, n, m, dedup="sort"):
+                   static_argnames=("batch", "qcap", "n", "m", "dedup"))
+def _queue_round(key, offsets, edge_rows, root_table, *, batch, qcap, n, m,
+                 dedup="sort"):
     """Root draw + queue BFS as ONE jit: every operand is a device array, so
     a round triggers no host↔device traffic (runs under
     ``jax.transfer_guard("disallow")``).  The key-split structure matches the
@@ -316,27 +376,31 @@ def _queue_round(key, offsets, indices, weights, root_table, *, batch, qcap,
     key, sub = jax.random.split(key)
     roots = draw_roots(sub, batch, n, root_table)
     nodes, lengths, overflowed, steps = _sample_queue(
-        key, offsets, indices, weights, roots,
-        batch=batch, qcap=qcap, ec=ec, n=n, m=m, dedup=dedup)
+        key, offsets, edge_rows, roots,
+        batch=batch, qcap=qcap, n=n, m=m, dedup=dedup)
     return nodes, lengths, roots, overflowed, steps
 
 
 def sample_rrsets_queue(key, g_rev: CSRGraph, batch: int, qcap: int,
                         ec: int = EC_DEFAULT,
                         dedup: str | None = None,
-                        root_table=None) -> QueueSample:
+                        root_table=None, edge_rows=None) -> QueueSample:
     """Sample ``batch`` RR sets (one round) on the reverse CSR.
 
     ``dedup=None`` runs :func:`detect_dedup_mode` on the host once per call
     (engines cache the detection at construction).  ``root_table`` (an
     :class:`~repro.core.roots.AliasTable`) switches the root draw to
-    weight-proportional sampling (weighted IM)."""
+    weight-proportional sampling (weighted IM).  ``edge_rows`` is
+    ``g_rev``'s :func:`edge_row_table` (engines build it once); ``None``
+    builds it here at chunk width ``ec``."""
     n, m = g_rev.n_nodes, g_rev.n_edges
     if dedup is None:
         dedup = detect_dedup_mode(g_rev)
+    if edge_rows is None:
+        edge_rows = edge_row_table(g_rev.indices, g_rev.weights, ec=ec)
     nodes, lengths, roots, overflowed, steps = _queue_round(
-        key, g_rev.offsets, g_rev.indices, g_rev.weights, root_table,
-        batch=batch, qcap=qcap, ec=ec, n=n, m=m, dedup=dedup)
+        key, g_rev.offsets, edge_rows, root_table,
+        batch=batch, qcap=qcap, n=n, m=m, dedup=dedup)
     return QueueSample(nodes=nodes, lengths=lengths, roots=roots,
                        overflowed=overflowed, steps=steps)
 
@@ -368,11 +432,11 @@ class RefillSample(NamedTuple):
 
 @functools.partial(jax.jit,
                    static_argnames=("batch", "out_cap", "quota",
-                                    "max_sets_per_lane", "ec", "n", "m",
-                                    "dedup"))
-def _sample_refill(key, offsets, indices, weights, roots0, root_table, *,
-                   batch, out_cap, quota, max_sets_per_lane, ec, n, m,
+                                    "max_sets_per_lane", "n", "m", "dedup"))
+def _sample_refill(key, offsets, edge_rows, roots0, root_table, *,
+                   batch, out_cap, quota, max_sets_per_lane, n, m,
                    dedup="sort"):
+    ec = edge_rows.shape[2]
     n_words = (n + 31) // 32
     lane = jnp.arange(batch, dtype=jnp.int32)
     arange_ec = jnp.arange(ec, dtype=jnp.int32)
@@ -407,9 +471,7 @@ def _sample_refill(key, offsets, indices, weights, roots0, root_table, *,
         deg = offsets[u + 1] - s
         pos = ecur[:, None] + arange_ec[None, :]
         valid = (pos < deg[:, None]) & active[:, None]
-        eidx = jnp.clip(s[:, None] + pos, 0, m - 1)
-        nbr = indices[eidx]
-        pw = weights[eidx]
+        nbr, pw = _fetch_edges(edge_rows, s + ecur, m)
         # ONE uniform draw per micro-step: EC edge trials + 1 refill-root
         # column per lane (a second split+randint per step costs a whole
         # extra threefry dispatch)
@@ -476,17 +538,16 @@ def _sample_refill(key, offsets, indices, weights, roots0, root_table, *,
 
 @functools.partial(jax.jit,
                    static_argnames=("batch", "out_cap", "quota",
-                                    "max_sets_per_lane", "ec", "n", "m",
-                                    "dedup"))
-def _refill_round(key, offsets, indices, weights, root_table, *, batch,
-                  out_cap, quota, max_sets_per_lane, ec, n, m, dedup="sort"):
+                                    "max_sets_per_lane", "n", "m", "dedup"))
+def _refill_round(key, offsets, edge_rows, root_table, *, batch,
+                  out_cap, quota, max_sets_per_lane, n, m, dedup="sort"):
     """Root draw + persistent-lane worker as ONE jit (see ``_queue_round``)."""
     key, sub = jax.random.split(key)
     roots = draw_roots(sub, batch, n, root_table)
     return _sample_refill(
-        key, offsets, indices, weights, roots, root_table,
+        key, offsets, edge_rows, roots, root_table,
         batch=batch, out_cap=out_cap, quota=quota,
-        max_sets_per_lane=max_sets_per_lane, ec=ec, n=n, m=m, dedup=dedup)
+        max_sets_per_lane=max_sets_per_lane, n=n, m=m, dedup=dedup)
 
 
 def sample_rrsets_refill(key, g_rev: CSRGraph, batch: int,
@@ -494,19 +555,22 @@ def sample_rrsets_refill(key, g_rev: CSRGraph, batch: int,
                          max_sets_per_lane: int | None = None,
                          ec: int = EC_DEFAULT,
                          dedup: str | None = None,
-                         root_table=None) -> RefillSample:
+                         root_table=None, edge_rows=None) -> RefillSample:
     """Persistent-lane sampling with a global quota: lanes refill with new
     roots until >= ``quota`` RR sets are complete across all lanes (the
-    paper's Alg. 6 worker loop); in-flight sets always finish (unbiased)."""
+    paper's Alg. 6 worker loop); in-flight sets always finish (unbiased).
+    ``edge_rows`` as in :func:`sample_rrsets_queue`."""
     n, m = g_rev.n_nodes, g_rev.n_edges
     if max_sets_per_lane is None:
         max_sets_per_lane = max(4 * quota // batch + 4, 4)
     if dedup is None:
         dedup = detect_dedup_mode(g_rev)
+    if edge_rows is None:
+        edge_rows = edge_row_table(g_rev.indices, g_rev.weights, ec=ec)
     flat, lengths, n_done, overflow, steps = _refill_round(
-        key, g_rev.offsets, g_rev.indices, g_rev.weights, root_table,
+        key, g_rev.offsets, edge_rows, root_table,
         batch=batch, out_cap=out_cap, quota=quota,
-        max_sets_per_lane=max_sets_per_lane, ec=ec, n=n, m=m, dedup=dedup)
+        max_sets_per_lane=max_sets_per_lane, n=n, m=m, dedup=dedup)
     return RefillSample(flat=flat, lengths=lengths, n_done=n_done,
                         overflowed=overflow, steps=steps)
 

@@ -82,7 +82,7 @@ class ShardedQueueEngine:
         table = (None if self._table is None else type(self._table)(
             *(jax.device_put(x, rep0) for x in self._table)))
 
-        def local(offsets, indices, w, keydata):
+        def local(offsets, edge_rows, keydata):
             # full 128-bit key state travels as raw uint32 data (typed keys
             # don't cross shard_map on older jax); fold_in(dev) gives each
             # device its own collision-free stream, like gIM's per-block
@@ -94,8 +94,8 @@ class ShardedQueueEngine:
             # weighted IM draws ∝ node_weights through the alias table
             roots = draw_roots(sub, bpd, n, table)
             nodes, lengths, overflow, steps = rrset._sample_queue(
-                key, offsets, indices, w, roots,
-                batch=bpd, qcap=qcap, ec=ec, n=n, m=m, dedup=dedup)
+                key, offsets, edge_rows, roots,
+                batch=bpd, qcap=qcap, n=n, m=m, dedup=dedup)
             return nodes[None], lengths[None], overflow[None], steps[None]
 
         # jit the shard_map so rounds hit a compiled executable (no
@@ -103,9 +103,9 @@ class ShardedQueueEngine:
         # the per-round call does no *implicit* cross-device transfer (the
         # IMM driver holds transfer_guard("disallow") over the hot loop)
         rep = NamedSharding(mesh, P())
+        edge_rows = rrset.edge_row_table(g_rev.indices, g_rev.weights, ec=ec)
         self._replicated = tuple(
-            jax.device_put(x, rep)
-            for x in (g_rev.offsets, g_rev.indices, g_rev.weights))
+            jax.device_put(x, rep) for x in (g_rev.offsets, edge_rows))
         self._rep_sharding = rep
         # on-device all-gather to a replicated copy: a device_put of a
         # sharded array onto one device would gather through the host,
@@ -113,7 +113,7 @@ class ShardedQueueEngine:
         self._replicate = jax.jit(lambda *xs: xs, out_shardings=rep)
         return jax.jit(jax.shard_map(
             local, mesh=mesh, check_vma=False,
-            in_specs=(P(), P(), P(), P()),
+            in_specs=(P(), P(), P()),
             out_specs=(P(axis), P(axis), P(axis), P(axis))))
 
     def _sample_raw(self, key):

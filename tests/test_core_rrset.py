@@ -157,9 +157,9 @@ def test_multi_edges_single_enqueue():
     g_rev = csr_mod.reverse(g)
     # root=2 in reverse graph reaches 0 and 1 through parallel edges
     nodes, lengths, overflow, _ = rrset._sample_queue(
-        jax.random.key(0), g_rev.offsets, g_rev.indices, g_rev.weights,
-        jnp.asarray([2, 2, 2, 2], jnp.int32), batch=4, qcap=3, ec=8,
-        n=3, m=6)
+        jax.random.key(0), g_rev.offsets,
+        rrset.edge_row_table(g_rev.indices, g_rev.weights, ec=8),
+        jnp.asarray([2, 2, 2, 2], jnp.int32), batch=4, qcap=3, n=3, m=6)
     for b in range(4):
         row = np.asarray(nodes[b, :int(lengths[b])])
         assert sorted(row.tolist()) == [0, 1, 2]
@@ -249,4 +249,95 @@ def test_pack_keeps_the_draw_stream(sampler, graph, monkeypatch):
     jax.clear_caches()
     assert traced
     for a, b in zip(packed_by_reduce, packed_by_search):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("m,ec", [(1, 128), (127, 128), (128, 128),
+                                  (129, 128), (5_000, 128), (1_000, 100),
+                                  (37, 8), (50, 1)])
+def test_edge_row_fetch_matches_clipped_element_reads(m, ec):
+    """The row fetch returns ``indices[clip(start + c, 0, m - 1)]`` and the
+    weights alike, bit for bit, for windows starting at 0, at m - 1, at m
+    (a degree-0 node after the last edge), on and across row boundaries,
+    and anywhere else."""
+    rng = np.random.default_rng(m + ec)
+    idx = rng.integers(0, 1 << 30, m).astype(np.int32)
+    w = rng.random(m).astype(np.float32)
+    w[::7] = np.float32(1.0)
+    table = rrset.edge_row_table(jnp.asarray(idx), jnp.asarray(w), ec=ec)
+    assert table.shape == (-(-m // ec) + 2, 2, ec)
+    starts = np.r_[0, m - 1, m, ec - 1, ec, ec + 1, 2 * ec - 1,
+                   rng.integers(0, m + 1, 64)]
+    starts = np.clip(starts, 0, m).astype(np.int32)
+    nbr, pw = rrset._fetch_edges(table, jnp.asarray(starts), m)
+    eidx = np.clip(starts[:, None] + np.arange(ec), 0, m - 1)
+    np.testing.assert_array_equal(np.asarray(nbr), idx[eidx])
+    np.testing.assert_array_equal(np.asarray(pw).view(np.int32),
+                                  w[eidx].view(np.int32))
+
+
+def _multi_edge_graph(n=160, seed=3):
+    """Reverse graph with parallel edges, a hub whose row spans many
+    chunks, and degree-0 nodes (the last 10 have no in-edges)."""
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, n, 700)
+    dst = rng.integers(0, n - 10, 700)
+    hub = np.r_[rng.integers(0, n, 90), np.repeat(src[:5], 3)]
+    src, dst = np.r_[src, hub], np.r_[dst, np.zeros(len(hub), np.int64)]
+    return csr_mod.from_edges(src, dst, n,
+                              weights=rng.random(len(src)).astype(np.float32)
+                              * 0.6)
+
+
+@pytest.mark.parametrize("sampler,dedup", [("queue", "none"),
+                                           ("queue", "segmented"),
+                                           ("queue", "sort"),
+                                           ("refill", "segmented")])
+def test_row_fetch_keeps_the_draw_stream(sampler, dedup, monkeypatch):
+    """Both samplers draw the same RR sets, lengths and steps with the
+    former per-element fetch (``indices[eidx]``, ``weights[eidx]``) in
+    place of the row fetch, at a chunk width whose windows cross row
+    boundaries on most steps."""
+    g = _multi_edge_graph()
+    g_rev = csr_mod.reverse(g)                       # rows dst-sorted
+    if dedup == "none":
+        g_rev = csr_mod.coalesce_ic(g_rev)
+    elif dedup == "sort":
+        src, dst, w = csr_mod.to_edges(g_rev)
+        order = np.random.default_rng(0).permutation(len(src))
+        g_rev = csr_mod.from_edges(src[order], dst[order], g.n_nodes,
+                                   weights=w[order])
+    assert rrset.detect_dedup_mode(g_rev) == dedup
+    ec = 8
+    assert np.diff(np.asarray(g_rev.offsets)).max() > 4 * ec
+    indices, weights_ = g_rev.indices, g_rev.weights
+
+    def draw():
+        jax.clear_caches()
+        if sampler == "queue":
+            s = rrset.sample_rrsets_queue(jax.random.key(31), g_rev, 32,
+                                          qcap=g.n_nodes, ec=ec, dedup=dedup)
+            out = (s.nodes, s.lengths, s.steps)
+        else:
+            s = rrset.sample_rrsets_refill(jax.random.key(32), g_rev, 16,
+                                           quota=48, out_cap=8 * g.n_nodes,
+                                           ec=ec, dedup=dedup)
+            out = (s.flat, s.lengths, s.n_done, s.steps)
+        return [np.asarray(x) for x in out]
+
+    by_rows = draw()
+    traced = []
+
+    def element_fetch(edge_rows, start, m):
+        traced.append(1)
+        eidx = jnp.clip(start[:, None] + jnp.arange(edge_rows.shape[2]),
+                        0, m - 1)
+        return indices[eidx], weights_[eidx]
+
+    monkeypatch.setattr(rrset, "_fetch_edges", element_fetch)
+    by_elements = draw()
+    jax.clear_caches()
+    assert traced
+    assert by_rows[-1] > 0
+    for a, b in zip(by_rows, by_elements):
         np.testing.assert_array_equal(a, b)

@@ -147,9 +147,10 @@ def test_fetch_outside_a_solve_returns_the_value():
 
 def _sample_queue_hlo(g_rev, dedup):
     return rrset._sample_queue.lower(
-        jax.random.key(0), g_rev.offsets, g_rev.indices, g_rev.weights,
+        jax.random.key(0), g_rev.offsets,
+        rrset.edge_row_table(g_rev.indices, g_rev.weights, ec=32),
         jax.numpy.arange(16, dtype=jax.numpy.int32),
-        batch=16, qcap=g_rev.n_nodes + 1, ec=32, n=g_rev.n_nodes,
+        batch=16, qcap=g_rev.n_nodes + 1, n=g_rev.n_nodes,
         m=g_rev.n_edges, dedup=dedup).compile().as_text()
 
 

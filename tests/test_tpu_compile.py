@@ -10,7 +10,7 @@ on a 1-chip and a 4-chip mesh, and compile them for ``v5e:2x2``.  The
 queue sampler's chunk pack is compiled at the benchmark's lane count to
 keep data-dependent gathers and scatters out of it, and one sampling
 round and the pool append of its output at pokec width, to bound their
-memory.
+memory and to keep the round's edge fetch to whole-row gathers.
 
 The topology is described inside a module fixture only: the TPU library
 may be loaded by one process at a time, so nothing here touches it while
@@ -139,6 +139,11 @@ def test_selection_programs_compile(width, chips, topo, chip_compiles):
     assert "tpu_custom_call" in c.as_text()
 
 
+def _edge_rows_sds(m, ec, sharding):
+    """The shape of ``rrset.edge_row_table`` for m edges."""
+    return _sds((-(-m // ec) + 2, 2, ec), jnp.int32, sharding)
+
+
 def test_sampler_pack_has_no_gather_or_scatter(one_chip, chip_compiles):
     """The chunk left-pack at 512 lanes of 128-edge chunks: one fused
     compare-reduce, no gather, no scatter, the (B, EC, EC) compare never
@@ -155,9 +160,8 @@ def test_sampler_pack_has_no_gather_or_scatter(one_chip, chip_compiles):
 
     hlo = rrset._queue_round.lower(
         jax.random.key(0), _sds((n + 1,), jnp.int32, one_chip),
-        _sds((m,), jnp.int32, one_chip), _sds((m,), jnp.float32, one_chip),
-        None, batch=b, qcap=n, ec=ec, n=n, m=m, dedup="none"
-    ).compile().as_text()
+        _edge_rows_sds(m, ec, one_chip), None, batch=b, qcap=n, n=n, m=m,
+        dedup="none").compile().as_text()
     ops = [line for line in hlo.splitlines()
            if re.search(r"\s(gather|scatter)\(", line)]
     assert any("rr.edges" in line for line in ops)
@@ -170,19 +174,68 @@ def test_sampler_pack_has_no_gather_or_scatter(one_chip, chip_compiles):
 ROUND_B, POKEC_M = 512, 32_655_950         # BA r=10 graph, both directions
 
 
-def test_sampling_round_holds_its_queue_once(one_chip, chip_compiles):
+@pytest.fixture(scope="module")
+def pokec_round(one_chip, chip_compiles):
+    """``_queue_round`` compiled at pokec width, the benchmark's lanes and
+    EC (one compile for the tests below)."""
+    from repro.core import rrset
+    n, m, ec = WIDTHS["pokec"], POKEC_M, rrset.EC_DEFAULT
+    return rrset._queue_round.lower(
+        jax.random.key(0), _sds((n + 1,), jnp.int32, one_chip),
+        _edge_rows_sds(m, ec, one_chip), None, batch=ROUND_B, qcap=n, n=n,
+        m=m, dedup="none").compile()
+
+
+def test_sampling_round_holds_its_queue_once(pokec_round):
     """The round returns its queue buffer, pad tail included, so its
     temporaries hold the (B, n + EC) queue about once (three copies when
     it returned a ``[:, :qcap]`` slice)."""
     from repro.core import rrset
-    n, m, ec = WIDTHS["pokec"], POKEC_M, rrset.EC_DEFAULT
-    c = rrset._queue_round.lower(
-        jax.random.key(0), _sds((n + 1,), jnp.int32, one_chip),
-        _sds((m,), jnp.int32, one_chip), _sds((m,), jnp.float32, one_chip),
-        None, batch=ROUND_B, qcap=n, ec=ec, n=n, m=m, dedup="none"
-    ).compile()
-    queue_bytes = ROUND_B * (n + ec) * 4
-    assert c.memory_analysis().temp_size_in_bytes <= 1.1 * queue_bytes
+    queue_bytes = ROUND_B * (WIDTHS["pokec"] + rrset.EC_DEFAULT) * 4
+    assert pokec_round.memory_analysis().temp_size_in_bytes <= (
+        1.1 * queue_bytes)
+
+
+def test_edge_fetch_gathers_whole_rows(pokec_round):
+    """The step's ``rr.edges`` scope reads the edges as rows of the edge-row
+    table: every gather from an operand the size of the edge arrays (m) or
+    of the table moves a slice of at least EC elements (no per-element
+    gather from the edge arrays), and the scope runs no loop (a vmapped
+    ``dynamic_slice`` of the window would be a loop over the lanes)."""
+    from repro.core import rrset
+    ec = rrset.EC_DEFAULT
+    edge_sizes = {POKEC_M, (-(-POKEC_M // ec) + 2) * 2 * ec}
+    hlo = pokec_round.as_text()
+    size = {}
+    for line in hlo.splitlines():
+        d = re.match(r"\s*(?:ROOT\s+)?%(\S+)\s*=\s*\w+\[([\d,]*)\]", line)
+        if d:
+            size[d.group(1)] = int(np.prod(
+                [int(x) for x in d.group(2).split(",") if x]))
+    edges = [line for line in hlo.splitlines() if "rr.edges" in line]
+    assert not [x for x in edges
+                if re.search(r"\s(while|dynamic-slice)\(", x)]
+    row_gathers = 0
+    for line in edges:
+        g = re.search(r"\sgather\(%(\S+?),.*slice_sizes=\{([\d,]+)\}",
+                      line)
+        if g and size[g.group(1)] in edge_sizes:
+            moved = int(np.prod([int(x) for x in g.group(2).split(",")]))
+            assert moved >= ec, line.strip()[:160]
+            row_gathers += 1
+    assert row_gathers >= 1
+
+
+def test_edge_row_table_builds_without_a_loop(one_chip, chip_compiles):
+    """The engines' edge-row table at pokec's m compiles to straight-line
+    ops; laid out through a (2, R, EC) -> (R, 2, EC) transpose it compiled
+    to a loop of dynamic slices, and took about a minute to compile."""
+    from repro.core import rrset
+    hlo = rrset.edge_row_table.lower(
+        _sds((POKEC_M,), jnp.int32, one_chip),
+        _sds((POKEC_M,), jnp.float32, one_chip),
+        ec=rrset.EC_DEFAULT).compile().as_text()
+    assert not re.search(r"\s(while|dynamic-slice)\(", hlo)
 
 
 @pytest.mark.parametrize("weighted", [False, True])
