@@ -40,27 +40,21 @@ from repro.core.imm import imm
 
 N, R, QUOTA, B = 20000, 8, 2048, 512
 PIPELINE_ENGINES = ("queue", "refill", "dense", "lt")
-SELECTION_PATHS = ("fused", "bitset", "celf-sketch")
 
 
-def bench_selection(n=2000, r=4, k=10, pool_rows=2048, batch=256,
-                    sketch_k=512, reps=3, seed=0,
-                    eval_batches=(8, 32, 128)):
-    """Time the three selection backends on one shared RR pool.
+def bench_selection(n=2000, r=4, k=10, pool_rows=2048, batch=256, reps=3,
+                    seed=0):
+    """Time the selection scan on one sampled RR pool.
 
-    The pool is sampled once (queue engine) into a ``DeviceRRStore`` with an
-    incremental coverage sketch; each path then selects the same k seeds.
-    First call per path is reported separately as compile+run; steady-state
-    is the min over ``reps`` repeats.  The celf-sketch path additionally
-    sweeps the exact-verification batch width (``IMMSolver(eval_batch=)`` /
-    ``--eval-batch``): wider batches amortize sweep launches against wasted
-    speculative exact evals, and the sweep records where that trade lands
-    on this pool.  Writes BENCH_selection.json.
+    The pool is sampled once (queue engine) into a ``DeviceRRStore``; the
+    fused scan then selects k seeds.  The first call is reported
+    separately as compile+run; steady-state is the min over ``reps``
+    repeats.  Writes BENCH_selection.json.
     """
     g = ba_graph(n, r)
     g_rev = csr_mod.reverse(g)
     eng = make_engine("queue", g_rev, batch=batch)
-    store = cov.DeviceRRStore(n, sketch_k=sketch_k)
+    store = cov.DeviceRRStore(n)
     i = 0
     while store.n_rr < pool_rows:
         store.append_batch(eng.sample(jax.random.key(seed * 100003 + i)))
@@ -68,68 +62,26 @@ def bench_selection(n=2000, r=4, k=10, pool_rows=2048, batch=256,
     out = {"graph": {"kind": "barabasi_albert", "n": n, "r": r,
                      "weights": "wc"},
            "pool": {"rows": store.n_rr, "elements": store.n_elems,
-                    "sketch_k": store.sketch_k, "batch": batch},
-           "params": {"k": k, "reps": reps, "seed": seed},
-           "paths": {}}
-    seeds_by_path = {}
-    for path in SELECTION_PATHS:
-        method = {"fused": "flat", "bitset": "bitset",
-                  "celf-sketch": "celf"}[path]
+                    "batch": batch},
+           "params": {"k": k, "reps": reps, "seed": seed}}
+    t0 = time.perf_counter()
+    res = store.select(k)
+    jax.block_until_ready(res.seeds)
+    first = time.perf_counter() - t0
+    best = float("inf")
+    for _ in range(reps):
         t0 = time.perf_counter()
-        if method == "celf":
-            stats = {}
-            res = cov.select_seeds_celf(store, k, stats_out=stats)
-        else:
-            res = store.select(k, method=method)
+        res = store.select(k)
         jax.block_until_ready(res.seeds)
-        first = time.perf_counter() - t0
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            if method == "celf":
-                res = cov.select_seeds_celf(store, k)
-            else:
-                res = store.select(k, method=method)
-            jax.block_until_ready(res.seeds)
-            best = min(best, time.perf_counter() - t0)
-        seeds = np.asarray(res.seeds).tolist()
-        seeds_by_path[path] = seeds
-        out["paths"][path] = {
-            "first_call_s": round(first, 4),
-            "steady_s": round(best, 4),
-            "seeds": seeds,
-            "frac": round(float(res.frac), 6),
-        }
-        if method == "celf":
-            out["paths"][path]["exact_evals"] = stats["n_exact_evals"]
-            out["paths"][path]["eval_calls"] = stats["n_eval_calls"]
-            sweep = {}
-            for eb in eval_batches:
-                st = {}
-                res_eb = cov.select_seeds_celf(store, k, eval_batch=eb,
-                                               stats_out=st)
-                jax.block_until_ready(res_eb.seeds)   # compile pass
-                best_eb = float("inf")
-                for _ in range(reps):
-                    t0 = time.perf_counter()
-                    res_eb = cov.select_seeds_celf(store, k, eval_batch=eb)
-                    jax.block_until_ready(res_eb.seeds)
-                    best_eb = min(best_eb, time.perf_counter() - t0)
-                assert (np.asarray(res_eb.seeds).tolist()
-                        == seeds), "eval_batch must not change seeds"
-                sweep[str(eb)] = {
-                    "steady_s": round(best_eb, 4),
-                    "exact_evals": st["n_exact_evals"],
-                    "eval_calls": st["n_eval_calls"],
-                }
-                report(f"perf_im/selection/celf-eb{eb}", best_eb * 1e6,
-                       f"steady={best_eb * 1e3:.1f}ms;"
-                       f"evals={st['n_exact_evals']}")
-            out["paths"][path]["eval_batch_sweep"] = sweep
-        report(f"perf_im/selection/{path}", best * 1e6,
-               f"steady={best * 1e3:.1f}ms;first={first:.2f}s")
-    out["seeds_identical"] = all(
-        s == seeds_by_path[SELECTION_PATHS[0]] for s in seeds_by_path.values())
+        best = min(best, time.perf_counter() - t0)
+    out["fused"] = {
+        "first_call_s": round(first, 4),
+        "steady_s": round(best, 4),
+        "seeds": np.asarray(res.seeds).tolist(),
+        "frac": round(float(res.frac), 6),
+    }
+    report("perf_im/selection/fused", best * 1e6,
+           f"steady={best * 1e3:.1f}ms;first={first:.2f}s")
     os.makedirs(OUT_DIR, exist_ok=True)
     path = os.path.join(OUT_DIR, "BENCH_selection.json")
     with open(path, "w") as f:
@@ -139,12 +91,10 @@ def bench_selection(n=2000, r=4, k=10, pool_rows=2048, batch=256,
 
 def bench_sharded(n=100_000, rows=1 << 20, k=10, sketch_k=1024,
                   batch_rows=8192, mean_len=8, mesh_spec=None, seed=0):
-    """Selection at the post-bitset-matrix scale on the mesh-sharded pool.
+    """Selection at scale on the mesh-sharded pool.
 
-    Builds a synthetic RR pool past the point where the packed bitset
-    matrix no longer fits (default n=1e5, θ=2^20 ≈ 1e6: the matrix would be
-    ``row_capacity · ceil(n/32) · 4`` ≈ 13 GB), then times the fused scan
-    and CELF-sketch selection on the sharded store.  Synthetic sets (random
+    Builds a synthetic RR pool (default n=1e5, θ=2^20 ≈ 1e6 rows), then
+    times the fused scan on the sharded store.  Synthetic sets (random
     base + stride, row-unique by construction) keep pool-building O(rows)
     — selection cost does not depend on how the sets were sampled.
 
@@ -176,8 +126,6 @@ def bench_sharded(n=100_000, rows=1 << 20, k=10, sketch_k=1024,
     assert not any(
         a.dtype == bool and a.ndim >= 2 and store.sketch_k in a.shape[1:]
         for a in jax.live_arrays()), "live (..., k) bool occupancy found"
-    n_words = (n + 31) // 32
-    bitset_bytes = store.row_capacity() * n_words * 4 * store.n_shards
     out = {"graph": {"kind": "synthetic", "n": n, "mean_len": mean_len},
            "mesh": {"devices": store.n_shards,
                     "pool_sharding": f"{store.axis}:{store.n_shards}",
@@ -188,23 +136,15 @@ def bench_sharded(n=100_000, rows=1 << 20, k=10, sketch_k=1024,
                "packed_bytes": packed_bytes, "bool_bytes": bool_bytes,
                "ratio": bool_bytes / max(packed_bytes, 1),
                "sketch_k": store.sketch_k},
-           "bitset_matrix_bytes": bitset_bytes,
-           "bitset_skipped": bitset_bytes > (1 << 31),
-           "params": {"k": k, "seed": seed}, "paths": {}}
-    seeds_by = {}
-    for path, fn in (("fused", lambda: store.select(k, method="flat")),
-                     ("celf-sketch",
-                      lambda: cov.select_seeds_celf(store, k))):
-        t0 = time.perf_counter()
-        res = fn()
-        jax.block_until_ready(res.seeds)
-        dt = time.perf_counter() - t0
-        seeds_by[path] = np.asarray(res.seeds).tolist()
-        out["paths"][path] = {"wall_s": round(dt, 3),
-                              "seeds": seeds_by[path],
-                              "frac": round(float(res.frac), 6)}
-        report(f"perf_im/sharded/{path}", dt * 1e6, f"wall={dt:.2f}s")
-    out["seeds_identical"] = seeds_by["fused"] == seeds_by["celf-sketch"]
+           "params": {"k": k, "seed": seed}}
+    t0 = time.perf_counter()
+    res = store.select(k)
+    jax.block_until_ready(res.seeds)
+    dt = time.perf_counter() - t0
+    out["fused"] = {"wall_s": round(dt, 3),
+                    "seeds": np.asarray(res.seeds).tolist(),
+                    "frac": round(float(res.frac), 6)}
+    report("perf_im/sharded/fused", dt * 1e6, f"wall={dt:.2f}s")
     os.makedirs(OUT_DIR, exist_ok=True)
     with open(os.path.join(OUT_DIR, "BENCH_sharded.json"), "w") as f:
         json.dump(out, f, indent=2)
@@ -222,11 +162,11 @@ def bench_fused(n=100_000, rows=1 << 20, k=10, sketch_k=1024,
 
     * **scale** — identical synthetic frontier batches drive a pool-free
       :class:`SketchRRStore` and an exact :class:`ShardedDeviceRRStore`
-      side by side at the post-bitset-matrix scale (default n=1e5,
-      θ=2^20).  The sketch leg runs *first* and a live-array scan then
-      proves the flat pool was never allocated: no int32/bool device
-      array at pool scale exists anywhere.  The memory ratio (exact
-      per-device pool bytes / per-replica sketch bytes) is **asserted**
+      side by side at scale (default n=1e5, θ=2^20).  The sketch leg runs
+      *first* and a live-array scan then proves the flat pool was never
+      allocated: no int32/bool device array at pool scale exists
+      anywhere.  The memory ratio (exact per-device pool bytes /
+      per-replica sketch bytes) is **asserted**
       ≥ ``min_mem_ratio``; cold (compile included) and steady build+select
       wall-clock ratios are both recorded.
     * **quality** — end-to-end ``mode="approximate"`` solve on a real WC
@@ -292,11 +232,11 @@ def bench_fused(n=100_000, rows=1 << 20, k=10, sketch_k=1024,
     ex_build = feed(ex_store)
     assert ex_store.n_rr == sk_store.n_rr == rows
     t0 = time.perf_counter()
-    res_ex = ex_store.select(k, method="flat")
+    res_ex = ex_store.select(k)
     jax.block_until_ready(res_ex.seeds)
     ex_sel_cold = time.perf_counter() - t0
     t0 = time.perf_counter()
-    res_ex = ex_store.select(k, method="flat")
+    res_ex = ex_store.select(k)
     jax.block_until_ready(res_ex.seeds)
     ex_sel = time.perf_counter() - t0
 
@@ -344,8 +284,7 @@ def bench_fused(n=100_000, rows=1 << 20, k=10, sketch_k=1024,
 
     # ---- quality: certified interval vs the real (re-materialised) pool --
     gq = ba_graph(quality_n, quality_r)
-    se = IMMSolver(gq, engine="queue", batch=quality_batch, seed=seed + 1,
-                   selection="fused")
+    se = IMMSolver(gq, engine="queue", batch=quality_batch, seed=seed + 1)
     t0 = time.perf_counter()
     r_ex = se.solve(IMProblem(k=quality_k, theta=quality_theta))
     q_ex_wall = time.perf_counter() - t0
@@ -398,8 +337,7 @@ def bench_fused(n=100_000, rows=1 << 20, k=10, sketch_k=1024,
 
     # ---- exact-regime identity: θ ≤ sketch_k ⇒ bit-identical seeds -------
     th0 = min(quality_sketch_k // 2, 1024)
-    e1 = IMMSolver(gq, engine="queue", batch=quality_batch, seed=seed + 2,
-                   selection="fused")
+    e1 = IMMSolver(gq, engine="queue", batch=quality_batch, seed=seed + 2)
     r1 = e1.solve(IMProblem(k=quality_k, theta=th0))
     e2 = IMMSolver(gq, engine="queue", batch=quality_batch, seed=seed + 2,
                    sketch_k=quality_sketch_k)
@@ -760,10 +698,11 @@ if __name__ == "__main__":
     ap.add_argument("--pipeline-only", action="store_true",
                     help="skip the micro-step section (CI smoke)")
     ap.add_argument("--selection-only", action="store_true",
-                    help="run only the selection-backend comparison")
+                    help="time only the selection scan "
+                         "(writes BENCH_selection.json)")
     ap.add_argument("--sharded", action="store_true",
-                    help="mesh-sharded selection sweep past the bitset-"
-                         "matrix limit (writes BENCH_sharded.json)")
+                    help="selection on a mesh-sharded synthetic pool "
+                         "(writes BENCH_sharded.json)")
     ap.add_argument("--fused-sketch", action="store_true",
                     help="pool-free fused sample→sketch vs exact pipeline: "
                          "memory ratio (asserted ≥10×), wall-clock, and "
@@ -791,7 +730,7 @@ if __name__ == "__main__":
     pkw = dict(k=args.k, eps=args.eps, max_theta=args.max_theta,
                batch=args.batch, engines=tuple(args.engines.split(",")))
     skw = dict(n=args.n, r=args.r, k=args.k, pool_rows=args.pool_rows,
-               batch=args.batch, sketch_k=args.sketch_k)
+               batch=args.batch)
     if args.streaming:
         bench_streaming(n=args.n, r=args.r, k=args.k, theta=args.theta,
                         batch=args.batch, rounds=args.stream_rounds)

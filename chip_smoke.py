@@ -10,7 +10,7 @@ with n=75,879, r=4, weighted-cascade probabilities, made from a seed) and
 checks every answer.  The graph is uncut; the number of RR sets is cut.
 On one v5e the queue sampler draws about 207 RR sets per second on this
 graph (2.5 s per 512-set round of ~244 lockstep steps at ~10 ms each), and
-a solver that keeps a sketch (phase b, approximate mode) about 113.  The
+approximate mode, which folds every batch into a sketch, about 113.  The
 paper default's θ (about 2.2e6 here) would take about 3 h, so the ε-driven
 solve is capped at ``MAX_THETA`` and the pinned solves use ``THETA``, which
 keeps a run with a cold compile cache inside the 1200 s it may take:
@@ -21,10 +21,10 @@ a. ``IMMSolver(g, engine="queue", batch=512)`` solves the paper default
    within 10%.
 b. A θ-pinned solve must equal ``core/oracle.greedy_max_coverage`` on the
    pool fetched to the host: seeds, per-seed gains, covered rows.
-c. On one θ-pinned pool the ``fused``, ``bitset`` and ``celf-sketch``
-   selections must agree bit for bit.  ``mode="approximate"`` with one
-   sketch bucket per RR set (the exact regime) must return the same seeds
-   and gains, and certify their true coverage on that pool.
+c. A solver that adopts phase b's pool must select the same answer
+   from it, bit for bit, without sampling.  ``mode="approximate"`` with
+   one sketch bucket per RR set (the exact regime) must return the same
+   seeds and gains, and certify their true coverage on that pool.
 d. ``IMNetServer`` over ``build_service`` answers mixed θ-pinned requests
    over HTTP in this process's event loop, with no error status and every
    answer bit-identical to a direct solve.
@@ -63,7 +63,6 @@ BATCH = 512
 MAX_THETA = 65_536                          # cuts: sampling time on the
 THETA = 16_384                              # chip (see the docstring)
 MESH_MAX_THETA, MESH_THETA = 16_384, 4_096  # --chips 4
-SKETCH_K = 1024                             # the celf-sketch store's sketch
 MC_SIMS = 64
 MC_TOL = 0.10
 
@@ -153,7 +152,6 @@ def same_answer(a, b) -> bool:
 
 def phase_a(ctx, clock):
     """Main path at paper scale: ε-driven IMM with the LB loop."""
-    from repro.core import coverage
     from repro.core.oracle import imm_theta_params
     from repro.core.problem import IMProblem
     g = ctx["g"]
@@ -171,8 +169,7 @@ def phase_a(ctx, clock):
            "sampling_steps": st.sampling_steps,
            "overflow_fraction": st.overflow_fraction,
            "lb_iters": st.lb_iters, "lb": st.lb,
-           "pool_bytes_per_device": st.per_device_pool_bytes,
-           "selection": coverage.auto_method(s.store), **times}
+           "pool_bytes_per_device": st.per_device_pool_bytes, **times}
     mark = clock.mark()
     out.update(mc_check(g, res.seeds, res.spread))
     out["mc"] = clock.since(mark)
@@ -183,13 +180,11 @@ def phase_b(ctx, clock):
     """θ-pinned solve vs the numpy greedy on the pool fetched to the host."""
     import jax
     import numpy as np
-    from repro.core import coverage, oracle
+    from repro.core import oracle
     from repro.core.problem import IMProblem
     g = ctx["g"]
     mark = clock.mark()
-    # the pool keeps an incremental sketch, so phase c's celf-sketch
-    # solver can adopt it too
-    s = solver(g, sketch_k=SKETCH_K)
+    s = solver(g)
     res = s.solve(IMProblem(k=K, theta=ctx["theta"]))
     times = clock.since(mark)
     snap = s.store.snapshot()
@@ -221,33 +216,28 @@ def phase_b(ctx, clock):
     ctx["b_result"] = res
     return {"theta": ctx["theta"], "n_rr": n_rr, "elements": int(flat.size),
             "pool_bytes_per_device": res.stats.per_device_pool_bytes,
-            "selection": coverage.auto_method(s.store),
             "covered_rows": n_cov, "frac": res.frac, "oracle_s": ref_s,
             **times}
 
 
 def phase_c(ctx, clock):
-    """Every selection backend and the pool-free approximate mode."""
+    """The fused scan on an adopted pool, and the pool-free approximate
+    mode."""
     import numpy as np
     from repro.core.problem import IMProblem
     g, theta = ctx["g"], ctx["theta"]
-    problem = IMProblem(k=K, theta=theta)
-    lease = ctx.pop("b_solver").export_pool()
     base = ctx["b_result"]
-    out = {}
-    for sel in ("fused", "bitset", "celf-sketch"):
-        s = solver(g, selection=sel, sketch_k=SKETCH_K)
-        s.adopt_pool(lease)
-        mark = clock.mark()
-        res = s.solve(problem)
-        out[sel] = clock.since(mark)
-        check(res.stats.n_rr_sampled == theta, f"{sel}: pool resampled")
-        check(same_answer(res, base),
-              f"{sel} differs: seeds {res.seeds.tolist()} gains "
-              f"{res.gains.tolist()} frac {res.frac!r} vs "
-              f"{base.seeds.tolist()} {base.gains.tolist()} {base.frac!r}")
-        lease = s.export_pool()
-    ctx["lease"] = lease
+    s = solver(g)
+    s.adopt_pool(ctx.pop("b_solver").export_pool())
+    mark = clock.mark()
+    res = s.solve(IMProblem(k=K, theta=theta))
+    out = {"fused": clock.since(mark)}
+    check(res.stats.n_rr_sampled == theta, "the adopted pool was resampled")
+    check(same_answer(res, base),
+          f"the adopted pool's answer differs: seeds {res.seeds.tolist()} "
+          f"gains {res.gains.tolist()} frac {res.frac!r} vs "
+          f"{base.seeds.tolist()} {base.gains.tolist()} {base.frac!r}")
+    ctx["lease"] = s.export_pool()
 
     # the pool-free fold with one sketch bucket per RR set (sketch_k ≥ θ):
     # the exact regime, where the approximate solve must pick the exact
@@ -305,7 +295,7 @@ def phase_d(ctx, clock):
         build_service
     g = ctx["g"]
     distinct, first, second = serve_problems(g, ctx["theta"])
-    ref = solver(g, sketch_k=SKETCH_K)
+    ref = solver(g)
     ref.adopt_pool(ctx.pop("lease"))
     direct = {p.signature_digest(): ref.solve(p) for p in distinct}
 
@@ -445,7 +435,7 @@ def main() -> int:
           **clock.since(mark)})
     phases = ([("mesh4", phase_mesh4)] if args.chips == 4 else
               [("a_main_path", phase_a), ("b_exact_vs_oracle", phase_b),
-               ("c_backends", phase_c), ("d_serving", phase_d)])
+               ("c_selection", phase_c), ("d_serving", phase_d)])
     failed = []
     for name, fn in phases:
         try:

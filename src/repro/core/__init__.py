@@ -8,7 +8,7 @@ from repro.core.coverage import (RRStore, IncrementalRRStore, DeviceRRStore,
                                  ShardedDeviceRRStore, SelectionSpec,
                                  build_store, merge_stores, occur_histogram,
                                  select_seeds, select_seeds_device,
-                                 select_seeds_celf, select_variant)
+                                 select_variant)
 from repro.core.rrset import sample_rrsets_queue, to_lists
 from repro.core.dense import (sample_rrsets_dense, membership_to_lists,
                               membership_to_padded)
@@ -24,7 +24,7 @@ __all__ = [
     "RRStore", "IncrementalRRStore", "DeviceRRStore",
     "ShardedDeviceRRStore", "SelectionSpec", "build_store",
     "merge_stores", "occur_histogram", "select_seeds", "select_seeds_device",
-    "select_seeds_celf", "select_variant",
+    "select_variant",
     "sample_rrsets_queue", "to_lists",
     "sample_rrsets_dense", "membership_to_lists", "membership_to_padded",
     "sample_rrsets_lt", "ic_spread", "lt_spread", "solve_mrim",

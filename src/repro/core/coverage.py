@@ -14,8 +14,8 @@ buffers carry a leading shard dimension equal to the device-mesh size and
 stay sharded over the ``samples`` axis — each device keeps the rows it was
 dealt, rr_ids are **local**, and appends are per-shard jit'd windowed
 writes, driven by the batch's row offsets, into donated doubling buffers.
-Every selection backend (fused scan, Pallas bitset, CELF-sketch) runs as
-a ``shard_map`` over the same sharded views:
+The selection scan — one exact greedy for plain and variant problems
+alike — runs as a ``shard_map`` over the same sharded views:
 Occur is psum-reduced, argmax is replicated math, coverage updates stay
 local — per seed the only collective is one ``psum(n)`` (plus one scalar
 psum for the gain).  A single device is simply the mesh=1 special case of
@@ -24,8 +24,9 @@ the same code path; there is no separate single-device implementation.
 The per-node coverage sketch is maintained **as packed uint32 words**
 (``core/sketch.py``), replicated across the mesh: every device folds the
 identical full batch into its replica (cheaper than any cross-device OR of
-sketch deltas — see DESIGN.md §5), and the CELF sweep scores a disjoint
-stripe of candidates per device, combined by one psum.
+sketch deltas — see DESIGN.md §5), and the sketch sweep (approximate
+mode, the θ early exit, degraded results) scores a disjoint stripe of
+candidates per device, combined by one psum.
 """
 from __future__ import annotations
 
@@ -171,9 +172,6 @@ class IncrementalRRStore:
 # Mesh-sharded device-resident RR pool (paper §3.5 layout × DiFuseR sharding).
 # ---------------------------------------------------------------------------
 
-_EVAL_CHUNK = 8   # broadcast width of one exact-eval pass
-
-
 def _default_mesh() -> Mesh:
     """The mesh=1 special case: a single-device mesh over the default
     device.  Single-device execution is *not* a separate code path — it is
@@ -265,21 +263,6 @@ def _append_rows_local(flat, ids, valid, t, n_rr, nodes, lens, *, pack, n,
     return flat, ids, valid, ew, t_out, nrr_out, wsum
 
 
-def _bitset_from_flat_local(flat, ids, valid, *, num_rows, n_words):
-    """Pack one shard's flat pool into a (num_rows, n_words) bit matrix.
-
-    Elements are row-unique (RRBatch contract), so within one (row, word)
-    cell every scattered bit is distinct and scatter-add == scatter-or.
-    """
-    w = jnp.where(valid, flat >> 5, n_words)         # sentinel -> dropped
-    bit = jnp.where(
-        valid,
-        jnp.left_shift(jnp.uint32(1), (flat & 31).astype(jnp.uint32)),
-        jnp.uint32(0))
-    return jnp.zeros((num_rows, n_words), jnp.uint32).at[
-        jnp.clip(ids, 0, num_rows - 1), w].add(bit, mode="drop")
-
-
 @functools.lru_cache(maxsize=None)
 def _mesh_store_fns(mesh: Mesh):
     """Per-mesh jitted shard_map programs for the pool (append/grow/sketch).
@@ -361,16 +344,6 @@ def _mesh_store_fns(mesh: Mesh):
             local, mesh=mesh, in_specs=(b3, P(), P(), P()),
             out_specs=b3)(sk, nodes, lens, base)
 
-    @functools.partial(jax.jit, static_argnames=("num_rows", "n_words"))
-    def bitset_build(flat, ids, valid, *, num_rows, n_words):
-        def local(flat, ids, valid):
-            return _bitset_from_flat_local(
-                flat[0], ids[0], valid[0],
-                num_rows=num_rows, n_words=n_words)[None]
-        return _shard_map(
-            local, mesh=mesh, in_specs=(buf, buf, buf),
-            out_specs=b3)(flat, ids, valid)
-
     @functools.partial(jax.jit, static_argnames=("n_rows", "k", "mode"))
     def sketch_from_pool(flat, ids, valid, *, n_rows, k, mode):
         # on-demand sketch for stores built without an incremental one:
@@ -399,7 +372,6 @@ def _mesh_store_fns(mesh: Mesh):
     fns.grow = grow
     fns.grow_ew = grow_ew
     fns.sketch_fold = sketch_fold
-    fns.bitset_build = bitset_build
     fns.sketch_from_pool = sketch_from_pool
     return fns
 
@@ -470,12 +442,11 @@ class ShardedDeviceRRStore:
         self._w_dev = (jax.device_put(np.zeros(d, np.float32), self._sh_vec)
                        if row_weighted else None)
         self._cache: RRStore | None = None
-        self._bitset = None              # (D, num_rows, n_words) cache
         self.sketch_mode = sketch_mode
         self.sketch_k = (sketch_mod.resolve_sketch_k(sketch_k)
                          if sketch_k is not None else None)
-        # sketch rows padded to a multiple of the shard count so the CELF
-        # sweep can stripe candidates evenly across devices
+        # sketch rows padded to a multiple of the shard count so the
+        # sketch sweep can stripe candidates evenly across devices
         self.sketch_rows = -(-(n_nodes + 1) // d) * d
         self._sk_words = (jax.device_put(
             np.zeros((d, self.sketch_rows, self.sketch_k // 32), np.uint32),
@@ -612,7 +583,6 @@ class ShardedDeviceRRStore:
             self._round_rows.append(rows_l.copy())
             self._round_elems.append(elems_l.copy())
         self._cache = None
-        self._bitset = None
         self._sk_cache = None
 
     def _grow_to(self, need: int) -> None:
@@ -788,7 +758,6 @@ class ShardedDeviceRRStore:
                 np.broadcast_to(words[None], (d,) + words.shape).copy(),
                 self._sh_b3)
         self._cache = None
-        self._bitset = None
         self._sk_cache = None
         return {"rows_dropped": old_rows - self.n_rr,
                 "rows_kept": self.n_rr,
@@ -925,21 +894,6 @@ class ShardedDeviceRRStore:
         whole words).  Selection recompiles only when this doubles."""
         return max(32, _ceil_pow2(max(int(self._nrr_loc.max()), 1)))
 
-    def bitset_matrix(self):
-        """(D, row_capacity, n_words) packed membership matrix, one block
-        per shard (cached).  ``n_words`` is ceil(n/32) rounded up to whole
-        128-lane tiles, so the matrix keeps a row-major device layout and
-        reaches the Occur kernel without a relayout copy; the extra words
-        are zero."""
-        num_rows = self.row_capacity()
-        n_words = bitset_words(self.n_nodes)
-        if self._bitset is None or \
-                self._bitset.shape[1:] != (num_rows, n_words):
-            self._bitset = self._fns.bitset_build(
-                self._flat, self._ids, self._valid,
-                num_rows=num_rows, n_words=n_words)
-        return self._bitset
-
     def sketch_words_mesh(self, k: int | None = None):
         """(D, sketch_rows, k/32) packed per-node coverage sketch — one
         replica per shard.
@@ -971,17 +925,11 @@ class ShardedDeviceRRStore:
         identical on any mesh size)."""
         return _slice_extent(self.sketch_words_mesh(k), t=self.n_nodes + 1)
 
-    def select(self, k: int, method: str = "auto",
-               spec: "SelectionSpec | None" = None,
-               eval_batch: int | None = None) -> "CoverageResult":
-        if method in ("celf", "celf-sketch"):
-            if eval_batch is not None:
-                return select_seeds_celf(self, k, spec=spec,
-                                         eval_batch=eval_batch)
-            return select_seeds_celf(self, k, spec=spec)
+    def select(self, k: int, spec: "SelectionSpec | None" = None
+               ) -> "CoverageResult":
         if spec is not None:
-            return select_variant(self, spec, method=method)
-        return select_seeds_device(self, k, method=method)
+            return select_variant(self, spec)
+        return select_seeds_device(self, k)
 
 
 # the historical single-device pool IS the mesh=1 case — same class, same
@@ -1179,9 +1127,8 @@ class SketchRRStore:
     def sketch_words(self, k: int | None = None):
         return _slice_extent(self.sketch_words_mesh(k), t=self.n_nodes + 1)
 
-    def select(self, k: int, method: str = "auto",
-               spec: "SelectionSpec | None" = None,
-               eval_batch: int | None = None) -> "CoverageResult":
+    def select(self, k: int, spec: "SelectionSpec | None" = None
+               ) -> "CoverageResult":
         if spec is not None:
             raise ValueError("pool-free sketch store supports plain (or "
                              "candidate-masked) selection only; weighted/"
@@ -1252,7 +1199,7 @@ def select_seeds(store: RRStore, k: int) -> CoverageResult:
 
 
 # ---------------------------------------------------------------------------
-# Mesh-sharded selection backends (fused scan / Pallas bitset / CELF).
+# Mesh-sharded selection: the fused Alg. 7 scan and its variant twin.
 # ---------------------------------------------------------------------------
 
 def _unpack_covered(cov_words):
@@ -1272,10 +1219,10 @@ def _pack_covered(rows):
 def _newly_rows(flat, ids, valid, covered, u):
     """Rows containing ``u`` that are not yet covered — THE membership pass.
 
-    Single shared body for the fused scan step, the CELF exact-eval batch
-    (vmapped over candidates) and the CELF commit; every caller runs it
-    per shard on local rows, so the celf==fused parity contract hangs on
-    every path computing newly-covered rows identically.
+    Single shared body for the fused scan step, its variant twin and the
+    stacked scan; every caller runs it per shard on local rows, so the
+    stacked==solo parity contract hangs on every path computing
+    newly-covered rows identically.
     """
     match = (flat == u) & valid
     row_has = jax.ops.segment_max(match.astype(jnp.int32), ids,
@@ -1287,7 +1234,7 @@ def _newly_rows(flat, ids, valid, covered, u):
 def _mesh_select_fns(mesh: Mesh):
     """Per-mesh jitted shard_map selection programs.
 
-    Every backend reads the same sharded pool views: Occur partials are
+    Every program reads the same sharded pool views: Occur partials are
     psum-reduced, argmax is replicated math, Covered stays shard-local, and
     per seed the only collectives are one ``psum(n)`` (decrement) and one
     scalar psum (gain) — exactly the protocol of DESIGN.md §5.  Replicated
@@ -1308,9 +1255,8 @@ def _mesh_select_fns(mesh: Mesh):
         needs their psum), and Covered lives as a packed per-shard
         ``(num_rows/32,)`` uint32 bitset — per-seed gains are popcount
         arithmetic on the newly-covered words.  The Occur decrement stays a
-        masked scatter over the local flat elements: on a sparse pool that
-        is O(elements/D) per device, strictly less work than any dense
-        per-node pass (the bit-matrix variant is :func:`bitset`).
+        masked scatter over the local flat elements: O(elements/D) per
+        device, whatever the pool's density.
         """
         def local(flat, ids, valid, nrr):
             flat, ids, valid = flat[0], ids[0], valid[0]
@@ -1343,45 +1289,10 @@ def _mesh_select_fns(mesh: Mesh):
             local, mesh=mesh, in_specs=(buf, buf, buf, vec),
             out_specs=(P(), P(), P()))(flat, ids, valid, nrr)
 
-    @functools.partial(jax.jit, static_argnames=("k",))
-    def bitset(m_words, nrr, *, k):
-        """Alg. 7 on the per-shard packed membership matrices, via the
-        Pallas bitset kernels (each shard runs the kernels on its local
-        block; Occur and its decrement are psum-reduced).  Work per seed is
-        O(num_rows · n/32) per device regardless of sparsity, so this path
-        wins when RR sets are dense (mean size ≳ n/32)."""
-        from repro.kernels import ops as kops
-
-        def local(m, nrr):
-            m = m[0]
-            occur0 = jax.lax.psum(kops.occur_from_bitset(m), ax)
-            nrr_tot = jax.lax.psum(nrr[0], ax)
-
-            def step(carry, _):
-                occur, covered = carry
-                u = jnp.argmax(occur).astype(jnp.int32)
-                col = m[:, u >> 5]
-                hit = ((col >> (u & 31).astype(jnp.uint32))
-                       & jnp.uint32(1)) != 0
-                newly = hit & ~covered
-                dec = jax.lax.psum(
-                    kops.occur_from_bitset_masked(m, newly), ax)
-                gain = jax.lax.psum(newly.sum(dtype=jnp.int32), ax)
-                return (occur - dec, covered | hit), (u, gain)
-
-            covered0 = jnp.zeros(m.shape[0], bool)
-            _, (seeds, gains) = jax.lax.scan(
-                step, (occur0, covered0), None, length=k)
-            frac = gains.sum(dtype=jnp.int32) / jnp.maximum(nrr_tot, 1)
-            return seeds, gains, frac.astype(jnp.float32)
-
-        return _shard_map(
-            local, mesh=mesh, in_specs=(b3, vec),
-            out_specs=(P(), P(), P()))(m_words, nrr)
-
     @functools.partial(jax.jit, static_argnames=("n",))
     def occur(flat, valid, *, n):
-        """Exact psum-reduced Occur histogram (CELF's upper-bound init)."""
+        """Exact psum-reduced Occur histogram (the degraded result's
+        union upper bound)."""
         def local(flat, valid):
             h = jnp.zeros(n + 1, jnp.int32).at[flat[0]].add(
                 valid[0].astype(jnp.int32), mode="drop")[:n]
@@ -1390,58 +1301,13 @@ def _mesh_select_fns(mesh: Mesh):
             local, mesh=mesh, in_specs=(buf, buf),
             out_specs=P())(flat, valid)
 
-    @jax.jit
-    def eval_batch(flat, ids, valid, cov_words, cands):
-        """Exact marginal coverage of C candidates vs the covered bitset.
-
-        The membership pass is broadcast over ``_EVAL_CHUNK`` candidates at
-        a time under ``lax.map`` per shard, so peak memory is
-        O(local elements · _EVAL_CHUNK) — a *fixed* multiple of the pool
-        shard, independent of ``eval_batch``.  ``cands`` is replicated and
-        may be padded with -1 (matches nothing, gain 0); per-shard counts
-        are psum-reduced into the replicated exact gains.
-        """
-        def local(flat, ids, valid, cov_words, cands):
-            flat, ids, valid = flat[0], ids[0], valid[0]
-            covered = _unpack_covered(cov_words[0])
-            c = cands.shape[0]
-            pad = (-c) % _EVAL_CHUNK
-            cs = jnp.concatenate(
-                [cands, jnp.full((pad,), -1, cands.dtype)]) if pad else cands
-
-            def chunk(cc):
-                newly = jax.vmap(
-                    lambda u: _newly_rows(flat, ids, valid, covered, u))(cc)
-                return newly.sum(axis=1, dtype=jnp.int32)
-
-            gains = jax.lax.map(chunk, cs.reshape(-1, _EVAL_CHUNK))
-            return jax.lax.psum(gains.reshape(-1)[:c], ax)
-
-        return _shard_map(
-            local, mesh=mesh, in_specs=(buf, buf, buf, buf, P()),
-            out_specs=P())(flat, ids, valid, cov_words, cands)
-
-    @jax.jit
-    def apply_seed(flat, ids, valid, cov_words, u):
-        """Commit seed ``u``: OR its rows into each shard's packed Covered
-        bitset and psum the exact gain."""
-        def local(flat, ids, valid, cov_words, u):
-            newly = _newly_rows(flat[0], ids[0], valid[0],
-                                _unpack_covered(cov_words[0]), u)
-            new_words = _pack_covered(newly)
-            gain = jax.lax.psum(_popcount(new_words).sum(dtype=jnp.int32), ax)
-            return (cov_words[0] | new_words)[None], gain
-        return _shard_map(
-            local, mesh=mesh, in_specs=(buf, buf, buf, buf, P()),
-            out_specs=(buf, P()))(flat, ids, valid, cov_words, u)
-
     # -- variant programs (weighted Occur / candidate mask / cost-ratio /
     # group budgets) — the generalized Alg. 7 all four IM variants share.
-    # Plain problems never route here (they keep the bit-identical fast
-    # paths above); every variant knob composes inside one scan.
+    # Plain problems never route here (they keep the bit-identical fused
+    # scan above); every variant knob composes inside one scan.
 
     def _variant_locals(weighted):
-        """Shared scan body pieces for the fused/bitset variant programs."""
+        """Scan body pieces of the variant program, by Occur dtype."""
 
         def occur_init(flat, ids, valid, ew, *, n, num_rows):
             if weighted:
@@ -1492,19 +1358,17 @@ def _mesh_select_fns(mesh: Mesh):
 
         return occur_init, pick, gain_of, dec_of
 
-    def _make_variant(weighted, use_bitset):
+    def _make_variant(weighted):
         occur_init, pick, gain_of, dec_of = _variant_locals(weighted)
         statics = ("num_rows", "n", "k_steps", "n_group", "n_groups",
                    "group_quota", "use_costs")
 
-        def program(flat, ids, valid, nrr, wvec, m_words, ew, cand, costs,
-                    budget, *, num_rows, n, k_steps, n_group, n_groups,
-                    group_quota, use_costs):
-            def local(flat, ids, valid, nrr, wvec, m_words, ew, cand, costs,
-                      budget):
+        def program(flat, ids, valid, nrr, wvec, ew, cand, costs, budget,
+                    *, num_rows, n, k_steps, n_group, n_groups, group_quota,
+                    use_costs):
+            def local(flat, ids, valid, nrr, wvec, ew, cand, costs, budget):
                 flat, ids, valid = flat[0], ids[0], valid[0]
                 ew_sh = ew[0] if weighted else None
-                m = m_words[0] if use_bitset else None
                 occur0, ew_l, roww = occur_init(flat, ids, valid, ew_sh,
                                                 n=n, num_rows=num_rows)
                 occur0 = jax.lax.psum(occur0, ax)
@@ -1522,14 +1386,8 @@ def _mesh_select_fns(mesh: Mesh):
                     feas = (gbud[group_of] > 0) & cand & ~picked
                     u, ok = pick(occur, feas, costs, budget, spent,
                                  n=n, use_costs=use_costs)
-                    covered = _unpack_covered(cov_words)
-                    if use_bitset:
-                        col = m[:, jnp.minimum(u >> 5, m.shape[1] - 1)]
-                        hit = ((col >> (u & 31).astype(jnp.uint32))
-                               & jnp.uint32(1)) != 0
-                        newly = hit & ~covered & (u < n)
-                    else:
-                        newly = _newly_rows(flat, ids, valid, covered, u)
+                    newly = _newly_rows(flat, ids, valid,
+                                        _unpack_covered(cov_words), u)
                     new_words = _pack_covered(newly)
                     gain = jax.lax.psum(gain_of(newly, new_words, roww), ax)
                     dec = jax.lax.psum(
@@ -1545,8 +1403,8 @@ def _mesh_select_fns(mesh: Mesh):
                     if weighted:
                         # f32 decrement chains can drift a saturated node's
                         # marginal to ~-1ulp; clamping keeps the feasibility
-                        # test (occur >= 0 / > 0) aligned with CELF's fresh
-                        # exact sums, which are never negative
+                        # test (occur >= 0 / > 0) aligned with the exact
+                        # marginals, which are never negative
                         occur = jnp.maximum(occur, 0.0)
                     return ((occur, cov_words | new_words, spent, gbud,
                              picked), (u, gain))
@@ -1567,19 +1425,15 @@ def _mesh_select_fns(mesh: Mesh):
                 local, mesh=mesh,
                 in_specs=(buf, buf, buf, vec,
                           vec if weighted else dummy,
-                          b3 if use_bitset else dummy,
                           buf if weighted else dummy,
                           dummy, dummy, dummy),
                 out_specs=(P(), P(), P(), P()))(
-                flat, ids, valid, nrr, wvec, m_words, ew, cand, costs,
-                budget)
+                flat, ids, valid, nrr, wvec, ew, cand, costs, budget)
 
         return jax.jit(program, static_argnames=statics)
 
-    fused_variant = _make_variant(weighted=False, use_bitset=False)
-    fused_variant_w = _make_variant(weighted=True, use_bitset=False)
-    bitset_variant = _make_variant(weighted=False, use_bitset=True)
-    bitset_variant_w = _make_variant(weighted=True, use_bitset=True)
+    fused_variant = _make_variant(weighted=False)
+    fused_variant_w = _make_variant(weighted=True)
 
     @functools.partial(jax.jit, static_argnames=(
         "num_rows", "n", "k_max", "n_group", "n_groups"))
@@ -1694,84 +1548,6 @@ def _mesh_select_fns(mesh: Mesh):
             flat, ids, valid, nrr, cand, costs, budget, ks, quota,
             plain, use_costs)
 
-    @functools.partial(jax.jit, static_argnames=("n",))
-    def occur_weighted(flat, valid, ew, *, n):
-        """Weighted Occur histogram (CELF's upper-bound init): one
-        psum-reduced scatter-add of the element weights."""
-        def local(flat, valid, ew):
-            h = jnp.zeros(n + 1, jnp.float32).at[flat[0]].add(
-                jnp.where(valid[0], ew[0], 0.0), mode="drop")[:n]
-            return jax.lax.psum(h, ax)
-        return _shard_map(
-            local, mesh=mesh, in_specs=(buf, buf, buf),
-            out_specs=P())(flat, valid, ew)
-
-    @functools.partial(jax.jit, static_argnames=("num_rows",))
-    def row_weights(ids, valid, ew, *, num_rows):
-        """Per-shard (D, num_rows) row-weight vectors from the element
-        weights — computed once per CELF selection (it only changes on
-        append), then fed to ``eval_batch_w``/``apply_seed_w`` as an
-        operand instead of being re-derived per call."""
-        def local(ids, valid, ew):
-            ew_l = jnp.where(valid[0], ew[0], 0.0)
-            return jnp.maximum(jax.ops.segment_max(
-                ew_l, jnp.clip(ids[0], 0, num_rows - 1),
-                num_segments=num_rows), 0.0)[None]
-        return _shard_map(
-            local, mesh=mesh, in_specs=(buf, buf, buf),
-            out_specs=buf)(ids, valid, ew)
-
-    @jax.jit
-    def eval_batch_w(flat, ids, valid, roww, cov_words, cands):
-        """Weighted twin of ``eval_batch``: per-candidate marginal *covered
-        weight* (sum of row weights over newly covered rows), psum-reduced.
-        Same per-shard accumulation as the weighted fused scan, so the
-        celf==fused parity holds bit for bit."""
-        def local(flat, ids, valid, roww, cov_words, cands):
-            flat, ids, valid, roww = flat[0], ids[0], valid[0], roww[0]
-            covered = _unpack_covered(cov_words[0])
-            c = cands.shape[0]
-            pad = (-c) % _EVAL_CHUNK
-            cs = jnp.concatenate(
-                [cands, jnp.full((pad,), -1, cands.dtype)]) if pad else cands
-
-            def chunk(cc):
-                newly = jax.vmap(
-                    lambda u: _newly_rows(flat, ids, valid, covered, u))(cc)
-                return jnp.where(newly, roww[None, :], 0.0).sum(
-                    axis=1, dtype=jnp.float32)
-
-            gains = jax.lax.map(chunk, cs.reshape(-1, _EVAL_CHUNK))
-            return jax.lax.psum(gains.reshape(-1)[:c], ax)
-
-        return _shard_map(
-            local, mesh=mesh, in_specs=(buf, buf, buf, buf, buf, P()),
-            out_specs=P())(flat, ids, valid, roww, cov_words, cands)
-
-    @jax.jit
-    def apply_seed_w(flat, ids, valid, roww, cov_words, u):
-        """Weighted twin of ``apply_seed``: commit + weighted gain psum."""
-        def local(flat, ids, valid, roww, cov_words, u):
-            flat, ids, valid = flat[0], ids[0], valid[0]
-            newly = _newly_rows(flat, ids, valid,
-                                _unpack_covered(cov_words[0]), u)
-            new_words = _pack_covered(newly)
-            gain = jax.lax.psum(
-                jnp.where(newly, roww[0], 0.0).sum(dtype=jnp.float32), ax)
-            return (cov_words[0] | new_words)[None], gain
-        return _shard_map(
-            local, mesh=mesh, in_specs=(buf, buf, buf, buf, buf, P()),
-            out_specs=(buf, P()))(flat, ids, valid, roww, cov_words, u)
-
-    @jax.jit
-    def total_weight(wvec):
-        """psum of the per-shard valid-row weight sums (weighted F_R
-        denominator), as a replicated device scalar."""
-        def local(wvec):
-            return jax.lax.psum(wvec[0], ax)
-        return _shard_map(
-            local, mesh=mesh, in_specs=(vec,), out_specs=P())(wvec)
-
     @functools.partial(jax.jit, static_argnames=("stripe", "interpret"))
     def sweep(sk, cov_sk, *, stripe, interpret=None):
         """Δocc lower bounds for every node in one mesh-parallel sweep:
@@ -1807,64 +1583,29 @@ def _mesh_select_fns(mesh: Mesh):
 
     fns = Fns()
     fns.fused = fused
-    fns.bitset = bitset
     fns.occur = occur
-    fns.eval_batch = eval_batch
-    fns.apply_seed = apply_seed
     fns.sweep = sweep
     fns.union = union
     fns.fused_variant = fused_variant
     fns.fused_variant_w = fused_variant_w
-    fns.bitset_variant = bitset_variant
-    fns.bitset_variant_w = bitset_variant_w
-    fns.occur_weighted = occur_weighted
-    fns.row_weights = row_weights
-    fns.eval_batch_w = eval_batch_w
-    fns.apply_seed_w = apply_seed_w
-    fns.total_weight = total_weight
     fns.stacked = stacked
     return fns
 
 
-def bitset_words(n: int) -> int:
-    """Node-word width of the bitset matrix: ceil(n/32) rounded up to whole
-    128-word tiles."""
-    return -(-n // (32 * 128)) * 128
+def select_seeds_device(store: "ShardedDeviceRRStore", k: int
+                        ) -> CoverageResult:
+    """Fused greedy selection directly on a :class:`ShardedDeviceRRStore`:
+    the flat scan (:func:`_mesh_select_fns` ``fused``) over the pool's
+    capacity buffers.
 
-
-def auto_method(store: "ShardedDeviceRRStore") -> str:
-    """The backend ``method="auto"`` picks for this pool: ``"bitset"`` iff
-    the per-shard bit matrix is no larger than the per-shard flat capacity
-    buffers it replaces (mean RR size ≳ n/32), else ``"flat"``."""
-    n_words = bitset_words(store.n_nodes)
-    return ("bitset" if store.row_capacity() * n_words <= store.capacity
-            else "flat")
-
-
-def select_seeds_device(store: "ShardedDeviceRRStore", k: int,
-                        method: str = "auto") -> CoverageResult:
-    """Fused greedy selection directly on a :class:`ShardedDeviceRRStore`.
-
-    ``method``: ``"flat"`` (scatter decrement, optimal for sparse RR pools),
-    ``"bitset"`` (Pallas bit-matrix path, optimal for dense pools), or
-    ``"auto"`` (:func:`auto_method`).
     Everything stays on the mesh; the returned ``frac`` uses the psum of
     the per-shard device row counts, so the call is legal under
     ``jax.transfer_guard("disallow")`` on a mesh of any size.
     """
     fns = _mesh_select_fns(store.mesh)
-    num_rows = store.row_capacity()
-    if method == "auto":
-        method = auto_method(store)
-    if method == "flat":
-        seeds, gains, frac = fns.fused(
-            store._flat, store._ids, store._valid, store.n_rr_dev,
-            num_rows=num_rows, n=store.n_nodes, k=k)
-    elif method == "bitset":
-        seeds, gains, frac = fns.bitset(store.bitset_matrix(),
-                                        store.n_rr_dev, k=k)
-    else:
-        raise ValueError(f"unknown selection method {method!r}")
+    seeds, gains, frac = fns.fused(
+        store._flat, store._ids, store._valid, store.n_rr_dev,
+        num_rows=store.row_capacity(), n=store.n_nodes, k=k)
     return CoverageResult(seeds=seeds, gains=gains, frac=frac)
 
 
@@ -1878,7 +1619,7 @@ class SelectionSpec(NamedTuple):
     greedy to cost-ratio (argmax marginal-gain/cost among affordable
     nodes); ``weighted`` reads the store's per-row weights into Occur and
     the gains (the importance-weighted estimator).  Plain top-k problems
-    never build a spec — they keep the untouched bit-identical fast paths.
+    never build a spec — they keep the plain fused scan.
     """
     k_steps: int                       # scan length / max seeds
     n_group: int                       # group width over the item space
@@ -1916,13 +1657,13 @@ def _spec_operands(store: "ShardedDeviceRRStore", spec: SelectionSpec):
     return cand, costs, budget
 
 
-def select_variant(store: "ShardedDeviceRRStore", spec: SelectionSpec,
-                   method: str = "flat") -> VariantResult:
+def select_variant(store: "ShardedDeviceRRStore", spec: SelectionSpec
+                   ) -> VariantResult:
     """Generalized greedy (weighted / candidate-masked / cost-ratio /
     group-budgeted) over the sharded pool — the scan twin of
     :func:`select_seeds_device` for non-plain :class:`SelectionSpec`.
 
-    Runs as the same shard_map protocol as the plain backends (Occur psum,
+    Runs as the same shard_map protocol as the plain scan (Occur psum,
     replicated argmax, shard-local Covered), so results are bit-identical
     across mesh sizes whenever the weight sums are exact in float32 (always
     for unweighted specs; for weighted ones use integer-valued weights if
@@ -1937,21 +1678,10 @@ def select_variant(store: "ShardedDeviceRRStore", spec: SelectionSpec,
     dummy = jax.device_put(np.zeros(1, np.float32), store._sh_rep)
     wvec = store._w_dev if spec.weighted else dummy
     ew = store._ew if spec.weighted else dummy
-    if method == "auto":
-        method = "flat"
-    if method == "bitset":
-        m_words = store.bitset_matrix()
-        program = (fns.bitset_variant_w if spec.weighted
-                   else fns.bitset_variant)
-    elif method == "flat":
-        m_words = dummy
-        program = (fns.fused_variant_w if spec.weighted
-                   else fns.fused_variant)
-    else:
-        raise ValueError(f"unknown selection method {method!r}")
+    program = fns.fused_variant_w if spec.weighted else fns.fused_variant
     seeds, gains, frac, spent = program(
         store._flat, store._ids, store._valid, store.n_rr_dev, wvec,
-        m_words, ew, cand, costs, budget,
+        ew, cand, costs, budget,
         num_rows=num_rows, n=n, k_steps=spec.k_steps,
         n_group=spec.n_group, n_groups=spec.n_groups,
         group_quota=spec.group_quota,
@@ -2045,136 +1775,6 @@ def select_seeds_stacked(store: "ShardedDeviceRRStore",
                          n_requests=len(reqs))
 
 
-def select_seeds_celf(store: "ShardedDeviceRRStore", k: int, *,
-                      eval_batch: int = 32, use_sketch: bool = True,
-                      spec: SelectionSpec | None = None,
-                      stats_out: dict | None = None) -> CoverageResult:
-    """CELF lazy greedy selection with sketch-first candidate ordering.
-
-    The fused scan pays one full O(elements) pool pass per argmax round.
-    Here marginal gains are *lazily* verified: a host priority array holds
-    each node's last exact marginal gain (initialized from the exact Occur
-    histogram) — a valid upper bound under submodularity — and per seed only
-    the candidates that could still win are re-evaluated exactly, in batches
-    of ``eval_batch``.  The packed per-node coverage sketch
-    (``core/sketch.py``) orders that verification: its union-estimate Δocc
-    (one mesh-parallel popcount sweep over all nodes) is a certified *lower*
-    bound on the marginal gain, so the likeliest winners are verified first
-    and acceptance usually triggers on the first pop.
-
-    On a multi-device mesh, exact re-evaluation shards over the pool like
-    the fused scan (each device scans its local rows; per-shard counts are
-    psum-reduced), the sweep stripes candidates across devices, and the
-    union sketch is one psum-OR of k/32 words per accepted seed — so the
-    backend accepts the same sharded pool views as the other two.
-
-    Correctness is structural, not statistical: a candidate is accepted only
-    when its freshly-computed exact gain is ≥ every remaining upper bound
-    (ties resolved to the lowest node id, matching ``jnp.argmax``), so the
-    returned seeds are *identical* to the fused-scan path for any sketch
-    size and any mesh size — the sketch only changes how many exact
-    evaluations happen.  With ``sketch_k >= n_rr`` (mod bucketing) the
-    estimates are themselves exact and one verification batch per seed
-    suffices.  The (1−1/e−ε) guarantee of Alg. 2 is therefore preserved
-    verbatim.
-
-    All device interaction is explicit (``device_put``/``device_get``), so
-    the call is legal under ``jax.transfer_guard("disallow")``; shapes are
-    the pool's capacity buffers (compiles only at doublings, like the fused
-    path) plus the fixed-size sketch.
-
-    ``spec`` switches to the generalized variant loop (weighted gains,
-    candidate mask, cost-ratio lazy greedy, group budgets) — see
-    :func:`_celf_variant`; the plain path below is untouched.
-    """
-    if spec is not None:
-        return _celf_variant(store, spec, eval_batch=eval_batch,
-                             use_sketch=use_sketch, stats_out=stats_out)
-    n = store.n_nodes
-    num_rows = store.row_capacity()
-    nw = num_rows // 32
-    d = store.n_shards
-    fns = _mesh_select_fns(store.mesh)
-    flat, ids, valid = store._flat, store._ids, store._valid
-    c = max(1, min(eval_batch, n))
-
-    ub = np.asarray(spans.fetch("select",
-        fns.occur(flat, valid, n=n)), dtype=np.int64).copy()
-    fresh = np.zeros(n, bool)
-    # explicit placement: plain jnp.zeros is an implicit h2d transfer and
-    # would trip the solver's transfer_guard("disallow")
-    cov_words = jax.device_put(np.zeros((d, nw), np.uint32), store._sh_buf)
-    if use_sketch:
-        sk_words = store.sketch_words_mesh()
-        sk_k = int(sk_words.shape[2]) * 32
-        stripe = store.sketch_rows // d
-        itp = kops.resolve_interpret(None)
-        cov_sk = jax.device_put(
-            np.zeros((d, sk_words.shape[2]), np.uint32), store._sh_buf)
-    n_evals = 0
-    n_eval_calls = 0
-    node_ids = np.arange(n)
-
-    def eval_exact(cands):
-        nonlocal n_evals, n_eval_calls
-        cands = np.asarray(cands, np.int32)
-        pad = np.full(c, -1, np.int32)
-        pad[:len(cands)] = cands
-        g = np.asarray(spans.fetch("select", fns.eval_batch(
-            flat, ids, valid, cov_words,
-            jax.device_put(pad, store._sh_rep))))
-        ub[cands] = g[:len(cands)]
-        fresh[cands] = True
-        n_evals += len(cands)
-        n_eval_calls += 1
-
-    seeds, gains = [], []
-    for _ in range(k):
-        fresh[:] = False
-        if use_sketch:
-            # sketch sweep: Δocc lower bounds for every node in one
-            # mesh-parallel pass; verify the likeliest winners exactly
-            # before entering the lazy loop (O(n) top-c selection —
-            # eval-batch composition affects only the eval count, never
-            # the accepted seed)
-            deltas = np.asarray(spans.fetch("select",
-                fns.sweep(sk_words, cov_sk, stripe=stripe, interpret=itp)))[:n]
-            key = deltas.astype(np.int64) * (n + 1) - node_ids
-            eval_exact(np.argpartition(-key, c - 1)[:c])
-        while True:
-            u = int(np.argmax(ub))       # first max == lowest id on ties
-            if fresh[u]:
-                break
-            # verify the c highest-bound stale candidates, lowest id first
-            # on ties (they are the ones that block acceptance).  Composite
-            # int64 key keeps this O(n) — ub <= n_rr and id < n both fit
-            # int32, so ub*(n+1) - id cannot overflow.  The set always
-            # contains the stale argmax, so the loop makes progress.
-            stale_idx = node_ids[~fresh]
-            cc = min(c, len(stale_idx))
-            key = ub[stale_idx] * (n + 1) - stale_idx
-            eval_exact(stale_idx[np.argpartition(-key, cc - 1)[:cc]])
-        u_dev = jax.device_put(np.int32(u), store._sh_rep)
-        cov_words, gain_dev = fns.apply_seed(flat, ids, valid, cov_words,
-                                             u_dev)
-        if use_sketch:
-            cov_sk = fns.union(cov_sk, sk_words, u_dev)
-        gain = int(spans.fetch("select", gain_dev))
-        ub[u] = 0                        # exact: u's rows are now covered
-        seeds.append(u)
-        gains.append(gain)
-
-    if stats_out is not None:
-        stats_out.update(n_exact_evals=n_evals, n_eval_calls=n_eval_calls,
-                         sketch_k=(sk_k if use_sketch else 0),
-                         n_rr=store.n_rr)
-    frac = sum(gains) / max(store.n_rr, 1)
-    return CoverageResult(
-        seeds=jax.device_put(np.asarray(seeds, np.int32)),
-        gains=jax.device_put(np.asarray(gains, np.int32)),
-        frac=jax.device_put(np.float32(frac)))
-
-
 def select_seeds_sketch(store, k: int, *, cand=None,
                         info_out: dict | None = None) -> CoverageResult:
     """Greedy selection on sketch estimates alone — no exact verification.
@@ -2184,8 +1784,8 @@ def select_seeds_sketch(store, k: int, *, cand=None,
     psum of disjoint int32 stripes — bit-identical at any shard count), a
     host argmax (first max == lowest id, matching ``jnp.argmax``), and one
     psum-OR union fold.  No pool exists to verify against, so this is the
-    documented departure from the exact-acceptance contract of
-    :func:`select_seeds_celf`; what survives is a *certified* error
+    documented departure from the exact greedy of
+    :func:`select_seeds_device`; what survives is a *certified* error
     estimate from linear counting, surfaced via ``info_out`` and
     ``IMResult.spread_bounds``:
 
@@ -2255,186 +1855,13 @@ def select_seeds_sketch(store, k: int, *, cand=None,
                         saturated=saturated, rel_error=rel_err,
                         exact_regime=exact_regime, sketch_k=sk_k, n_rr=n_rr)
     # pad to k with the sentinel item (trimmed by the solver's live mask),
-    # matching the fixed-length contract of the device backends
+    # matching the fixed-length contract of the fused scan
     pad = [n] * (k - len(seeds))
     frac = est_rows / max(n_rr, 1)
     return CoverageResult(
         seeds=jax.device_put(np.asarray(seeds + pad, np.int32)),
         gains=jax.device_put(np.asarray(gains + [0] * len(pad), np.int32)),
         frac=jax.device_put(np.float32(frac)))
-
-
-def _celf_variant(store: "ShardedDeviceRRStore", spec: SelectionSpec, *,
-                  eval_batch: int = 32, use_sketch: bool = True,
-                  stats_out: dict | None = None) -> VariantResult:
-    """CELF lazy greedy generalized to the variant spec.
-
-    The acceptance logic is the plain path's, applied to the variant score
-    (``ub`` for cardinality specs, ``ub/cost`` for budgeted ones, both
-    masked to feasible candidates): a node is accepted only when its
-    *fresh* exact score is the argmax of all remaining upper-bound scores
-    (ties -> lowest id), so the returned seeds are identical to
-    :func:`select_variant`'s fused scan for any sketch size — submodularity
-    makes ``ub >= exact`` an invariant, and positive costs preserve it
-    under division.  Feasibility (candidate mask, group budgets, remaining
-    budget) only ever shrinks, so masked-out nodes never need their bounds
-    refreshed.
-
-    Weighted caveat: the fused scan maintains Occur by f32 decrement chains
-    while CELF re-sums fresh gains, so with *fractional* row weights the
-    two can disagree on ulp-level near-ties (the scan clamps drift at 0, so
-    seed counts still match); weights whose partial sums are exact in
-    float32 — integer-valued weights below 2^24 — make the parity exact,
-    and are what the conformance suite pins.
-    """
-    if spec.weighted and store._ew is None:
-        raise ValueError("weighted selection needs a row_weighted store")
-    n = store.n_nodes
-    num_rows = store.row_capacity()
-    nw = num_rows // 32
-    d = store.n_shards
-    fns = _mesh_select_fns(store.mesh)
-    flat, ids, valid = store._flat, store._ids, store._valid
-    c = max(1, min(eval_batch, n))
-    weighted = spec.weighted
-    use_costs = spec.budget is not None
-    # costs/budget bookkeeping in float32, mirroring the fused scan's
-    # device arithmetic exactly (same rounding -> same feasibility set and
-    # the same cost-ratio ordering, keeping the celf==fused seed contract)
-    costs = (np.asarray(spec.costs, np.float32) if spec.costs is not None
-             else np.ones(n, np.float32))
-    cand = (np.asarray(spec.cand, bool) if spec.cand is not None
-            else np.ones(n, bool))
-    group_of = np.arange(n) // spec.n_group
-    gbud = np.full(spec.n_groups, spec.group_quota, np.int64)
-    budget32 = np.float32(spec.budget) if use_costs else np.float32(np.inf)
-    spent32 = np.float32(0.0)
-
-    if weighted:
-        ub = np.asarray(spans.fetch("select", fns.occur_weighted(
-            flat, valid, store._ew, n=n)), np.float64).copy()
-        denom = float(spans.fetch("select", fns.total_weight(store._w_dev)))
-        roww_dev = fns.row_weights(ids, valid, store._ew, num_rows=num_rows)
-    else:
-        ub = np.asarray(spans.fetch("select",
-            fns.occur(flat, valid, n=n)), np.float64).copy()
-        denom = float(max(store.n_rr, 1))
-    fresh = np.zeros(n, bool)
-    cov_words = jax.device_put(np.zeros((d, nw), np.uint32), store._sh_buf)
-    if use_sketch:
-        sk_words = store.sketch_words_mesh()
-        sk_k = int(sk_words.shape[2]) * 32
-        stripe = store.sketch_rows // d
-        itp = kops.resolve_interpret(None)
-        cov_sk = jax.device_put(
-            np.zeros((d, sk_words.shape[2]), np.uint32), store._sh_buf)
-    n_evals = 0
-    n_eval_calls = 0
-    node_ids = np.arange(n)
-
-    def eval_exact(cands):
-        nonlocal n_evals, n_eval_calls
-        cands = np.asarray(cands, np.int32)
-        pad = np.full(c, -1, np.int32)
-        pad[:len(cands)] = cands
-        pad_dev = jax.device_put(pad, store._sh_rep)
-        if weighted:
-            g = np.asarray(spans.fetch("select", fns.eval_batch_w(
-                flat, ids, valid, roww_dev, cov_words, pad_dev)))
-        else:
-            g = np.asarray(spans.fetch("select", fns.eval_batch(
-                flat, ids, valid, cov_words, pad_dev)))
-        ub[cands] = g[:len(cands)]
-        fresh[cands] = True
-        n_evals += len(cands)
-        n_eval_calls += 1
-
-    def scores(feas):
-        if use_costs:
-            # float32 division, bit-identical to the device scan's
-            # occur.astype(f32) / costs (ub holds exact gains: int counts
-            # or f32-representable weighted sums, so the f32 cast is exact)
-            return np.where(feas & (ub > 0),
-                            ub.astype(np.float32) / costs, -np.inf)
-        return np.where(feas, ub, -np.inf)
-
-    def top_stale(feas, sc, k_top):
-        """Highest-score stale feasible candidates, lowest id first on
-        ties (float scores -> lexsort instead of the plain path's int
-        composite key)."""
-        idx = node_ids[~fresh & feas & (sc > -np.inf)]
-        order = np.lexsort((idx, -sc[idx]))
-        return idx[order[:k_top]]
-
-    seeds, gains_out = [], []
-    picked = np.zeros(n, bool)
-    for _ in range(spec.k_steps):
-        # ~picked mirrors the fused variant scan: seeds are never
-        # re-selected (their marginal is 0 forever under submodularity)
-        feas = cand & (gbud[group_of] > 0) & ~picked
-        if use_costs:
-            feas = feas & (costs <= budget32 - spent32)
-        if not feas.any():
-            break
-        fresh[:] = False
-        if use_sketch:
-            deltas = np.asarray(spans.fetch("select",
-                fns.sweep(sk_words, cov_sk, stripe=stripe, interpret=itp)))[:n]
-            est = np.where(feas, deltas / costs if use_costs
-                           else deltas.astype(np.float64), -np.inf)
-            order = np.lexsort((node_ids, -est))
-            eval_exact(order[:c])
-        accepted = None
-        while True:
-            sc = scores(feas)
-            u = int(np.argmax(sc))       # first max == lowest id on ties
-            if sc[u] == -np.inf:
-                # only reachable for budgeted specs (ub > 0 filter): every
-                # remaining affordable candidate has zero gain, exactly
-                # where the fused scan starts emitting sentinels.  For
-                # cardinality specs feas.any() guarantees a >= 0 score
-                # (ub is a non-negative coverage bound), so the lazy loop
-                # always accepts — zero-gain lowest-id picks included,
-                # matching the fused argmax semantics.
-                break
-            if fresh[u]:
-                accepted = u
-                break
-            stale = top_stale(feas, sc, c)
-            eval_exact(stale)
-        if accepted is None:
-            break
-        u = accepted
-        u_dev = jax.device_put(np.int32(u), store._sh_rep)
-        if weighted:
-            cov_words, gain_dev = fns.apply_seed_w(flat, ids, valid,
-                                                   roww_dev, cov_words,
-                                                   u_dev)
-        else:
-            cov_words, gain_dev = fns.apply_seed(flat, ids, valid, cov_words,
-                                                 u_dev)
-        if use_sketch:
-            cov_sk = fns.union(cov_sk, sk_words, u_dev)
-        gain = spans.fetch("select", gain_dev)
-        ub[u] = 0.0
-        picked[u] = True
-        gbud[group_of[u]] -= 1
-        if use_costs:
-            spent32 = np.float32(spent32 + costs[u])
-        seeds.append(u)
-        gains_out.append(gain)
-
-    if stats_out is not None:
-        stats_out.update(n_exact_evals=n_evals, n_eval_calls=n_eval_calls,
-                         sketch_k=(sk_k if use_sketch else 0),
-                         n_rr=store.n_rr)
-    gdtype = np.float32 if weighted else np.int32
-    frac = float(np.asarray(gains_out, np.float64).sum()) / max(denom, 1e-30)
-    return VariantResult(
-        seeds=jax.device_put(np.asarray(seeds, np.int32)),
-        gains=jax.device_put(np.asarray(gains_out, gdtype)),
-        frac=jax.device_put(np.float32(frac)),
-        spent=jax.device_put(np.float32(spent32 if use_costs else 0.0)))
 
 
 class PaddedStore(NamedTuple):

@@ -29,10 +29,9 @@ Variants thread through every layer: weighted problems draw roots ∝
 (:func:`~repro.core.engine.draw_roots`; engines without weighted-root
 support fall back to the importance-weighted row estimator on a
 ``row_weighted`` store), and non-plain selection runs the generalized
-shard_map scan (:func:`~repro.core.coverage.select_variant` /
-``select_seeds_celf(spec=...)``) with candidate masks, cost-ratio lazy
-greedy and per-round (group) budgets — on a mesh of any size, under the
-same transfer guard.
+shard_map scan (:func:`~repro.core.coverage.select_variant`) with
+candidate masks, cost-ratio greedy and per-round (group) budgets — on a
+mesh of any size, under the same transfer guard.
 
 The hot loop is *mesh-resident*: the RR pool is a
 :class:`~repro.core.coverage.ShardedDeviceRRStore` sharded over the device
@@ -100,7 +99,9 @@ class IMMStats:
     overflow_fraction: float = 0.0
     frac_covered: float = 0.0
     sampling_steps: int = 0
-    selection: str = "auto"
+    # the selection that ran: "fused" (the exact scan over the pool) or
+    # "sketch" (approximate mode's pool-free greedy)
+    selection: str = "fused"
     variant: str = "plain"
     early_exit_skips: int = 0
     budget_spent: float = 0.0
@@ -152,15 +153,6 @@ class PoolLease:
         return s.n_shards * (s.per_device_pool_bytes() + s.sketch_bytes())
 
 
-# user-facing selection knob -> DeviceRRStore.select method.  "fused" is the
-# single-scan flat path (the historical default), "bitset" the Pallas
-# bit-matrix path, "celf-sketch" the lazy greedy over coverage sketches.
-_SELECTION_METHODS = {
-    "auto": "auto", "fused": "flat", "flat": "flat", "bitset": "bitset",
-    "celf-sketch": "celf", "celf": "celf",
-}
-
-
 class IMMSolver:
     """Stateful solver: owns the RR pool so Alg. 2 reuses earlier samples.
 
@@ -169,6 +161,14 @@ class IMMSolver:
     (each engine takes the subset it understands).  ``model="lt"`` keeps its
     historical meaning by resolving to the ``lt`` engine (a problem's
     ``model=`` field overrides it per solve).
+
+    Selection is one exact greedy, the selection scan over the pool
+    (:func:`~repro.core.coverage.select_seeds_device`, or
+    :func:`~repro.core.coverage.select_variant` for non-plain problems);
+    ``mode="approximate"`` problems take the pool-free sketch greedy.
+    ``sketch_k`` sizes the exact pool's optional coverage sketch, which
+    only the θ early exit and deadline-degraded results read (an
+    ``early_exit`` problem builds one at the default size when unset).
 
     The engine and the pool are rebuilt whenever a solve's problem changes
     their *signature* (diffusion model, ``t_rounds``, ``node_weights``) —
@@ -180,8 +180,7 @@ class IMMSolver:
                  engine: Union[str, SamplerEngine] = "queue",
                  batch: Optional[int] = None, qcap: Optional[int] = None,
                  ec: Optional[int] = None, model: Optional[str] = None,
-                 selection: str = "auto", sketch_k: Optional[int] = None,
-                 eval_batch: Optional[int] = None,
+                 sketch_k: Optional[int] = None,
                  mesh=None, seed: int = 0,
                  fault_policy: Optional[FaultPolicy] = None,
                  checkpoint_dir: Optional[str] = None,
@@ -213,19 +212,7 @@ class IMMSolver:
                     "batch/qcap/ec/model have no effect when an engine "
                     "instance is passed; configure the engine instead")
             self.g_rev = getattr(engine, "g_rev", None)
-        if selection not in _SELECTION_METHODS:
-            raise ValueError(f"unknown selection {selection!r}; one of "
-                             f"{sorted(_SELECTION_METHODS)}")
-        self.selection = selection
-        self._sel_method = _SELECTION_METHODS[selection]
         self._sketch_k_arg = sketch_k
-        # CELF exact-verification batch width (celf/celf-sketch selection):
-        # candidates re-evaluated exactly per device pass.  None keeps the
-        # backend default; benchmarks/perf_im_engines --selection-only
-        # sweeps it (BENCH_selection.json)
-        if eval_batch is not None and int(eval_batch) < 1:
-            raise ValueError("eval_batch must be >= 1")
-        self.eval_batch = None if eval_batch is None else int(eval_batch)
         self._mesh = mesh
         self.key = jax.random.key(seed)
         self._engine_obj = None
@@ -269,6 +256,16 @@ class IMMSolver:
     def _default_model(self) -> str:
         return "lt" if self._model_arg == "lt" else "ic"
 
+    def _resolve_sketch_k(self, problem: IMProblem) -> Optional[int]:
+        """The exact store's sketch size: ``sketch_k=`` as given, else the
+        default for an ``early_exit`` problem, whose θ gate reads the
+        incremental sketch (its global row numbering keeps the
+        occupancy==count identity on any mesh — the on-demand per-shard
+        fold does not), else none."""
+        if self._sketch_k_arg is None and problem.early_exit:
+            return cov.ShardedDeviceRRStore.DEFAULT_SKETCH_K
+        return self._sketch_k_arg
+
     def _ensure_prepared(self):
         if self._sig is None:
             self._prepare(IMProblem(k=1, eps=0.5,
@@ -306,14 +303,7 @@ class IMMSolver:
                 raise ValueError("MRIM sampling is IC-only (paper §4.8); the "
                                  "solver's default model is 'lt'")
             w = r.node_weights
-            # the celf path estimates from the incremental coverage sketch,
-            # and the θ early-exit gate reads it (an incremental fold is
-            # required: its global row numbering keeps the occupancy==count
-            # identity on any mesh — the on-demand per-shard fold does not)
-            sketch_k = self._sketch_k_arg
-            if sketch_k is None and (self._sel_method == "celf"
-                                     or problem.early_exit):
-                sketch_k = cov.ShardedDeviceRRStore.DEFAULT_SKETCH_K
+            sketch_k = self._resolve_sketch_k(problem)
             # approximate (pool-free) mode: the sketch IS the pool, so one
             # always exists, auto-sized from (ε, n) so the certified
             # estimator error stays within ε/2 at design load
@@ -379,7 +369,7 @@ class IMMSolver:
             self._row_weight_mode = row_weight_mode
             self._node_w_dev = (jax.device_put(w) if row_weight_mode else None)
             # mesh placement is decided exactly once, here: the pool, the
-            # sketch, and every selection backend live on this mesh for the
+            # sketch, and the selection programs live on this mesh for the
             # solver's lifetime (mesh=None -> the 1-device mesh special case)
             if _store is not None:                   # adopt_pool() hand-off
                 want_k = (sketch_mod.resolve_sketch_k(sketch_k)
@@ -426,7 +416,6 @@ class IMMSolver:
             self._sig_problem = problem
             store = self._store_obj
             self._stats = IMMStats(
-                selection=self.selection,
                 variant=problem.variant,
                 mesh_shape=tuple(int(s) for s in store.mesh.devices.shape),
                 pool_sharding=f"{store.axis}:{store.n_shards}")
@@ -914,7 +903,8 @@ class IMMSolver:
 
             self._sketch_info = None
             pool_free = getattr(self.store, "pool_free", False)
-            method = "sketch" if pool_free else self._sel_method
+            method = "sketch" if pool_free else "fused"
+            self._stats.selection = method
 
             def _select():
                 if pool_free:
@@ -928,9 +918,7 @@ class IMMSolver:
                         self.store, r.k_steps, cand=r.cand_mask_items,
                         info_out=info))
                 else:
-                    fn = (lambda: self.store.select(
-                        r.k_steps, method=self._sel_method, spec=spec,
-                        eval_batch=self.eval_batch))
+                    fn = (lambda: self.store.select(r.k_steps, spec=spec))
                 if self.fault_policy is not None:
                     # ctx identifies the request so a match-gated injector
                     # can poison one problem in a batch (serving isolation
@@ -1176,10 +1164,7 @@ class IMMSolver:
         # reuse is sound only for a same-signature pool: the expected sig
         # mirrors _prepare's keying exactly
         model = problem.model or self._default_model()
-        sketch_k = self._sketch_k_arg
-        if sketch_k is None and (self._sel_method == "celf"
-                                 or problem.early_exit):
-            sketch_k = cov.ShardedDeviceRRStore.DEFAULT_SKETCH_K
+        sketch_k = self._resolve_sketch_k(problem)
         name = resolve_engine_name(self._engine_arg, model)
         want_sig = ("name", name, problem.pool_digest(model=model), sketch_k)
         store = self._store_obj if self._sig == want_sig else None
@@ -1218,9 +1203,9 @@ class IMMSolver:
 
 
 _SOLVER_KEYS = frozenset(("engine", "batch", "qcap", "ec", "model", "seed",
-                          "selection", "sketch_k", "eval_batch", "mesh",
-                          "fault_policy", "checkpoint_dir",
-                          "checkpoint_every", "checkpoint_keep"))
+                          "sketch_k", "mesh", "fault_policy",
+                          "checkpoint_dir", "checkpoint_every",
+                          "checkpoint_keep"))
 _PROBLEM_KEYS = frozenset(("model", "ell", "max_theta", "node_weights",
                            "costs", "budget", "candidates", "t_rounds",
                            "theta", "early_exit", "mode"))
@@ -1230,7 +1215,7 @@ def imm(g: CSRGraph, k: Optional[int] = None, eps: Optional[float] = None,
         **kw):
     """One-shot convenience wrapper; returns (seeds, spread_estimate, stats).
 
-    Keyword arguments split between the solver (engine/batch/selection/...)
+    Keyword arguments split between the solver (engine/batch/sketch_k/...)
     and the problem (node_weights/costs/budget/candidates/t_rounds/...);
     anything else raises ``TypeError`` — the historical whitelist filter
     silently swallowed typos like ``sketchk=64``.

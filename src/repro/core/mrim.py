@@ -11,11 +11,11 @@ of the queue engine sharing one root; elements are encoded as
 ``round * n + node`` so the whole coverage machinery (occur histogram,
 membership scan, decrement) is reused verbatim on an item space of size n·T.
 The cross-round greedy of CR-NAIMM — mask rounds whose per-round budget k is
-exhausted — is a *group budget* on the unified selection backends
+exhausted — is a *group budget* on the selection scan
 (``SelectionSpec(n_group=n, n_groups=T, group_quota=k)``), so MRIM is just
 ``IMMSolver.solve(IMProblem(k=k, t_rounds=T, ...))``: the dedicated
-``_greedy_mrim`` scan of earlier revisions is gone, and all three selection
-backends (fused scan, Pallas bitset, CELF-sketch) solve MRIM on any mesh.
+``_greedy_mrim`` scan of earlier revisions is gone, and the selection scan
+solves MRIM on any mesh.
 """
 from __future__ import annotations
 
@@ -50,15 +50,14 @@ class MRIMResult(NamedTuple):
 
 
 def solve_mrim(g: CSRGraph, k: int, t_rounds: int, n_rr: int, *,
-               qcap: int | None = None, batch: int = 64, seed: int = 0,
-               selection: str = "auto") -> MRIMResult:
+               qcap: int | None = None, batch: int = 64, seed: int = 0
+               ) -> MRIMResult:
     """Fixed-θ MRIM solve — a thin wrapper over the unified problem API:
     ``IMMSolver(g, engine=...).solve(IMProblem(k=k, t_rounds=T, theta=n_rr))``
     (the paper's Table-3 experiment uses fixed ε; the IMM θ machinery
     composes identically — drop ``theta=`` from the problem to run the full
     Alg. 2 schedule)."""
-    solver = IMMSolver(g, batch=batch, qcap=qcap, seed=seed,
-                       selection=selection)
+    solver = IMMSolver(g, batch=batch, qcap=qcap, seed=seed)
     res = solver.solve(IMProblem(k=k, t_rounds=t_rounds, theta=n_rr))
     frac = res.frac
     return MRIMResult(seeds_per_round=res.seeds_per_round(),

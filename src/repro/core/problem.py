@@ -281,7 +281,7 @@ class IMProblem:
 @dataclass(frozen=True)
 class ResolvedProblem:
     """An :class:`IMProblem` validated against a graph: normalized arrays
-    plus the derived sizes the solver and the selection backends consume."""
+    plus the derived sizes the solver and the selection scan consume."""
     problem: IMProblem
     n_nodes: int
     n_items: int                       # n * t_rounds (the coverage id space)

@@ -22,19 +22,18 @@ the survivors with one scatter-*add* — which at that point is exactly
 scatter-OR.  This is the ~8× sketch-memory cut over the historical bool
 occupancy (deleted), and the one fold on every backend.
 
-Properties the CELF selection path (``coverage.select_seeds_celf``) relies
-on:
+Properties the sketch readers (``coverage.select_seeds_sketch`` in
+approximate mode, the θ early exit, deadline-degraded results) rely on:
 
 * **Lower bound** — new occupied buckets require new rows, so
   ``Δocc(v | S) = occ(sketch_v | sketch_S) − occ(sketch_S)`` never exceeds
-  the exact marginal coverage of v.  CELF therefore uses Δocc only to
-  *order* candidates for exact verification; correctness never depends on
-  sketch accuracy.
+  the exact marginal coverage of v: summed Δocc gains certify a lower
+  bound on the rows a seed set covers.
 * **Exact-safe regime** — with the default ``"mod"`` bucketing
   (``bucket = row_id % k``) the map is injective while ``n_rr <= k``, so
-  Δocc *equals* the exact marginal gain and one verification per seed
-  suffices.  Past k rows the sketch degrades gracefully into a uniform
-  hash (sequential row ids stride the buckets perfectly).
+  Δocc *equals* the exact marginal gain and the sketch greedy returns the
+  exact greedy's seeds.  Past k rows the sketch degrades gracefully into a
+  uniform hash (sequential row ids stride the buckets perfectly).
 * **Incremental** — ``ShardedDeviceRRStore.append_batch`` folds each batch
   into the packed words with one jit'd sort+scatter (O(batch elements
   · log), no rebuild); on a multi-device mesh the fold runs replicated

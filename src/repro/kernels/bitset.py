@@ -158,9 +158,8 @@ def occur_from_bitset_masked(words, rowmask, *, block_b: int = 256,
                              block_w: int = 1024, interpret: bool = True):
     """Occur[v] = number of *selected* lanes with bit v set.
 
-    ``rowmask`` (B,) bool/int32 selects the lanes that contribute — this is
-    the popcount-arithmetic Occur *decrement* of the fused greedy selection
-    (dec over newly covered RR rows), replacing the per-seed flat scatter.
+    ``rowmask`` (B,) bool/int32 selects the lanes that contribute;
+    :func:`occur_from_bitset` (every lane) is the dense sampler's Occur.
 
     The grid tiles node words (parallel) over row blocks (the innermost,
     accumulating axis), so VMEM holds one (BB, TW) word tile and one

@@ -6,7 +6,7 @@ set of RR rows containing v as a k-bit hashed occupancy bitmap packed into
 use (``kernels/bitset.py``).
 
 :func:`sketch_union_popcount` computes per-node ``popcount(sketch[v] |
-covered)``, the inner product of the CELF sketch estimate: the
+covered)``, the inner product of the sketch estimate: the
 union-cardinality proxy for ``|rows(v) ∪ rows(S)|`` evaluated for *all*
 nodes in one popcount sweep.  The sketch matrix is (R, k/32) with k/32
 narrow, which XLA lays out node-minor; the kernel reads it through the
@@ -55,8 +55,8 @@ def sketch_union_popcount(words, cov, *, block_b: int = 2048,
 
     ``words``: (R, W) uint32 packed per-node sketches; ``cov``: (W,) uint32
     packed union sketch of the selected seed set.  Returns (R,) int32 —
-    the occupancy of each candidate union, from which the CELF path derives
-    estimated marginal coverage (see ``core/sketch.py``).
+    the occupancy of each candidate union, from which the sketch greedy
+    derives estimated marginal coverage (see ``core/sketch.py``).
 
     ``interpret=None`` (default) defers to ``ops.resolve_interpret`` like
     every other kernel: interpret mode on CPU, compiled Mosaic on an

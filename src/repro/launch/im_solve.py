@@ -163,8 +163,7 @@ class ShardedQueueEngine:
 
 
 def solve(g, k: int | None = None, eps: float | None = None, *,
-          batch_per_dev: int = 128, seed: int = 0, selection: str = "auto",
-          eval_batch: int | None = None, mesh=None,
+          batch_per_dev: int = 128, seed: int = 0, mesh=None,
           problem: IMProblem | None = None, fault_policy=None,
           checkpoint_dir: str | None = None, checkpoint_every: int = 0):
     """Distributed IM solve: sampler fan-out AND pool/selection sharing one
@@ -198,8 +197,7 @@ def solve(g, k: int | None = None, eps: float | None = None, *,
     engine = ShardedQueueEngine(
         g_rev, ShardedQueueEngine.Config(batch=batch_per_dev), mesh=mesh,
         root_weights=problem.node_weights)
-    solver = IMMSolver(g, engine=engine, seed=seed, selection=selection,
-                       eval_batch=eval_batch, mesh=mesh,
+    solver = IMMSolver(g, engine=engine, seed=seed, mesh=mesh,
                        fault_policy=fault_policy,
                        checkpoint_dir=checkpoint_dir,
                        checkpoint_every=checkpoint_every)
@@ -331,8 +329,7 @@ def _serve(args, g):
     async def run():
         svc = build_service({"graph": g}, ServeConfig(
             max_batch=8, batch_window_s=0.002,
-            solver_opts={"batch": 64, "seed": 0,
-                         "selection": args.selection}))
+            solver_opts={"batch": 64, "seed": 0}))
         server = IMNetServer(svc, host="127.0.0.1", port=0)
         await server.start()
         stop = asyncio.Event()
@@ -383,8 +380,7 @@ def _stream(args, g):
 
     rng = np.random.default_rng(11)
     problem = IMProblem(k=args.k, theta=args.stream_theta)
-    solver = IMMSolver(g, engine="queue", batch=128, seed=0,
-                       selection=args.selection, eval_batch=args.eval_batch)
+    solver = IMMSolver(g, engine="queue", batch=128, seed=0)
     t0 = time.time()
     res = solver.solve(problem)
     print(f"cold: theta={res.stats.theta} "
@@ -431,13 +427,6 @@ def main():
                     help="edges added per --stream-deltas batch (default 8)")
     ap.add_argument("--stream-theta", type=int, default=4096,
                     help="fixed θ for --stream-deltas solves (default 4096)")
-    ap.add_argument("--selection", default="auto",
-                    choices=("auto", "fused", "bitset", "celf-sketch"),
-                    help="seed-selection backend (DESIGN.md §3)")
-    ap.add_argument("--eval-batch", type=int, default=None,
-                    help="CELF exact-verification batch width (celf-sketch "
-                         "selection); default: backend default (32).  Swept "
-                         "by benchmarks/perf_im_engines --selection-only")
     ap.add_argument("--mesh", default=None,
                     help="device count or axis spec for the sampling mesh "
                          "(e.g. '4' or 'samples:8'; default: all devices)")
@@ -500,16 +489,14 @@ def main():
     t0 = time.time()
     if args.t_rounds is not None:
         from repro.core.imm import imm_result
-        res = imm_result(g, problem, selection=args.selection)
+        res = imm_result(g, problem)
         print(f"variant={res.stats.variant} theta={res.stats.theta} "
               f"sampled={res.stats.n_rr_sampled} "
               f"selection={res.stats.selection} time={time.time() - t0:.2f}s")
         print(f"seeds_per_round={res.seeds_per_round()} "
               f"estimate={res.spread:.1f}")
         return
-    seeds, est, stats = solve(g, selection=args.selection,
-                              eval_batch=args.eval_batch,
-                              mesh=make_sample_mesh(args.mesh),
+    seeds, est, stats = solve(g, mesh=make_sample_mesh(args.mesh),
                               problem=problem, fault_policy=fault_policy,
                               checkpoint_dir=args.checkpoint_dir,
                               checkpoint_every=args.checkpoint_every)

@@ -54,8 +54,7 @@ from repro.graph.csr import graph_digest as _graph_digest
 
 # solver constructor options a registry may carry (forwarded verbatim)
 _SOLVER_OPTS = frozenset(("engine", "batch", "qcap", "ec", "model", "seed",
-                          "selection", "sketch_k", "eval_batch", "mesh",
-                          "fault_policy"))
+                          "sketch_k", "mesh", "fault_policy"))
 
 
 @dataclass(frozen=True)

@@ -159,7 +159,7 @@ def test_exact_regime_store_level_identity():
         exact.append_batch(b)
         sk.append_batch(b)
     assert exact.n_rr == sk.n_rr and sk.n_rr <= sk.sketch_k
-    r_exact = exact.select(k, method="flat")
+    r_exact = exact.select(k)
     info = {}
     r_sk = cov.select_seeds_sketch(sk, k, info_out=info)
     a, b_ = jax.device_get(((r_exact.seeds, r_exact.gains, r_exact.frac),
@@ -177,7 +177,7 @@ def test_exact_regime_end_to_end_identity():
     # the whole approximate solve is bit-identical to fused exact
     g = _graph()
     theta = 192
-    se = IMMSolver(g, engine="queue", batch=64, seed=3, selection="fused")
+    se = IMMSolver(g, engine="queue", batch=64, seed=3)
     re_ = se.solve(IMProblem(k=4, theta=theta))
     sa = IMMSolver(g, engine="queue", batch=64, seed=3, sketch_k=256)
     ra = sa.solve(IMProblem(k=4, theta=theta, mode="approximate"))

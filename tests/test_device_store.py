@@ -120,17 +120,41 @@ def test_device_store_accepts_overflowed_truncated_rows():
 
 # ----------------------------------------------- fused selection parity
 
-@pytest.mark.parametrize("method", ("flat", "bitset", "auto"))
-def test_fused_selection_matches_oracle(method):
-    rng = np.random.default_rng(3)
-    n, k = 50, 6
-    dev = cov.DeviceRRStore(n, capacity=8)
+def _pool_batches(rng, density):
+    """(n, k, batches) of a random RR pool.  ``sparse``: rows of 1-7 nodes
+    of 50.  ``dense``: rows of 128-1023 nodes of 4,096, a mean RR size
+    past n/32; ``full``: every row holds all 256 nodes.  A bit-matrix scan
+    over (rows, ⌈n/32⌉) words is no larger than the flat pool on the dense
+    and full pools; the flat scan serves them all."""
+    if density == "sparse":
+        return 50, 6, [_random_batch(rng, 50, 60) for _ in range(4)]
+    if density == "dense":
+        n, rows, lens = 4096, 96, rng.integers(128, 1024, 96)
+    else:
+        n, rows = 256, 40
+        lens = np.full(rows, n)
+    nodes = np.zeros((rows, int(lens.max())), np.int64)
+    for i, ln in enumerate(lens):
+        nodes[i, :ln] = rng.choice(n, size=ln, replace=False)
+    return n, 6, [(nodes, lens)]
+
+
+def _store_and_rows(n, batches, **kw):
+    dev = cov.DeviceRRStore(n, capacity=8, **kw)
     rr_all = []
-    for _ in range(4):
-        nodes, lens = _random_batch(rng, n, 60)
+    for nodes, lens in batches:
         dev.append_batch((nodes, lens))
         rr_all += [nodes[j, :lens[j]].tolist() for j in range(len(lens))]
-    res = dev.select(k, method=method)
+    return dev, rr_all
+
+
+@pytest.mark.parametrize("density", ("sparse", "dense", "full"))
+def test_fused_selection_matches_oracle(density):
+    n, k, batches = _pool_batches(np.random.default_rng(3), density)
+    dev, rr_all = _store_and_rows(n, batches)
+    if density != "sparse":
+        assert dev.n_elems >= dev.n_rr * n / 32
+    res = dev.select(k)
     seeds_o, frac_o = oracle.greedy_max_coverage(rr_all, n, k)
     assert np.asarray(res.seeds).tolist() == seeds_o
     assert float(res.frac) == pytest.approx(frac_o, abs=1e-6)
@@ -147,11 +171,76 @@ def test_fused_selection_matches_oracle_on_random_graph_batches():
         dev.append_batch(b)
         nodes, lens = np.asarray(b.nodes), np.asarray(b.lengths)
         rr_all += [nodes[j, :lens[j]].tolist() for j in range(b.n_sets)]
-    for method in ("flat", "bitset"):
-        res = dev.select(5, method=method)
-        seeds_o, frac_o = oracle.greedy_max_coverage(rr_all, 45, 5)
-        assert np.asarray(res.seeds).tolist() == seeds_o, method
-        assert float(res.frac) == pytest.approx(frac_o, abs=1e-6)
+    res = dev.select(5)
+    seeds_o, frac_o = oracle.greedy_max_coverage(rr_all, 45, 5)
+    assert np.asarray(res.seeds).tolist() == seeds_o
+    assert float(res.frac) == pytest.approx(frac_o, abs=1e-6)
+
+
+@pytest.mark.parametrize("sketch_k,mode", [
+    pytest.param(32, "mod", id="32-mod"),
+    pytest.param(64, "mod", id="64-mod"),
+    pytest.param(None, "mod", id="None"),
+    pytest.param(64, "mix", id="64-mix")])
+def test_fused_selection_unchanged_by_store_sketch(sketch_k, mode):
+    """A store that keeps a coverage sketch (for the θ early exit and
+    degraded results) selects bit for bit what a store without one does,
+    plain and candidate-masked, also after its sketch has been read (built
+    on demand when the store keeps none)."""
+    n, k, batches = _pool_batches(np.random.default_rng(7), "sparse")
+    plain, _ = _store_and_rows(n, batches)
+    sketched, _ = _store_and_rows(n, batches, sketch_k=sketch_k,
+                                  sketch_mode=mode)
+    assert sketched.sketch_k == sketch_k
+    jax.block_until_ready(sketched.sketch_words())
+    spec = cov.SelectionSpec(k_steps=k, n_group=n, group_quota=k,
+                             cand=np.arange(n) % 3 != 0)
+    for a, b in ((plain.select(k), sketched.select(k)),
+                 (plain.select(k, spec=spec), sketched.select(k, spec=spec))):
+        a, b = jax.device_get((tuple(a), tuple(b)))
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("variant", ("weighted", "budgeted", "candidates"))
+def test_variant_selection_matches_oracle_on_dense_pool(variant):
+    """The variant scan on a dense pool (mean RR size past n/32) equals the
+    numpy reference greedy of its variant on the same rows."""
+    rng = np.random.default_rng(11)
+    n, k, batches = _pool_batches(rng, "dense")
+    nodes, lens = batches[0]
+    rr_all = [nodes[j, :lens[j]].tolist() for j in range(len(lens))]
+    if variant == "weighted":
+        roww = rng.integers(1, 9, len(lens)).astype(np.float32)
+        dev = cov.DeviceRRStore(n, capacity=8, row_weighted=True)
+        dev.append_batch((nodes, lens), row_w=roww)
+        spec = cov.SelectionSpec(k_steps=k, n_group=n, group_quota=k,
+                                 weighted=True)
+        seeds_o, frac_o = oracle.greedy_max_coverage_weighted(
+            rr_all, n, k, roww)
+    else:
+        dev, _ = _store_and_rows(n, batches)
+        if variant == "budgeted":
+            costs = rng.integers(1, 5, n).astype(np.float32)
+            budget = 9.0
+            spec = cov.SelectionSpec(k_steps=9, n_group=n, group_quota=9,
+                                     costs=costs, budget=budget)
+            seeds_o, frac_o, spent_o = oracle.budgeted_greedy_cost_ratio(
+                rr_all, n, costs, budget)
+        else:
+            cand = np.arange(n) % 3 == 1
+            spec = cov.SelectionSpec(k_steps=k, n_group=n, group_quota=k,
+                                     cand=cand)
+            seeds_o, frac_o, _ = oracle.budgeted_greedy_cost_ratio(
+                rr_all, n, np.ones(n), float(k), candidates=cand)
+            assert len(seeds_o) == k          # every pick gains: no ties at 0
+    res = dev.select(k, spec=spec)
+    seeds, frac, spent = jax.device_get((res.seeds, res.frac, res.spent))
+    seeds = np.asarray(seeds)
+    assert seeds[seeds < n].tolist() == seeds_o
+    assert float(frac) == pytest.approx(frac_o, rel=1e-6)
+    if variant == "budgeted":
+        assert float(spent) == pytest.approx(spent_o) and spent_o <= budget
 
 
 # --------------------------------------------- transfer-guard regression

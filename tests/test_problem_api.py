@@ -3,15 +3,16 @@ budgeted / candidate-restricted / MRIM influence maximization.
 
 Contracts under test (ISSUE acceptance criteria):
 * plain problems through ``solve(IMProblem(...))`` match ``solve_problem``
-  bit-identically on all three selection backends;
+  bit-identically on every sampler engine;
 * the removed ``solve(k, eps)`` shim raises TypeError (never warns, never
   samples);
 * ``imm()`` raises TypeError on unknown kwargs (the old whitelist filter
-  silently swallowed typos);
+  silently swallowed typos), the removed ``selection=`` / ``eval_batch=``
+  knobs included;
 * variant solves are deterministic conformant with the numpy references
   (weighted greedy, budgeted cost-ratio greedy) on the *same* RR pool;
-* candidate restriction and budgets are honored, all three backends agree;
-* MRIM routes through the unified backends (``_greedy_mrim`` is gone) with
+* candidate restriction and budgets are honored;
+* MRIM routes through the selection scan (``_greedy_mrim`` is gone) with
   per-round quotas;
 * the sketch-driven θ early exit provably never changes seeds/θ;
 * variant solves run under ``jax.transfer_guard("disallow")``.
@@ -26,8 +27,6 @@ from repro.core import coverage as cov, mrim, oracle
 from repro.core.engine import make_engine
 from repro.core.imm import IMMSolver, imm, imm_result
 from repro.core.problem import IMProblem, IMResult
-
-SELECTIONS = ("fused", "bitset", "celf-sketch")
 
 
 def _wc_graph(n=50, m=250, seed=0):
@@ -73,19 +72,19 @@ def test_improblem_validation():
 
 # --------------------------------------- plain parity + shim removal
 
-@pytest.mark.parametrize("selection", SELECTIONS)
-def test_plain_problem_solve_and_solve_problem_agree(selection):
+@pytest.mark.parametrize("engine", ("queue", "refill", "lt"))
+def test_plain_problem_solve_and_solve_problem_agree(engine):
     g = _wc_graph()
-    res = IMMSolver(g, batch=64, seed=3, selection=selection).solve(
+    res = IMMSolver(g, engine=engine, batch=64, seed=3).solve(
         IMProblem(k=4, eps=0.5, max_theta=256))
-    res2 = IMMSolver(g, batch=64, seed=3,
-                     selection=selection).solve_problem(
+    res2 = IMMSolver(g, engine=engine, batch=64, seed=3).solve_problem(
         IMProblem(k=4, eps=0.5, max_theta=256))
     assert isinstance(res, IMResult)
     np.testing.assert_array_equal(res.seeds, res2.seeds)
     assert res.spread == res2.spread
     assert res.stats.theta == res2.stats.theta
     assert res.stats.variant == "plain"
+    assert res.stats.selection == "fused"
 
 
 def test_removed_solve_k_eps_form_raises_typeerror():
@@ -138,8 +137,28 @@ def test_imm_unknown_kwargs_raise_typeerror():
         imm_result(g, IMProblem(k=2, eps=0.5), foo=1)
     # known keys still work end to end
     seeds, est, st = imm(g, 3, 0.5, engine="queue", batch=64, max_theta=128,
-                         sketch_k=64, selection="celf-sketch")
+                         sketch_k=64)
     assert len(seeds) == 3 and est > 0
+
+
+@pytest.mark.parametrize("keyword,value", [("selection", "fused"),
+                                           ("eval_batch", 32)],
+                         ids=("selection", "eval_batch"))
+def test_removed_selection_keywords_raise(keyword, value):
+    """Selection has no options left: the removed ``selection=`` and
+    ``eval_batch=`` knobs raise TypeError like any unknown keyword, on the
+    solver, the one-shot wrappers and the serving registry's solver
+    options, before anything is built or sampled."""
+    from repro.serve.registry import WarmSolverRegistry
+    g = _wc_graph()
+    with pytest.raises(TypeError, match=keyword):
+        IMMSolver(g, **{keyword: value})
+    with pytest.raises(TypeError, match=keyword):
+        imm(g, 3, 0.5, **{keyword: value})
+    with pytest.raises(TypeError, match=keyword):
+        imm_result(g, IMProblem(k=2, eps=0.5), **{keyword: value})
+    with pytest.raises(TypeError, match=keyword):
+        WarmSolverRegistry(solver_opts={"batch": 64, keyword: value})
 
 
 # ------------------------------------------------------------- variants
@@ -147,13 +166,16 @@ def test_imm_unknown_kwargs_raise_typeerror():
 def test_candidate_restriction_honored_all_backends():
     g = _wc_graph(seed=1)
     cand = np.arange(0, 50, 3)
-    outs = {}
-    for sel in SELECTIONS:
-        res = IMMSolver(g, batch=64, seed=2, selection=sel).solve(
-            IMProblem(k=4, eps=0.5, max_theta=256, candidates=cand))
-        assert set(res.seeds.tolist()) <= set(cand.tolist())
-        outs[sel] = (res.seeds.tolist(), res.gains.tolist())
-    assert len(set(map(str, outs.values()))) == 1, outs
+    solver = IMMSolver(g, batch=64, seed=2)
+    res = solver.solve(IMProblem(k=4, eps=0.5, max_theta=256,
+                                 candidates=cand))
+    assert set(res.seeds.tolist()) <= set(cand.tolist())
+    # the candidate-masked greedy on the pool the solver selected from
+    mask = np.isin(np.arange(50), cand)
+    ref_seeds, ref_frac, _ = oracle.budgeted_greedy_cost_ratio(
+        _pool_lists(solver.store), 50, np.ones(50), 4.0, candidates=mask)
+    assert res.seeds.tolist() == ref_seeds
+    assert res.frac == pytest.approx(ref_frac, abs=1e-6)
 
 
 def test_candidate_exhaustion_never_duplicates_seeds():
@@ -162,12 +184,11 @@ def test_candidate_exhaustion_never_duplicates_seeds():
     re-picking an already-selected seed at zero gain."""
     g = _wc_graph(seed=1)
     cand = [7, 9]
-    for sel in SELECTIONS:
-        res = IMMSolver(g, batch=64, seed=2, selection=sel).solve(
-            IMProblem(k=5, eps=0.5, theta=256, candidates=cand))
-        s = res.seeds.tolist()
-        assert len(s) == len(set(s)), (sel, s)
-        assert set(s) <= set(cand) and len(s) <= len(cand)
+    res = IMMSolver(g, batch=64, seed=2).solve(
+        IMProblem(k=5, eps=0.5, theta=256, candidates=cand))
+    s = res.seeds.tolist()
+    assert len(s) == len(set(s)), s
+    assert set(s) <= set(cand) and len(s) <= len(cand)
 
 
 def test_problem_model_overrides_solver_default():
@@ -188,50 +209,36 @@ def test_budgeted_solve_honors_budget_and_matches_reference():
     rng = np.random.default_rng(5)
     costs = rng.integers(1, 5, 50).astype(np.float32)
     budget = 7.0
-    outs = {}
-    for sel in SELECTIONS:
-        solver = IMMSolver(g, batch=64, seed=4, selection=sel)
-        res = solver.solve(IMProblem(eps=0.5, theta=512, costs=costs,
-                                     budget=budget))
-        assert res.cost <= budget + 1e-6
-        assert res.cost == pytest.approx(float(costs[res.seeds].sum()))
-        outs[sel] = res.seeds.tolist()
-        if sel == "fused":
-            # deterministic conformance: numpy cost-ratio greedy on the
-            # exact pool the solver selected from
-            ref_seeds, ref_frac, ref_spent = oracle.budgeted_greedy_cost_ratio(
-                _pool_lists(solver.store), 50, costs, budget)
-            assert res.seeds.tolist() == ref_seeds
-            assert res.frac == pytest.approx(ref_frac, abs=1e-6)
-            assert res.cost == pytest.approx(ref_spent)
-    assert len(set(map(str, outs.values()))) == 1, outs
+    solver = IMMSolver(g, batch=64, seed=4)
+    res = solver.solve(IMProblem(eps=0.5, theta=512, costs=costs,
+                                 budget=budget))
+    assert res.cost <= budget + 1e-6
+    assert res.cost == pytest.approx(float(costs[res.seeds].sum()))
+    # deterministic conformance: numpy cost-ratio greedy on the exact pool
+    # the solver selected from
+    ref_seeds, ref_frac, ref_spent = oracle.budgeted_greedy_cost_ratio(
+        _pool_lists(solver.store), 50, costs, budget)
+    assert res.seeds.tolist() == ref_seeds
+    assert res.frac == pytest.approx(ref_frac, abs=1e-6)
+    assert res.cost == pytest.approx(ref_spent)
 
 
 def test_weighted_row_estimator_matches_numpy_reference():
     """Row-weighted (importance-weighted) selection — the fallback for
     engines without weighted-root sampling — equals the weighted numpy
-    greedy on the same pool, for all three backends."""
+    greedy on the same pool."""
     g = _wc_graph(seed=3)
     w = (np.arange(50) % 7 + 1).astype(np.float32)
     eng = make_engine("queue", csr_mod.reverse(g), batch=64)  # uniform roots
-    outs = {}
-    for sel in SELECTIONS:
-        solver = IMMSolver(g, engine=eng, seed=6, selection=sel)
-        res = solver.solve(IMProblem(k=4, eps=0.5, theta=512,
-                                     node_weights=w))
-        assert solver._row_weight_mode        # fallback estimator engaged
-        outs[sel] = (res.seeds.tolist(),
-                     np.round(res.gains, 4).tolist())
-        if sel == "fused":
-            rr = _pool_lists(solver.store)
-            roww = w[[r[0] for r in rr]]      # queue rows are root-first
-            ref_seeds, ref_frac = oracle.greedy_max_coverage_weighted(
-                rr, 50, 4, roww)
-            assert res.seeds.tolist() == ref_seeds
-            assert res.frac == pytest.approx(ref_frac, rel=1e-5)
-            assert res.spread == pytest.approx(float(w.sum()) * ref_frac,
-                                               rel=1e-5)
-    assert len(set(map(str, outs.values()))) == 1, outs
+    solver = IMMSolver(g, engine=eng, seed=6)
+    res = solver.solve(IMProblem(k=4, eps=0.5, theta=512, node_weights=w))
+    assert solver._row_weight_mode            # fallback estimator engaged
+    rr = _pool_lists(solver.store)
+    roww = w[[r[0] for r in rr]]              # queue rows are root-first
+    ref_seeds, ref_frac = oracle.greedy_max_coverage_weighted(rr, 50, 4, roww)
+    assert res.seeds.tolist() == ref_seeds
+    assert res.frac == pytest.approx(ref_frac, rel=1e-5)
+    assert res.spread == pytest.approx(float(w.sum()) * ref_frac, rel=1e-5)
 
 
 def test_plain_problem_on_weighted_engine_instance_raises():
@@ -271,15 +278,11 @@ def test_weighted_solve_uses_weight_proportional_roots():
 def test_mrim_routes_through_unified_backends():
     assert not hasattr(mrim, "_greedy_mrim")   # dedicated scan deleted
     g = _wc_graph(seed=8)
-    outs = {}
-    for sel in SELECTIONS:
-        res = IMMSolver(g, seed=0, batch=32, selection=sel).solve(
-            IMProblem(k=2, t_rounds=3, theta=512))
-        per_round = res.seeds_per_round()
-        assert len(per_round) == 3
-        assert all(len(s) == 2 for s in per_round)   # per-round quota
-        outs[sel] = res.seeds.tolist()
-    assert len(set(map(str, outs.values()))) == 1, outs
+    res = IMMSolver(g, seed=0, batch=32).solve(
+        IMProblem(k=2, t_rounds=3, theta=512))
+    per_round = res.seeds_per_round()
+    assert len(per_round) == 3
+    assert all(len(s) == 2 for s in per_round)       # per-round quota
     # the wrapper is a thin IMProblem(t_rounds=T) shim over the same path
     wrapped = mrim.solve_mrim(g, k=2, t_rounds=3, n_rr=512, batch=32, seed=0)
     assert wrapped.seeds_per_round == \

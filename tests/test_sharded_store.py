@@ -3,12 +3,12 @@ single-device == multi-device parity contract.
 
 The packed fold (sort+dedup+scatter-add in ``core/sketch.py``, and the
 Pallas scatter-or kernel in ``kernels/sketch.py``) must be bit-identical to
-the PR-3 bool-matrix fold it replaced; the sharded selection backends
-(fused scan, Pallas bitset, CELF) must return seeds/gains/F_R bit-identical
-to the 1-device mesh on a forced 8-way host-device mesh, with the whole
-solve legal under ``jax.transfer_guard("disallow")``.  Device count is
-locked at first jax init, so the multi-device checks run in a subprocess
-with XLA_FLAGS set (the suite itself must keep seeing 1 device).
+the bool-matrix fold it replaced; the sharded selection scan must
+return seeds/gains/F_R bit-identical to the 1-device mesh on a forced
+8-way host-device mesh, with the whole solve legal under
+``jax.transfer_guard("disallow")``.  Device count is locked at first jax
+init, so the multi-device checks run in a subprocess with XLA_FLAGS set
+(the suite itself must keep seeing 1 device).
 """
 import os
 import subprocess
@@ -150,8 +150,8 @@ def batches():
         out.append((nodes, lens))
     return out
 
-# identical pool on a 1-device and an 8-device mesh: every backend must be
-# bit-identical, for every sketch size, all under the transfer guard
+# identical pool on a 1-device and an 8-device mesh: the selection scan
+# must be bit-identical, for every sketch size, all under the transfer guard
 for sketch_k in (32, 256, None):
     d1 = cov.ShardedDeviceRRStore(n, capacity=8, sketch_k=sketch_k)
     d8 = cov.ShardedDeviceRRStore(n, capacity=64, sketch_k=sketch_k,
@@ -165,26 +165,18 @@ for sketch_k in (32, 256, None):
             s1, s8 = jax.device_get((d1.sketch_words(), d8.sketch_words()))
             assert np.array_equal(np.asarray(s1), np.asarray(s8)), \
                 "incremental sketch fold diverged across mesh sizes"
-        for method in ("flat", "bitset"):
-            r1, r8 = d1.select(k, method=method), d8.select(k, method=method)
-            a, b_ = jax.device_get(((r1.seeds, r1.gains, r1.frac),
-                                    (r8.seeds, r8.gains, r8.frac)))
-            assert np.array_equal(a[0], b_[0]), (method, a[0], b_[0])
-            assert np.array_equal(a[1], b_[1]) and a[2] == b_[2], method
-        c1 = cov.select_seeds_celf(d1, k)
-        c8 = cov.select_seeds_celf(d8, k)
-        a, b_ = jax.device_get(((c1.seeds, c1.gains, c1.frac),
-                                (c8.seeds, c8.gains, c8.frac)))
-        assert np.array_equal(a[0], b_[0]), ("celf", sketch_k, a[0], b_[0])
-        assert np.array_equal(a[1], b_[1]) and a[2] == b_[2]
+        r1, r8 = d1.select(k), d8.select(k)
+        a, b_ = jax.device_get(((r1.seeds, r1.gains, r1.frac),
+                                (r8.seeds, r8.gains, r8.frac)))
+        assert np.array_equal(a[0], b_[0]), (sketch_k, a[0], b_[0])
+        assert np.array_equal(a[1], b_[1]) and a[2] == b_[2], sketch_k
 
 # full solve: same engine stream into a sharded vs single-device pool
 src, dst = generators.erdos_renyi(60, 300, seed=6)
 g = weights.wc_weights(csr_mod.from_edges(src, dst, 60))
 res = {}
 for mesh in (None, mesh8):
-    solver = IMMSolver(g, engine="queue", batch=64, seed=3,
-                       selection="celf-sketch", mesh=mesh)
+    solver = IMMSolver(g, engine="queue", batch=64, seed=3, mesh=mesh)
     with jax.transfer_guard("disallow"):
         r = solver.solve(IMProblem(k=4, eps=0.5, max_theta=256))
     res[r.stats.pool_sharding] = (r.seeds.tolist(), round(r.spread, 6))

@@ -5,8 +5,9 @@ unaligned blocks, unsupported casts and reductions, layouts Mosaic cannot
 infer, VMEM overflows.  These tests lower every Pallas kernel on a
 user-reachable path with ``interpret=False`` at the node widths of the
 ``im_paper`` stand-ins (epinions-like n=75,879 and pokec-like
-n=1,632,803), plus the fused and bitset selection ``shard_map`` programs
-on a 1-chip and a 4-chip mesh, and compile them for ``v5e:2x2``.  The
+n=1,632,803), plus the fused selection and sketch-sweep ``shard_map``
+programs on a 1-chip and a 4-chip mesh and the variant selection scan on
+one chip, and compile them for ``v5e:2x2``.  The
 queue sampler's chunk pack is compiled at the benchmark's lane count to
 keep data-dependent gathers and scatters out of it, and one sampling
 round and the pool append of its output at pokec width, to bound their
@@ -26,12 +27,16 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.core.coverage import bitset_words
 from repro.kernels import bitset, ops
 from repro.kernels import sketch as ksketch
 
 WIDTHS = {"epinions": 75_879, "pokec": 1_632_803}   # configs/im_paper.py
 SKETCH_K = 1024                                       # store default
+
+
+def _node_words(n):
+    """A bit matrix's node-word width: ceil(n/32) in whole 128-word tiles."""
+    return -(-n // (32 * 128)) * 128
 
 
 @pytest.fixture(scope="module")
@@ -81,7 +86,7 @@ def _sds(shape, dtype, sharding):
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
 def test_occur_kernels_compile(width, one_chip, chip_compiles):
-    w = bitset_words(WIDTHS[width])
+    w = _node_words(WIDTHS[width])
     rows = 4096
     words = _sds((rows, w), jnp.uint32, one_chip)
     mask = _sds((rows,), jnp.bool_, one_chip)
@@ -105,9 +110,9 @@ def test_union_popcount_kernel_compiles(width, one_chip, chip_compiles):
 @pytest.mark.parametrize("chips", [1, 4])
 @pytest.mark.parametrize("width", sorted(WIDTHS))
 def test_selection_programs_compile(width, chips, topo, chip_compiles):
-    """The fused and bitset Alg. 7 scans as the solver runs them: one
-    ``shard_map`` over a ``samples`` mesh, pool buffers sharded per chip,
-    at a pool that fills a realistic share of 16 GB."""
+    """The fused Alg. 7 scan and the sketch sweep as the solver runs them:
+    one ``shard_map`` over a ``samples`` mesh, pool buffers sharded per
+    chip, at a pool that fills a realistic share of 16 GB."""
     from repro.core.coverage import _mesh_select_fns
     n = WIDTHS[width]
     mesh = Mesh(np.asarray(topo.devices[:chips]), ("samples",))
@@ -124,19 +129,45 @@ def test_selection_programs_compile(width, chips, topo, chip_compiles):
                         k=50).compile()
     assert c.memory_analysis().temp_size_in_bytes < 2 << 30
 
-    # the bitset backend's pool: auto picks it while the bit matrix is no
-    # larger than the flat buffers, so it holds few rows at pokec width
-    m_rows = 1 << 16 if n < 500_000 else 1 << 12
-    m = _sds((chips, m_rows, bitset_words(n)), jnp.uint32, b3)
-    c = fns.bitset.lower(m, nrr, k=50).compile()
-    assert "tpu_custom_call" in c.as_text()
-
     sk = _sds((chips, -(-(n + 1) // chips) * chips, SKETCH_K // 32),
               jnp.uint32, b3)
     cov = _sds((chips, SKETCH_K // 32), jnp.uint32, buf)
     c = fns.sweep.lower(sk, cov, stripe=sk.shape[1] // chips,
                         interpret=False).compile()
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_variant_selection_programs_compile(width, topo, chip_compiles):
+    """The variant scan (``program``: candidate mask, costs, group
+    budgets), unweighted and row-weighted, as the solver runs it on one
+    chip at a cell's pool (``ba-r4-75k``'s 2,048 RR sets, 2^20 pool
+    elements): each compiles, its temporaries bounded by the pool and a
+    few node-wide vectors."""
+    from repro.core.coverage import _mesh_select_fns
+    n = WIDTHS[width]
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("samples",))
+    buf = NamedSharding(mesh, P("samples", None))
+    vec = NamedSharding(mesh, P("samples"))
+    rep = NamedSharding(mesh, P())
+    fns = _mesh_select_fns(mesh)
+    rows, elems = 1 << 11, 1 << 20
+    flat = _sds((1, elems), jnp.int32, buf)
+    valid = _sds((1, elems), jnp.bool_, buf)
+    nrr = _sds((1,), jnp.int32, vec)
+    cand = _sds((n,), jnp.bool_, rep)
+    costs = _sds((n,), jnp.float32, rep)
+    budget = _sds((), jnp.float32, rep)
+    static = dict(num_rows=rows, n=n, k_steps=50, n_group=n, n_groups=1,
+                  group_quota=50, use_costs=True)
+    dummy = _sds((1,), jnp.float32, rep)
+    for program, wvec, ew in (
+            (fns.fused_variant, dummy, dummy),
+            (fns.fused_variant_w, _sds((1,), jnp.float32, vec),
+             _sds((1, elems), jnp.float32, buf))):
+        c = program.lower(flat, flat, valid, nrr, wvec, ew, cand, costs,
+                          budget, **static).compile()
+        assert c.memory_analysis().temp_size_in_bytes < 16 * (elems + n)
 
 
 def _edge_rows_sds(m, ec, sharding):
